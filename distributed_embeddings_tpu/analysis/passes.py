@@ -274,10 +274,8 @@ def donation_pass(mod: ir.Module, ctx: PlanContext) -> List[Finding]:
             severity="error", func=entry.name, line=entry.line,
             message=(f"{len(donated)} donated/aliased arg(s) "
                      f"{names[:4]} but the donation policy for this "
-                     f"build is OFF (platform={ctx.platform}; on "
-                     "XLA:CPU a donated module loaded from the "
-                     "persistent compilation cache can mis-execute — "
-                     "compat.install_cpu_donation_cache_guard)")) ]
+                     f"build is OFF (platform={ctx.platform}): the "
+                     "caller's arrays would be consumed by the step"))]
     if ctx.donate_expected and not donated:
         return [Finding(
             pass_name="donation", fid="donation/missing-donation",

@@ -1,19 +1,14 @@
-"""Version/backend compatibility shims.
+"""Backend seams of the one supported installation (jax / jaxlib 0.9.0).
 
-The library targets the moving jax API surface across the versions its
-deployment environments actually carry. Two seams matter:
-
-  * ``shard_map`` moved: old releases expose it as
-    ``jax.experimental.shard_map.shard_map`` with a ``check_rep`` flag; new
-    ones as ``jax.shard_map`` with ``check_vma``. ``shard_map`` here accepts
-    the new-style signature and lowers to whichever the installed jax has.
-  * Host memory spaces are backend-dependent: TPU backends expose
-    ``pinned_host`` next to ``device``; the XLA:CPU backend of older
-    releases exposes only ``unpinned_host`` (which is also its *default*
-    space — host "offload" is then a placement no-op, but the whole
-    offload/serving code path, including ``compute_on`` host regions, still
-    compiles and runs, which is what the CPU test mesh needs).
-    ``host_memory_kind`` picks the best available host space.
+  * ``shard_map`` is ``jax.shard_map`` with this package's default
+    (``check_vma=False``) spelled once.
+  * Host memory: both backends the repo runs on expose ``pinned_host`` next
+    to ``device`` (the TPU, and XLA:CPU where the tests run), and a plain
+    array lands in ``device``. ``host_memory_kind`` returns the space table
+    offload stages into, or None on a backend without one (offload then
+    stays off).
+  * ``assemble_like`` rebuilds an array from per-device shards without
+    losing its host memory space on one device.
 """
 
 from typing import Optional
@@ -21,107 +16,35 @@ from typing import Optional
 import jax
 
 __all__ = ["shard_map", "host_memory_kind", "default_memory_kind",
-           "install_cpu_donation_cache_guard"]
+           "assemble_like"]
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """New-style ``jax.shard_map`` signature on any supported jax."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
-def _memory_kinds(device) -> set:
-    try:
-        return {m.kind for m in device.addressable_memories()}
-    except Exception:  # noqa: BLE001 - backend without the memories API
-        return set()
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def host_memory_kind(device) -> Optional[str]:
-    """The backend's host memory space for table offload: ``pinned_host``
-    where the runtime supports it (TPU; DMA-able), else ``unpinned_host``
-    (older XLA:CPU), else None (no host space — offload must stay off)."""
-    kinds = _memory_kinds(device)
-    for kind in ("pinned_host", "unpinned_host"):
-        if kind in kinds:
-            return kind
-    return None
+    """``pinned_host`` where the device can address it, else None."""
+    kinds = {m.kind for m in device.addressable_memories()}
+    return "pinned_host" if "pinned_host" in kinds else None
 
 
-def default_memory_kind(device) -> Optional[str]:
-    """The memory space a plain array lands in on `device` ('device' on
-    TPU/GPU; older XLA:CPU reports 'unpinned_host'). Lets tests assert
-    offload placement without hardcoding a backend's space names."""
-    try:
-        return device.default_memory().kind
-    except Exception:  # noqa: BLE001
-        kinds = _memory_kinds(device)
-        if "device" in kinds:
-            return "device"
-        return next(iter(kinds), None)
+def default_memory_kind(device) -> str:
+    """The memory space a plain array lands in on `device`. Lets tests
+    assert offload placement without hardcoding a backend's space names."""
+    return device.default_memory().kind
 
 
-_donation_cache_guard_installed = False
-
-
-def install_cpu_donation_cache_guard() -> bool:
-    """Bypass the persistent compilation cache for DONATED modules on the
-    XLA:CPU backend (idempotent; returns True when the guard is active).
-
-    jaxlib 0.4.36's CPU runtime intermittently mis-executes executables
-    **deserialized from the persistent compilation cache** when the
-    module carries input->output buffer donation (`tf.aliasing_output`
-    on unsharded modules, `jax.buffer_donor` on sharded ones — the
-    donated sharded train step lowers with the latter):
-    roughly 1 in 5 cache-loaded donated train steps computes structurally
-    wrong numerics (~7% off on a small training loss), consistently for
-    the lifetime of that loaded executable, while the freshly-compiled
-    twin of the SAME StableHLO is always correct. Isolated empirically
-    (tests/conftest.py enables the cache; the wire-compression bit-exact
-    A/B tests build identical donated steps twice per process, which
-    made the load path hot): 225/225 builds correct with the cache off,
-    135/135 correct with the cache on and donation off, ~20% of
-    processes wrong with both on. Undonated programs (forwards, inits,
-    set_weights) load correctly, so the guard scopes the bypass to
-    donated modules on CPU: they always compile fresh — correctness over
-    compile-time reuse — and everything else keeps the cache. TPU/GPU
-    backends are untouched.
-    """
-    global _donation_cache_guard_installed
-    if _donation_cache_guard_installed:
-        return True
-    try:
-        from jax._src import compilation_cache as _comp_cache
-        from jax._src import compiler as _compiler
-        orig = _compiler.compile_or_get_cached
-        backend_compile = _compiler.backend_compile
-        cache_in_use = _comp_cache.is_cache_used
-    except Exception:  # noqa: BLE001 - internal layout changed; newer
-        return False   # jax releases carry the runtime fix anyway
-
-    def _compile_or_get_cached(backend, computation, devices,
-                               compile_options, host_callbacks,
-                               *args, **kwargs):
-        # the O(module-text) donation probe only runs where the hazard
-        # exists: CPU backend AND the persistent cache actually enabled
-        if (getattr(backend, "platform", None) == "cpu"
-                and cache_in_use(backend)):
-            try:
-                text = str(computation)
-                donated = ("tf.aliasing_output" in text
-                           or "jax.buffer_donor" in text)
-            except Exception:  # noqa: BLE001 - unprintable module
-                donated = True  # fail safe: skip the cache
-            if donated:
-                return backend_compile(backend, computation,
-                                       compile_options, host_callbacks)
-        return orig(backend, computation, devices, compile_options,
-                    host_callbacks, *args, **kwargs)
-
-    _compiler.compile_or_get_cached = _compile_or_get_cached
-    _donation_cache_guard_installed = True
-    return True
+def assemble_like(global_ref: jax.Array, shards) -> jax.Array:
+    """Rebuild an array laid out like `global_ref` from new per-device
+    shards, each ``jax.device_put`` like `global_ref`'s shard on its device.
+    A single-device array IS its one shard: under a SingleDeviceSharding
+    jax 0.9.0's make_array_from_single_device_arrays (like ``shard.data``)
+    returns a "device"-typed array for pinned_host data, and the next
+    traced host-region gather then refuses to mix it with host ids."""
+    if (isinstance(global_ref.sharding, jax.sharding.SingleDeviceSharding)
+            and len(shards) == 1):
+        return shards[0]
+    return jax.make_array_from_single_device_arrays(
+        global_ref.shape, global_ref.sharding, shards)

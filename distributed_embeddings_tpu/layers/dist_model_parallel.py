@@ -455,15 +455,23 @@ class DistributedEmbedding:
         # decides per exchange group from the static padding accounting
         # (see _use_ragged_exchange). DET_RAGGED_NATIVE overrides the
         # native-vs-emulation op choice (default: native iff TPU backend).
-        # DET_LOOKUP_PATH=tiled must not be silently inert for flows that
-        # never call make_sparse_train_step (inference, dense-grad optax):
-        # __init__ runs eagerly, so validate the kernels on the chip here —
-        # traced forwards then consult the cached verdict
+        # flows that never call make_sparse_train_step (inference,
+        # dense-grad optax) reach the lookup kernels too: __init__ runs
+        # eagerly, so a requested kernel path is checked against the chip
+        # here (compiled vs XLA, raising on a mismatch), and a request
+        # this plan's tables cannot serve is refused before any step
         from distributed_embeddings_tpu.ops.sparse_update import (
             measured_default, prevalidate_active_impl)
-        if measured_default("DET_LOOKUP_PATH", "auto") in ("tiled",
-                                                          "fused"):
+        lookup_path = measured_default("DET_LOOKUP_PATH", "auto")
+        if lookup_path in ("tiled", "fused"):
             prevalidate_active_impl(widths=self.plan_widths())
+        if (lookup_path == "pallas" and use_custom_kernel
+                and pallas_lookup.is_tpu_backend()):
+            for bucket in self.plan.tp_buckets:
+                if bucket.offload and self._offload_enabled:
+                    continue             # host-side lookup, no kernel
+                pallas_lookup.check_lookup_kernel(
+                    max(bucket.rows_max, 1), bucket.width, jnp.float32)
         # mixed precision (reference tests' mixed_precision_policy,
         # dist_model_parallel_test.py:30-34): params stay fp32, the lookup
         # outputs / combines / collectives run in compute_dtype (e.g. bf16).
@@ -506,9 +514,6 @@ class DistributedEmbedding:
         if any(b.offload for b in self.plan.tp_buckets):
             devs = (list(self.mesh.devices.flat) if self.mesh is not None
                     else jax.devices())
-            # pinned_host on TPU; older XLA:CPU only has unpinned_host (its
-            # default space — placement is then a no-op but the whole
-            # offload path still runs, which the CPU test mesh relies on)
             self._host_kind = compat.host_memory_kind(devs[0])
             self._offload_enabled = self._host_kind is not None
             if not self._offload_enabled:
@@ -1276,11 +1281,8 @@ class DistributedEmbedding:
         path = sparse_update_ops.measured_default("DET_LOOKUP_PATH", "auto")
         if path not in ("tiled", "fused") or not self.use_custom_kernel:
             return False
-        if bucket.combiner is None and k != 1:
-            return False       # flatten path; no sorted gather
-        if path == "fused":
-            return sparse_update_ops.pallas_fwd_ok_static(bucket.width)
-        return sparse_update_ops.tiled_fwd_ok_static()
+        # flatten path (no combiner at hotness > 1) has no sorted gather
+        return bucket.combiner is not None or k == 1
 
     def _sort_plan(self, groups, spec) -> List[Optional[str]]:
         """Per exchange group: None (no artifact), "plain" (sid/perm/
@@ -1370,46 +1372,39 @@ class DistributedEmbedding:
             # fused_lookup_combine): one weighted-gather kernel pass +
             # scatter-free unpermute + plain hotness sum, replacing the
             # descriptor-bound XLA table gather AND the separate combine
-            # einsum. Compiled use requires the eager shape-class gate
-            # (prevalidate_active_impl); off-TPU it runs in interpret
-            # mode (tests). The constructor opt-out wins over the knob.
-            from distributed_embeddings_tpu.ops import (pallas_tiled,
-                                                        sparse_update)
+            # einsum. Off-TPU it runs in interpret mode (tests). The
+            # constructor opt-out wins over the knob.
+            from distributed_embeddings_tpu.ops import pallas_tiled
             if not pallas_lookup.is_tpu_backend():
                 _warn_interpret_once("fused")
-            if sparse_update.pallas_kernels_ok(table):
-                w = (weights if weights is not None
-                     else jnp.ones((b_sz, f, k), jnp.float32))
-                ps = None
-                if presorted is not None and presorted.inv is not None:
-                    ps = (presorted.sid, presorted.perm, presorted.inv)
-                out = pallas_tiled.fused_lookup_combine(
-                    table, ids.reshape(b_sz * f, k), w.reshape(b_sz * f, k),
-                    combiner, presorted=ps)
-                return self._cast(out.reshape(b_sz, f, out.shape[-1]))
+            w = (weights if weights is not None
+                 else jnp.ones((b_sz, f, k), jnp.float32))
+            ps = None
+            if presorted is not None and presorted.inv is not None:
+                ps = (presorted.sid, presorted.perm, presorted.inv)
+            out = pallas_tiled.fused_lookup_combine(
+                table, ids.reshape(b_sz * f, k), w.reshape(b_sz * f, k),
+                combiner, presorted=ps)
+            return self._cast(out.reshape(b_sz, f, out.shape[-1]))
         if (path == "tiled" and combiner in ("sum", "mean")
                 and self.use_custom_kernel):
             # round-4 tiled one-hot-matmul gather (ops/pallas_tiled.py):
             # sort + block-streamed table walk, replacing the ~22 ns/row
-            # descriptor-bound XLA row gather. Compiled use requires the
-            # eager hardware validation (prevalidate_active_impl); off-TPU
-            # it runs in interpret mode (tests). Gated on use_custom_kernel
-            # like the pallas path — the constructor opt-out wins over the
-            # env knob (ADVICE r4).
-            from distributed_embeddings_tpu.ops import (pallas_tiled,
-                                                        sparse_update)
+            # descriptor-bound XLA row gather. Off-TPU it runs in interpret
+            # mode (tests). Gated on use_custom_kernel like the pallas path
+            # — the constructor opt-out wins over the env knob (ADVICE r4).
+            from distributed_embeddings_tpu.ops import pallas_tiled
             if not pallas_lookup.is_tpu_backend():
                 _warn_interpret_once("tiled")
-            if sparse_update.tiled_kernels_ok(table):
-                w = (weights if weights is not None
-                     else jnp.ones((b_sz, f, k), jnp.float32))
-                ps = None
-                if presorted is not None and presorted.inv is not None:
-                    ps = (presorted.sid, presorted.perm, presorted.inv)
-                out = pallas_tiled.tiled_embedding_lookup(
-                    table, ids.reshape(b_sz * f, k), w.reshape(b_sz * f, k),
-                    combiner, presorted=ps)
-                return self._cast(out.reshape(b_sz, f, out.shape[-1]))
+            w = (weights if weights is not None
+                 else jnp.ones((b_sz, f, k), jnp.float32))
+            ps = None
+            if presorted is not None and presorted.inv is not None:
+                ps = (presorted.sid, presorted.perm, presorted.inv)
+            out = pallas_tiled.tiled_embedding_lookup(
+                table, ids.reshape(b_sz * f, k), w.reshape(b_sz * f, k),
+                combiner, presorted=ps)
+            return self._cast(out.reshape(b_sz, f, out.shape[-1]))
         want_pallas = (self.use_custom_kernel
                        and pallas_lookup.is_tpu_backend()
                        and combiner in ("sum", "mean")
@@ -1653,10 +1648,10 @@ class DistributedEmbedding:
         default) takes true-splits on the TPU backend when the group's
         padded wire volume exceeds 1.5x its true id volume (static
         accounting, same arithmetic as exchange_padding_report — e.g.
-        tiny/comm_balanced pads 2.54x, jumbo 1.16x). The ragged op's TPU
-        lowering+semantics are hardware-verified (r03 'ragged' stage); a
-        padded-vs-ragged wall-clock A/B needs a real pod and is recorded
-        as pending in docs/round4_notes.md."""
+        tiny/comm_balanced pads 2.54x, jumbo 1.16x). The automatic choice
+        and the native op ran across four v5e chips and matched the
+        one-device run (chip_smoke.py --chips 4, PR 22); the 1.5x
+        threshold itself has no timing behind it (ROADMAP S4)."""
         if world <= 1:
             return False
         mode = os.environ.get("DET_RAGGED_EXCHANGE", "auto")
@@ -3621,20 +3616,17 @@ class DistributedEmbedding:
         default_registry().counter(
             "store/quantized_rows_applied_total").inc(rows_applied)
 
-        def assemble(global_ref, shards):
-            return jax.make_array_from_single_device_arrays(
-                global_ref.shape, global_ref.sharding, shards)
-
         out_state, ai = [], 0
         for i, x in enumerate(state_h):
             if getattr(x, "ndim", 0) >= 1:
-                out_state.append(assemble(x, new_s[ai]))
+                out_state.append(compat.assemble_like(x, new_s[ai]))
                 ai += 1
             else:
                 out_state.append(jax.device_put(
                     jnp.asarray(scalar_after[i], dtype=x.dtype),
                     x.sharding))
-        return (assemble(table_h, new_p), assemble(scale_h, new_sc),
+        return (compat.assemble_like(table_h, new_p),
+                compat.assemble_like(scale_h, new_sc),
                 tuple(out_state))
 
     def _host_bucket_apply_f32(self, b, table_h, state_h, rep, sums, valid,
@@ -3916,15 +3908,11 @@ class DistributedEmbedding:
                 new_s[i].append(
                     jax.device_put(s_np, state_d[i][dev].sharding))
 
-        def assemble(global_ref, shards):
-            return jax.make_array_from_single_device_arrays(
-                global_ref.shape, global_ref.sharding, shards)
-
-        out_table = assemble(table_h, new_t)
+        out_table = compat.assemble_like(table_h, new_t)
         out_state, ai = [], 0
         for i, x in enumerate(state_h):
             if getattr(x, "ndim", 0) >= 1:
-                out_state.append(assemble(x, new_s[ai]))
+                out_state.append(compat.assemble_like(x, new_s[ai]))
                 ai += 1
             else:
                 out_state.append(jax.device_put(

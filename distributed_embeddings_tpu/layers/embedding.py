@@ -103,10 +103,7 @@ class Embedding:
         tests, far too slow for training)."""
         if not self.use_custom_kernel:
             return False
-        try:
-            from distributed_embeddings_tpu.ops import pallas_lookup
-        except ImportError:  # pallas unavailable on this jax build
-            return False
+        from distributed_embeddings_tpu.ops import pallas_lookup
         if os.environ.get("DET_FORCE_PALLAS", "0") == "1":
             return True
         return pallas_lookup.is_tpu_backend()
@@ -171,8 +168,8 @@ class IntegerLookup:
     GPU backend is a cuCollections hash map living in device memory
     (embedding_lookup_kernels.cu:383-516); TPUs have no device-side dynamic
     hash table, so the TPU-native design runs the hash on the TPU-VM host —
-    a C++ open-addressing table (native/hashmap.cpp, loaded via ctypes) with a
-    pure-numpy fallback — and keeps the device side a plain gather. Index 0 is
+    a C++ open-addressing table (native/hashmap.cpp, loaded via ctypes), with
+    a pure-numpy twin on request — and keeps the device side a plain gather. Index 0 is
     reserved for OOV, matching the reference (embedding.py:219-220).
 
     This layer is stateful host-side preprocessing: call it outside jit (like
@@ -188,23 +185,16 @@ class IntegerLookup:
         max_tokens = int(max_tokens)
         self.max_tokens = max_tokens
         self.capacity = max_tokens + 1
-        backend = None
         if use_native is None:
             use_native = os.environ.get("DET_DISABLE_NATIVE", "0") != "1"
         if use_native:
-            try:
-                from distributed_embeddings_tpu.native import hashmap as native_hashmap
-                backend = native_hashmap.NativeIntegerLookup(self.capacity)
-            except Exception as e:  # noqa: BLE001 - fall back to numpy backend
-                import warnings
-                warnings.warn(
-                    "IntegerLookup native backend unavailable "
-                    f"({type(e).__name__}: {e}); falling back to the pure-"
-                    "Python per-key loop — expect orders of magnitude lower "
-                    "keys/sec (host-bound). Set DET_DISABLE_NATIVE=1 to "
-                    "silence.", RuntimeWarning, stacklevel=2)
-                backend = None
-        if backend is None:
+            # builds native/_det_native.so on demand; a failed build raises
+            # (ask for the numpy backend explicitly: use_native=False or
+            # DET_DISABLE_NATIVE=1 — orders of magnitude fewer keys/sec)
+            from distributed_embeddings_tpu.native import (
+                hashmap as native_hashmap)
+            backend = native_hashmap.NativeIntegerLookup(self.capacity)
+        else:
             backend = _NumpyIntegerLookup(self.capacity)
         self._backend = backend
 

@@ -122,16 +122,13 @@ class RawBinaryDataset:
         self._prefetcher = None
         self._fds = None
         if use_native_prefetch:
-            try:
-                from distributed_embeddings_tpu.native import loader
-                import ctypes
-                lib = loader.load()
-                arr = (ctypes.c_char_p * len(self.paths))(
-                    *[p.encode() for p in self.paths])
-                self._prefetcher_lib = lib
-                self._prefetcher = lib.pf_create(arr, len(self.paths), 4)
-            except Exception:  # noqa: BLE001 - fall back to os.pread
-                self._prefetcher = None
+            from distributed_embeddings_tpu.native import loader
+            import ctypes
+            lib = loader.load()    # builds on demand; a failed build raises
+            arr = (ctypes.c_char_p * len(self.paths))(
+                *[p.encode() for p in self.paths])
+            self._prefetcher_lib = lib
+            self._prefetcher = lib.pf_create(arr, len(self.paths), 4)
         if self._prefetcher is None:
             self._fds = [os.open(p, os.O_RDONLY) for p in self.paths]
 

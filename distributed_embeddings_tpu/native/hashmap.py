@@ -56,21 +56,10 @@ class NativeIntegerLookup:
                 self._handle, keys.ctypes.data, keys.size, out.ctypes.data)
         return out
 
-    @property
-    def supports_erase(self) -> bool:
-        """False only with a stale prebuilt .so from before the erasable
-        map (no g++ to rebuild) — then no erase can ever have happened,
-        so the pre-erase export contracts below stay valid too."""
-        return hasattr(self._lib, "il_erase")
-
     def erase(self, keys: np.ndarray) -> np.ndarray:
         """Unbind keys: returns the freed index per key (0 = was not
         bound). Freed indices are reused by later lookup_or_insert calls
         (LIFO) before new indices are minted."""
-        if not self.supports_erase:
-            raise NotImplementedError(
-                "native _det_native.so predates il_erase and could not be "
-                "rebuilt; rebuild with g++ or use the numpy backend")
         keys = np.ascontiguousarray(keys, dtype=np.int64)
         out = np.empty(keys.shape, dtype=np.int64)
         with self._call_lock:
@@ -81,8 +70,6 @@ class NativeIntegerLookup:
     def free_slots(self) -> np.ndarray:
         """Erased (reusable) indices, in reuse order — the binding-table
         free-list the vocab checkpoint round-trips."""
-        if not self.supports_erase:
-            return np.empty((0,), np.int64)
         with self._call_lock:
             n = int(self._lib.il_free_count(self._handle))
             out = np.empty((n,), dtype=np.int64)
@@ -96,9 +83,7 @@ class NativeIntegerLookup:
         # high-water sized (== size pre-erase); erased indices hole as
         # INT64_MIN and are kept so positions stay 1-based-index-aligned.
         with self._call_lock:
-            n = (int(self._lib.il_high_water(self._handle))
-                 if self.supports_erase
-                 else int(self._lib.il_size(self._handle)))
+            n = int(self._lib.il_high_water(self._handle))
             out = np.empty((n,), dtype=np.int64)
             if n:
                 self._lib.il_export_keys(self._handle, out.ctypes.data)

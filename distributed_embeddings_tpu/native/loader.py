@@ -9,6 +9,8 @@ _LIB = None
 _LOCK = threading.Lock()
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "_det_native.so")
+SOURCES = ("hashmap.cpp", "io.cpp", "host_apply.cpp")
+CXXFLAGS = ("-O3", "-std=c++17", "-fPIC", "-shared", "-Wall", "-pthread")
 
 
 def load():
@@ -16,32 +18,24 @@ def load():
     with _LOCK:
         if _LIB is not None:
             return _LIB
-        srcs = [os.path.join(_DIR, f)
-                for f in ("hashmap.cpp", "io.cpp", "host_apply.cpp")]
-        have_so = os.path.exists(_SO)
-        # missing sources (stripped install) are NOT stale — use the .so
-        stale = (not have_so
-                 or (all(os.path.exists(s) for s in srcs)
-                     and any(os.path.getmtime(s) > os.path.getmtime(_SO)
-                             for s in srcs)))
-        if stale:
+        srcs = [os.path.join(_DIR, f) for f in SOURCES]
+        if (not os.path.exists(_SO)
+                or any(os.path.getmtime(s) > os.path.getmtime(_SO)
+                       for s in srcs)):
             # build to a temp name + atomic rename: concurrent processes
-            # (multi-process tests) must never dlopen a half-written .so
+            # (multi-process tests) must never dlopen a half-written .so.
+            # A failed build raises: a stale library or a slower stand-in
+            # would hide it. Keep native/Makefile's command equal to this.
             tmp = f"{_SO}.build.{os.getpid()}"
-            cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-Wall",
-                   "-pthread", *srcs, "-o", tmp]
+            cmd = ["g++", *CXXFLAGS, *srcs, "-o", tmp]
             try:
-                subprocess.run(cmd, check=True, capture_output=True)
-                os.replace(tmp, _SO)
-            except Exception:
-                # rebuild of a newer source failed (no g++?): a prebuilt
-                # .so still beats the numpy fallback — warn and use it
-                if not have_so:
-                    raise
-                import warnings
-                warnings.warn(
-                    "native rebuild failed; using the existing (possibly "
-                    "stale) _det_native.so", RuntimeWarning, stacklevel=2)
+                subprocess.run(cmd, check=True, capture_output=True,
+                               text=True)
+            except subprocess.CalledProcessError as e:
+                raise RuntimeError(
+                    f"building {_SO} failed ({' '.join(cmd)}):\n"
+                    f"{e.stderr[-2000:]}") from e
+            os.replace(tmp, _SO)
         lib = ctypes.CDLL(_SO)
 
         i64 = ctypes.c_int64
@@ -55,15 +49,13 @@ def load():
         lib.il_lookup.argtypes = [p, ctypes.c_void_p, i64, ctypes.c_void_p]
         lib.il_export_keys.argtypes = [p, ctypes.c_void_p]
         lib.il_export_counts.argtypes = [p, ctypes.c_void_p]
-        # erase/free-slot surface (ISSUE 7): a prebuilt .so from before
-        # the erasable map may lack these — wrappers hasattr-guard
-        if hasattr(lib, "il_erase"):
-            lib.il_erase.argtypes = [p, ctypes.c_void_p, i64, ctypes.c_void_p]
-            lib.il_high_water.restype = i64
-            lib.il_high_water.argtypes = [p]
-            lib.il_free_count.restype = i64
-            lib.il_free_count.argtypes = [p]
-            lib.il_export_free.argtypes = [p, ctypes.c_void_p]
+        # erase/free-slot surface (ISSUE 7)
+        lib.il_erase.argtypes = [p, ctypes.c_void_p, i64, ctypes.c_void_p]
+        lib.il_high_water.restype = i64
+        lib.il_high_water.argtypes = [p]
+        lib.il_free_count.restype = i64
+        lib.il_free_count.argtypes = [p]
+        lib.il_export_free.argtypes = [p, ctypes.c_void_p]
 
         lib.pf_create.restype = p
         lib.pf_create.argtypes = [ctypes.POINTER(ctypes.c_char_p), i64, i64]
@@ -74,15 +66,11 @@ def load():
         lib.pf_read.restype = i64
         lib.pf_read.argtypes = [p, i64, i64, i64, ctypes.c_void_p]
 
-        # a prebuilt .so from before host_apply.cpp may lack these symbols
-        # (stripped install with no g++): keep il_*/pf_* usable and let the
-        # host-apply wrapper fall back to numpy
-        if hasattr(lib, "ha_sgd"):
-            f32 = ctypes.c_float
-            lib.ha_sgd.argtypes = [p, i64, p, p, p, i64, f32]
-            lib.ha_adagrad.argtypes = [p, p, i64, p, p, p, i64, f32, f32]
-            lib.ha_adam.argtypes = [p, p, p, i64, p, p, p, i64, f32, f32,
-                                    f32, f32, f32, f32]
+        f32 = ctypes.c_float
+        lib.ha_sgd.argtypes = [p, i64, p, p, p, i64, f32]
+        lib.ha_adagrad.argtypes = [p, p, i64, p, p, p, i64, f32, f32]
+        lib.ha_adam.argtypes = [p, p, p, i64, p, p, p, i64, f32, f32,
+                                f32, f32, f32, f32]
 
         _LIB = lib
         return _LIB

@@ -630,8 +630,8 @@ def ragged_exchange(operand, output, in_off, send_sz, out_off, recv_sz,
     semantics-exact emulation from equal-shaped collectives (all_gather +
     masked gather) so the FULL exchange path — metadata, layouts,
     reassembly — is executable and equivalence-tested on the CPU mesh;
-    only the op itself differs, and that op is validated on hardware by
-    the 'ragged' stage of tools/tpu_validate.py.
+    only the op itself differs, and that op runs on the chips in
+    `chip_smoke.py --chips 4` (tools/tpu_ragged_check.py isolates it).
 
     The OPERAND must already be wire-encoded by the caller (the bucket's
     float or id format); the emulation's three metadata all_gathers move

@@ -68,7 +68,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from distributed_embeddings_tpu import faults
+from distributed_embeddings_tpu import compat, faults
 from distributed_embeddings_tpu.obs.trace import default_recorder
 from distributed_embeddings_tpu.ops import sparse_update as sparse_update_ops
 from distributed_embeddings_tpu.ops import wire as wire_ops
@@ -183,6 +183,8 @@ def _host_set_rows(table_h, w_idx: np.ndarray, r_idx: np.ndarray,
     through the `host_apply_rows_inplace` seam (kind='set') — the same
     XLA-free path the offloaded sparse apply uses, so only the delta rows
     ever cross a memory boundary."""
+    if not len(w_idx):
+        return table_h
     new_shards = []
     for sh in table_h.addressable_shards:
         start = sh.index[0].start or 0
@@ -211,8 +213,7 @@ def _host_set_rows(table_h, w_idx: np.ndarray, r_idx: np.ndarray,
                     # set is the same rows-only write at these dtypes
                     t_np[j][r_idx[m]] = np.asarray(rows[m], t_np.dtype)
         new_shards.append(jax.device_put(t_np, sh.data.sharding))
-    return jax.make_array_from_single_device_arrays(
-        table_h.shape, table_h.sharding, new_shards)
+    return compat.assemble_like(table_h, new_shards)
 
 
 _FILE_RE = re.compile(r"^stream_v(\d{8})_(delta|snapshot)\.npz$")

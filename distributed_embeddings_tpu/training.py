@@ -123,13 +123,9 @@ def apply_updates(params, updates):
 
 def default_donate() -> bool:
     """Default for the train-step factories' ``donate`` argument:
-    ``DET_STEP_DONATE`` (unset/'1' -> True). The escape hatch exists for
-    environments where donated executables cannot be trusted end to end —
-    tests/conftest.py sets '0' because jaxlib 0.4.36 XLA:CPU intermittently
-    mis-executes DONATED executables loaded from the persistent
-    compilation cache (see compat.install_cpu_donation_cache_guard);
-    undonated steps are numerically identical, they just update out of
-    place."""
+    ``DET_STEP_DONATE`` (unset/'1' -> True). '0' builds steps that update
+    out of place — numerically identical, and twice the table memory —
+    for a caller that must keep reading the arrays it passed in."""
     return os.environ.get("DET_STEP_DONATE", "1") != "0"
 
 
@@ -348,7 +344,10 @@ def make_sparse_train_step(model, optimizer: str = "adagrad", lr=0.01,
         for b, pend in pending.items():
             rep, sums, valid = pend[0], pend[1], pend[2]
             lr_t = pend[3] if len(pend) > 3 else None
-            scale_b = (tp_scale[b] if tp_scale is not None else None)
+            # the INPUT scale leaf: the step's output slot for a host
+            # bucket is a zeroed placeholder (see drain_sparse_apply)
+            scale_b = (params["embedding"]["tp_scale"][b]
+                       if tp_scale is not None else None)
             out = emb.host_bucket_apply(
                 b, params["embedding"]["tp"][b], opt_state["emb"]["tp"][b],
                 rep, sums, valid, sopt, lr_value=lr_t, scale_h=scale_b)

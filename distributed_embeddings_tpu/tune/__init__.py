@@ -15,8 +15,8 @@ closes the measure->decide loop (ROADMAP item 5):
             harness all read.
   resolve   the consumption seam: `knob_value(env, fallback)` resolves
             env var > tools/tuned/<workload>.json (explicit opt-in via
-            DET_TUNED_WORKLOAD / DET_TUNED_PATH) >
-            tools/measured_defaults.json (TPU-backend only) > fallback,
+            DET_TUNED_WORKLOAD / DET_TUNED_PATH) > the file
+            DET_MEASURED_DEFAULTS_PATH names (TPU-backend only) > fallback,
             every tuned/measured adoption leaving a flight-recorder
             event. `ops.sparse_update.measured_default` delegates here.
   search    bench-independent search machinery for `bench.py --mode

@@ -12,9 +12,11 @@ helpers):
      against the repo's tools/tuned/) or ``DET_TUNED_PATH=<file>``.
      Explicit opt-in keeps CPU test equivalence: no env, no silent
      behavior change because a tuner ran on the same checkout;
-  3. ``tools/measured_defaults.json`` (the PR-2 seed of this machinery,
-     now subsumed): consulted only on the TPU backend, or anywhere
-     under ``DET_MEASURED_DEFAULTS_CONSULT=1`` (the rehearsal knob);
+  3. a measured-defaults file (the PR-2 seed of this machinery), ONLY
+     where ``DET_MEASURED_DEFAULTS_PATH`` names one: consulted on the
+     TPU backend, or anywhere under ``DET_MEASURED_DEFAULTS_CONSULT=1``.
+     Nothing is looked up inside the checkout: a file that git does not
+     hold cannot change what a run dispatches to;
   4. the hand-picked ``fallback``.
 
 Every adoption from layer 2 or 3 lands a flight-recorder instant
@@ -133,11 +135,12 @@ def _load_tuned_locked() -> Dict[str, str]:
 
 
 def _load_measured_locked() -> Dict[str, str]:
-    """tools/measured_defaults.json in its historical shape: flat
-    {env: value-or-{value, provenance...}}; absent/invalid = {}."""
-    path = os.environ.get(
-        "DET_MEASURED_DEFAULTS_PATH",
-        os.path.join(_ROOT, "tools", "measured_defaults.json"))
+    """The file DET_MEASURED_DEFAULTS_PATH names, in its historical shape:
+    flat {env: value-or-{value, provenance...}}; unset/absent/invalid =
+    {}."""
+    path = os.environ.get("DET_MEASURED_DEFAULTS_PATH")
+    if not path:
+        return {}
     try:
         with open(path) as f:
             raw = json.load(f)

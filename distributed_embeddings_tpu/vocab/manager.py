@@ -144,19 +144,6 @@ class ManagedVocab:
         self.slack = int(slack)
         self.binding = IntegerLookup(max_tokens=capacity - 1,
                                      use_native=use_native)
-        if self.binding.native and not getattr(
-                self.binding._backend, "supports_erase", True):
-            # stale prebuilt .so from before the erasable map (no g++ to
-            # rebuild): erase would raise at the FIRST eviction, hours
-            # into a run — fall back to the numpy binding now instead
-            import warnings
-            warnings.warn(
-                "native _det_native.so predates il_erase and could not "
-                "be rebuilt; vocab binding falls back to the numpy "
-                "backend (slower translation, identical semantics)",
-                RuntimeWarning, stacklevel=3)
-            self.binding = IntegerLookup(max_tokens=capacity - 1,
-                                         use_native=False)
         self.tracker = HotnessTracker(
             capacity=capacity - 1, promote_threshold=admit_threshold,
             decay=decay)

@@ -72,7 +72,6 @@ def main(argv=None):
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count={n}").strip()
 
-    import numpy as np
     import jax
     import jax.numpy as jnp
     import optax
@@ -80,6 +79,9 @@ def main(argv=None):
     if args.force_cpu:
         jax.config.update("jax_platforms", "cpu")
 
+    from distributed_embeddings_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()
     from distributed_embeddings_tpu.models.synthetic import (
         SYNTHETIC_MODELS, SyntheticModel, InputGenerator)
     from distributed_embeddings_tpu.parallel.mesh import create_mesh
@@ -121,25 +123,29 @@ def main(argv=None):
         # [V, w] grads, no full-table optimizer pass)
         from distributed_embeddings_tpu.training import make_sparse_train_step
         init_fn, step_fn = make_sparse_train_step(
-            model, args.optimizer, lr=args.lr, donate=False,
+            model, args.optimizer, lr=args.lr,
             strategy=args.sparse_strategy)
         opt_state = init_fn(params)
     else:
         opt = {"sgd": optax.sgd, "adagrad": optax.adagrad,
                "adam": optax.adam}[args.optimizer](args.lr)
         opt_state = opt.init(params)
-        step_fn = make_train_step(model.loss_fn, opt, donate=False)
+        step_fn = make_train_step(model.loss_fn, opt)
 
     gen = InputGenerator(cfg, args.batch_size, alpha=args.alpha,
                          num_batches=args.num_data_batches, seed=args.seed)
 
-    batches = [(params, opt_state, gen[i][0], to_model_inputs(gen[i][1]),
-                gen[i][2]) for i in range(len(gen))]
+    batches = [(gen[i][0], to_model_inputs(gen[i][1]), gen[i][2])
+               for i in range(len(gen))]
 
+    # the state is threaded through the timed calls and donated (the
+    # library default): out of place, a step holds two copies of the
+    # tables and accumulators — 16.8 GiB for tiny on a 16 GB chip
     ctx = mesh if mesh is not None else nullcontext()
     with ctx:
-        res = profiling.benchmark_batches(step_fn, batches, iters=args.steps,
-                                          warmup=args.warmup_steps)
+        res, params, opt_state = profiling.benchmark_train_steps(
+            step_fn, params, opt_state, batches, iters=args.steps,
+            warmup=args.warmup_steps)
     print(f"step time: {res}", flush=True)
     print(f"throughput: {args.batch_size / res.mean_s:,.0f} samples/sec",
           flush=True)

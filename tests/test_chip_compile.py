@@ -1,0 +1,231 @@
+"""Compiles for a DESCRIBED TPU v5e — no chip attached, nothing runs.
+
+The installed TPU compiler builds programs for a chip that is described and
+not attached (`on-chip-measurement` guide, section 2). Interpret mode cannot
+see what it refuses: block shapes that are not tiling-legal, DMA semaphore
+and scalar memory that overflow, row slices the HBM tiling cannot address, a
+step that does not fit the chip's memory. These cases hold every Pallas
+entry point a dispatch on a TPU backend can select to that compiler at the
+widths the models use (16 and 128, at the models' batch of 65,536), and the
+full-size Tiny V3 Adagrad step to the chip's 16 GB. A kernel that cannot be
+made legal at a width must be refused by name before any step runs, and the
+case pins that refusal instead.
+
+A compile that passes here is not a chip run: `chip_smoke.py` is.
+
+This is the ONLY file that describes the chip, and it does so inside a
+module-scoped fixture: one process at a time may hold the TPU library, the
+suite runs under several workers, and only the worker that is handed this
+file may load it. Nothing here touches the topology at import, in a skipif
+or in a parametrize argument.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributed_embeddings_tpu.ops import (pallas_lookup, pallas_scatter,
+                                            pallas_tiled)
+
+BATCH = 65536
+HBM_BYTES = 16 * 10 ** 9        # one TPU v5e chip (Google Cloud, "TPU v5e")
+F32, I32 = jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TPU_LOG_DIR", "disabled")   # or the compiler logs under /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        mp.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: every later run would warn
+    # and compile again, so the cache is off around these cases
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _vocab(width):
+    return 100_000 if width == 128 else 1_000_000
+
+
+def _lookup_args(S, width, vocab, hot=10):
+    return (S((vocab, width), F32), S((BATCH, hot), I32),
+            S((BATCH, hot), F32))
+
+
+def _stream_args(S, width, n=BATCH):
+    """(table, sorted ids / rep, permutation, rows) of an update stream."""
+    return (S((_vocab(width), width), F32), S((n,), I32), S((n,), I32),
+            S((n, width), F32))
+
+
+# name -> (S, width) -> (fn, args): the entry point called with
+# interpret=False, exactly as a TPU dispatch calls it. The tiled update
+# kernels take the caller's sort (`presorted`), as the folded train step
+# passes it; a fresh 65,536-key XLA sort only adds ten seconds of compile.
+def _onehot(S, width):
+    return (lambda t, i, w: pallas_lookup._onehot_lookup(
+        t, i, w, interpret=False)), _lookup_args(S, width, 1000)
+
+
+def _dma_gather(S, width):
+    pallas_lookup.check_lookup_kernel(_vocab(width), width, F32)
+    return (lambda t, i, w: pallas_lookup._dma_gather_lookup(
+        t, i, w, interpret=False)), _lookup_args(S, width, _vocab(width))
+
+
+def _tiled_gather(S, width):
+    t, sid, _, _ = _stream_args(S, width)
+    return (lambda t, s: pallas_tiled.tiled_gather_sorted(
+        t, s, interpret=False)), (t, sid)
+
+
+def _tiled_gather_weighted(S, width):
+    t, sid, _, _ = _stream_args(S, width)
+    return (lambda t, s, w: pallas_tiled.tiled_gather_sorted_weighted(
+        t, s, w, interpret=False)), (t, sid, S((BATCH,), F32))
+
+
+def _tiled_sgd(S, width):
+    return (lambda t, s, p, r: pallas_tiled.tiled_sgd(
+        t, s, r, 0.01, interpret=False, presorted=(s, p))
+    ), _stream_args(S, width)
+
+
+def _tiled_adagrad(S, width):
+    t, sid, perm, rows = _stream_args(S, width)
+    return (lambda t, a, s, p, r: pallas_tiled.tiled_adagrad(
+        t, a, s, r, 0.01, interpret=False, presorted=(s, p))
+    ), (t, t, sid, perm, rows)
+
+
+def _tiled_adam(S, width):
+    t, sid, perm, rows = _stream_args(S, width)
+    return (lambda t, m, v, c, s, p, r: pallas_tiled.tiled_adam(
+        t, m, v, c, s, r, 0.01, interpret=False, presorted=(s, p))
+    ), (t, t, t, S((), I32), sid, perm, rows)
+
+
+def _tiled_sgd_rows(S, width):
+    t, rep, _, sums = _stream_args(S, width)
+    return (lambda t, r, s: pallas_tiled.tiled_sgd_rows(
+        t, r, s, 0.01, interpret=False)), (t, rep, sums)
+
+
+def _tiled_adagrad_rows(S, width):
+    t, rep, _, sums = _stream_args(S, width)
+    return (lambda t, a, r, s: pallas_tiled.tiled_adagrad_rows(
+        t, a, r, s, 0.01, interpret=False)), (t, t, rep, sums)
+
+
+def _tiled_adam_rows(S, width):
+    t, rep, _, sums = _stream_args(S, width)
+    return (lambda t, m, v, c, r, s: pallas_tiled.tiled_adam_rows(
+        t, m, v, c, r, s, 0.01, interpret=False)
+    ), (t, t, t, S((), I32), rep, sums)
+
+
+def _dma_scatter(S, width):
+    # a DLRM-sized update: 26 features x 65,536 deduped rows
+    t, rep, _, sums = _stream_args(S, width, n=26 * BATCH)
+    return (lambda t, r, s: pallas_scatter.scatter_add_sorted_unique(
+        t, r, s, interpret=False)), (t, rep, sums)
+
+
+def _dma_adagrad(S, width):
+    t, rep, _, sums = _stream_args(S, width, n=26 * BATCH)
+    return (lambda t, a, r, s: pallas_scatter.adagrad_rows_sorted_unique(
+        t, a, r, s, 0.01, interpret=False)), (t, t, rep, sums)
+
+
+KERNELS = {f.__name__.lstrip("_"): f for f in (
+    _onehot, _dma_gather, _tiled_gather, _tiled_gather_weighted, _tiled_sgd,
+    _tiled_adagrad, _tiled_adam, _tiled_sgd_rows, _tiled_adagrad_rows,
+    _tiled_adam_rows, _dma_scatter, _dma_adagrad)}
+# the per-row DMA kernels address only float32 rows of width 128
+# (pallas_lookup._ROW_DMA_WIDTH): at 16 their dispatch must raise
+REFUSED = {("dma_gather", 16), ("dma_scatter", 16), ("dma_adagrad", 16)}
+
+
+def _tiny_v3_adagrad_step(one_chip, monkeypatch):
+    """The program `chip_smoke.py` trains: Tiny V3 as published, sparse
+    Adagrad, batch 65,536, donated. The library asks `jax.default_backend()`
+    where a TPU takes another branch than the CPU (lookup dispatch, kernel
+    interpret mode), and here it would see the CPU: steer it, in the test,
+    so that what compiles is what the chip is given."""
+    from distributed_embeddings_tpu.models.synthetic import (
+        SYNTHETIC_MODELS, SyntheticModel, expand_embedding_configs)
+    from distributed_embeddings_tpu.training import make_sparse_train_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pallas_tiled, "_BACKEND_INTERPRET", None)
+    cfg = SYNTHETIC_MODELS["tiny"]
+    model = SyntheticModel(cfg, mesh=None, distributed=True,
+                           strategy="memory_balanced")
+    init_fn, step_fn = make_sparse_train_step(model, "adagrad", lr=0.01)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    opt_state = jax.eval_shape(init_fn, params)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: S(s.shape, s.dtype), tree)
+
+    _, _, hotness = expand_embedding_configs(cfg)
+    compiled = jax.jit(step_fn, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt_state),
+        S((BATCH, cfg.num_numerical_features), F32),
+        [S((BATCH, h), I32) for h in hotness], S((BATCH, 1), F32)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    tables = sum(r * w for c in cfg.embedding_configs
+                 for r, w in [(c.num_rows, c.width)] * c.num_tables) * 4
+    # tables + accumulators are arguments, and all of them are donated
+    assert m.argument_size_in_bytes >= 2 * tables
+    assert m.alias_size_in_bytes >= 2 * tables
+    assert live < HBM_BYTES, (
+        f"Tiny V3 step needs {live / 2**30:.2f} GiB of a 16 GB chip")
+
+
+@pytest.mark.parametrize(
+    "kernel,width",
+    [(k, w) for k in KERNELS for w in (16, 128)] + [("tiny_v3_step", None)],
+    ids=lambda v: str(v))
+def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
+    if kernel == "tiny_v3_step":
+        _tiny_v3_adagrad_step(one_chip, monkeypatch)
+        return
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def compile_it():
+        fn, args = KERNELS[kernel](S, width)
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+    if (kernel, width) in REFUSED:
+        with pytest.raises(ValueError, match=f"width {width}"):
+            compile_it()
+    else:
+        compile_it()

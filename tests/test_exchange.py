@@ -415,8 +415,8 @@ def test_ragged_exchange_equivalence(monkeypatch):
     explicit (ids, weights) all ride the exchange (ragged/sparse inputs
     synthesize mask weights, so the weight exchange is load-bearing for
     exactly the workloads the padding problem is about). Metadata, layout
-    and reassembly are the parts the CPU can prove; the op itself is
-    validated on hardware by tools/tpu_ragged_check.py."""
+    and reassembly are the parts the CPU can prove; the op itself runs
+    on the chips in `chip_smoke.py --chips 4`."""
     from distributed_embeddings_tpu.ops.embedding_ops import RaggedIds
 
     rng = np.random.RandomState(17)

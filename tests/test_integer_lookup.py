@@ -150,27 +150,23 @@ def test_io_callback_under_jit():
     assert out[0] == out[1] != out[2]
 
 
-def test_native_build_failure_warns(monkeypatch):
-    """A broken native build must be loud (VERDICT r2 weak 5): the criteo
-    pipeline silently becoming host-bound is the failure mode."""
-    import builtins
-    import warnings
+def test_native_build_failure_raises(monkeypatch, tmp_path):
+    """A broken native build is an error, not a quiet switch to the
+    per-key Python loop (VERDICT r2 weak 5: the criteo pipeline silently
+    becoming host-bound is the failure mode): the loader raises with the
+    compiler's message, and the numpy backend stays available on
+    request."""
+    from distributed_embeddings_tpu.native import loader
 
-    real_import = builtins.__import__
-
-    def broken(name, *a, **kw):
-        if "native" in name and "hashmap" in str(a) + name:
-            raise OSError("simulated compiler failure")
-        return real_import(name, *a, **kw)
-
-    monkeypatch.setattr(builtins, "__import__", broken)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        layer = IntegerLookup(max_tokens=10)
+    bad = tmp_path / "broken.cpp"
+    bad.write_text("this is not C++\n")
+    monkeypatch.setattr(loader, "_LIB", None)
+    monkeypatch.setattr(loader, "_SO", str(tmp_path / "_det_native.so"))
+    monkeypatch.setattr(loader, "SOURCES", (str(bad),))
+    with pytest.raises(RuntimeError, match="building .* failed"):
+        IntegerLookup(max_tokens=10)
+    layer = IntegerLookup(max_tokens=10, use_native=False)
     assert not layer.native
-    assert any("falling back to the pure-Python" in str(x.message)
-               for x in w), [str(x.message) for x in w]
-    # fallback still functions
     assert layer(np.array([5, 5, 9])).tolist() == [1, 1, 2]
 
 

@@ -1,14 +1,13 @@
-"""measured_default: bench-written hardware defaults for DET_* knobs.
+"""measured_default: measured hardware defaults for DET_* knobs.
 
-bench.py persists winning A/B knob values to tools/measured_defaults.json
-(decision rule 5, docs/perf_model.md); the dispatch reads them as the
-TPU-backend default. Env always overrides; CPU backends never consult the
-file (test equivalence must not change because a TPU bench ran).
+The dispatch reads the file DET_MEASURED_DEFAULTS_PATH names, if it names
+one, as the TPU-backend default. Env always overrides; CPU backends never
+consult the file; nothing inside the checkout is read.
 
 Since ISSUE 18 `measured_default` delegates to `tune.resolve.knob_value`
 (env > tuned config > measured defaults > fallback) — the tests here
-cover the measured-defaults layer and the bench writer; the tuned layer
-is tests/test_tune.py's."""
+cover the measured-defaults layer and the bench's isolation from it; the
+tuned layer is tests/test_tune.py's."""
 
 import json
 
@@ -80,97 +79,23 @@ def _load_bench():
     return bench
 
 
-def _winning_record(**overrides):
-    """A record where the tiled family wins BOTH workloads with > 3%
-    margin on every arm (the flip-eligible shape)."""
-    rec = {"tiny_best_path": "tiled-fwd+bwd",
-           "dlrm_best_path": "tiled-fwd+bwd",
-           "git_sha": "deadbeef", "value": 90.0,
-           "dlrm_samples_per_sec": 2.6e6,
-           "tiny_ab_default_ms": 100.0, "tiny_ab_cumsum_ms": 101.0,
-           "tiny_ab_tiled_ms": 95.0, "tiny_ab_tiled_full_ms": 90.0,
-           "dlrm_ab_sort_ms": 50.0, "dlrm_ab_dense_ms": 52.0,
-           "dlrm_ab_tiled_ms": 47.0, "dlrm_ab_tiled_full_ms": 45.0}
-    rec.update(overrides)
-    return rec
-
-
-def test_bench_writer_round_trip(tmp_path, monkeypatch):
-    """bench._maybe_write_measured_defaults with agreeing winners on BOTH
-    workloads AND a >= 3% margin on each writes the file the library reads
-    back; anything less flips nothing."""
-    bench = _load_bench()
-    out = tmp_path / "measured_defaults.json"
-    monkeypatch.setattr(bench, "_MEASURED_DEFAULTS_PATH", str(out))
-
-    class _FakeDev:
-        platform = "tpu"
-
-    monkeypatch.setattr(bench.jax, "devices", lambda: [_FakeDev()])
-    record = _winning_record()
-    bench._maybe_write_measured_defaults(record)
-    assert record["measured_defaults_written"] == {
-        "DET_SCATTER_IMPL": "tiled", "DET_LOOKUP_PATH": "tiled"}
-    data = json.loads(out.read_text())
-    assert data["DET_SCATTER_IMPL"]["value"] == "tiled"
-    assert data["DET_LOOKUP_PATH"]["value"] == "tiled"
-    assert data["DET_SCATTER_IMPL"]["git_sha"] == "deadbeef"
-    # ADVICE r5: the margin is part of the evidence block
-    margins = data["DET_SCATTER_IMPL"]["evidence"]["margins"]
-    assert margins["tiny_scatter"] == pytest.approx(100 / 90, abs=1e-3)
-    assert margins["dlrm_lookup"] == pytest.approx(50 / 45, abs=1e-3)
-    assert data["DET_SCATTER_IMPL"]["evidence"][
-        "min_margin_required"] == bench.MEASURED_DEFAULTS_MIN_MARGIN
-
-    # disagreeing winners flip nothing
-    record2 = {"tiny_best_path": "default(xla)",
-               "dlrm_best_path": "tiled-onehot-matmul", "git_sha": "x"}
-    bench._maybe_write_measured_defaults(record2)
-    assert "measured_defaults_written" not in record2
-
-    # a MISSING workload (dlrm errored) must not weaken the rule to
-    # single-workload agreement
-    record3 = {"tiny_best_path": "tiled-onehot-matmul", "git_sha": "x"}
-    bench._maybe_write_measured_defaults(record3)
-    assert "measured_defaults_written" not in record3
-
-    # cumsum wall-clock wins never auto-flip numerics defaults
-    record4 = {"tiny_best_path": "xla+cumsum-dedup",
-               "dlrm_best_path": "cumsum", "git_sha": "x"}
-    bench._maybe_write_measured_defaults(record4)
-    assert "measured_defaults_written" not in record4
-
-
-def test_bench_writer_requires_margin(tmp_path, monkeypatch):
-    """ADVICE r5: a within-noise win (< 3% on either workload) or missing
-    arm timings must not persist a defaults flip."""
-    bench = _load_bench()
-    out = tmp_path / "measured_defaults.json"
-    monkeypatch.setattr(bench, "_MEASURED_DEFAULTS_PATH", str(out))
-
-    class _FakeDev:
-        platform = "tpu"
-
-    monkeypatch.setattr(bench.jax, "devices", lambda: [_FakeDev()])
-
-    # 1.001x "win" on dlrm: no flip at all
-    rec = _winning_record(dlrm_ab_tiled_ms=49.96, dlrm_ab_tiled_full_ms=49.95)
-    bench._maybe_write_measured_defaults(rec)
-    assert "measured_defaults_written" not in rec
-    assert not out.exists()
-
-    # scatter margin clears on both, but the fwd+bwd arm is within noise on
-    # tiny: only DET_SCATTER_IMPL flips
-    rec = _winning_record(tiny_ab_tiled_full_ms=98.0, tiny_ab_tiled_ms=90.0)
-    bench._maybe_write_measured_defaults(rec)
-    assert rec["measured_defaults_written"] == {"DET_SCATTER_IMPL": "tiled"}
-
-    # winner labels without the arm timings (older cached record shape):
-    # margins cannot be computed -> no flip
-    rec = {"tiny_best_path": "tiled-fwd+bwd",
-           "dlrm_best_path": "tiled-fwd+bwd", "git_sha": "x"}
-    bench._maybe_write_measured_defaults(rec)
-    assert "measured_defaults_written" not in rec
+def test_nothing_in_the_checkout_is_read(tmp_path, monkeypatch):
+    """Without DET_MEASURED_DEFAULTS_PATH there is no measured-defaults
+    layer at all: a tools/measured_defaults.json left in the checkout by
+    an old bench run (git never held it) must not change what a TPU run
+    dispatches to — and bench.py no longer has a writer for it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("DET_MEASURED_DEFAULTS_PATH", raising=False)
+    monkeypatch.delenv("DET_SCATTER_IMPL", raising=False)
+    stray = tmp_path / "tools"
+    stray.mkdir()
+    (stray / "measured_defaults.json").write_text(
+        json.dumps({"DET_SCATTER_IMPL": "tiled"}))
+    monkeypatch.setattr(tune_resolve, "_ROOT", str(tmp_path))
+    tune_resolve.reset_cache()
+    assert sparse_update.measured_default("DET_SCATTER_IMPL", "xla") == "xla"
+    tune_resolve.reset_cache()
+    assert not hasattr(_load_bench(), "_maybe_write_measured_defaults")
 
 
 def test_bench_isolation_pins_reader(tmp_path, monkeypatch):

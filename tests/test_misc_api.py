@@ -183,45 +183,21 @@ def test_gather_global_chunked_device_bucket(monkeypatch):
     np.testing.assert_array_equal(out, np.asarray(arr))
 
 
-def test_donation_cache_guard_skips_donated_modules(tmp_path):
-    """The conftest-installed persistent-cache guard (compat.py: jaxlib
-    0.4.36 XLA:CPU mis-executes cache-LOADED donated executables) must
-    keep donated modules out of the cache while undonated ones still
-    cache. Functional check against a throwaway cache dir."""
-    from jax._src import compilation_cache
-    from distributed_embeddings_tpu import compat
+def test_compile_cache_dir_comes_from_outside_or_the_checkout(monkeypatch):
+    """`enable_compile_cache`: a directory given through
+    JAX_COMPILATION_CACHE_DIR is returned untouched and NO directory is
+    set in code; without it the cache is <checkout>/.jax_cache."""
+    import os
+    from distributed_embeddings_tpu.utils import compile_cache
 
-    assert compat.install_cpu_donation_cache_guard()
-
-    cache_dir = str(tmp_path / "jaxcache")
-    cfg = jax.config
-    old_dir = cfg.jax_compilation_cache_dir
-    old_min_time = cfg.jax_persistent_cache_min_compile_time_secs
-    try:
-        cfg.update("jax_compilation_cache_dir", cache_dir)
-        cfg.update("jax_persistent_cache_min_compile_time_secs", 0)
-        # the cache object binds its directory on first use; rebind it
-        # to the throwaway dir for the duration of this test
-        compilation_cache.reset_cache()
-
-        import os
-        os.makedirs(cache_dir, exist_ok=True)  # nothing may cache at all
-        donated = jax.jit(lambda a, b: (a * 2 + b, b + 1),
-                          donate_argnums=(0,))
-        donated(jnp.arange(1024, dtype=jnp.float32),
-                jnp.ones(1024, jnp.float32))
-        entries = {e.split("-")[0] for e in os.listdir(cache_dir)
-                   if e.endswith("-cache")}
-        assert "jit__lambda_" not in entries, entries
-
-        undonated = jax.jit(lambda a, b: (a * 3 - b, b - 1))
-        undonated(jnp.arange(1024, dtype=jnp.float32),
-                  jnp.ones(1024, jnp.float32))
-        entries = {e.split("-")[0] for e in os.listdir(cache_dir)
-                   if e.endswith("-cache")}
-        assert "jit__lambda_" in entries, entries
-    finally:
-        cfg.update("jax_compilation_cache_dir", old_dir)
-        cfg.update("jax_persistent_cache_min_compile_time_secs",
-                   old_min_time)
-        compilation_cache.reset_cache()
+    calls = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/given/from/outside")
+    assert compile_cache.enable_compile_cache() == "/given/from/outside"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]

@@ -12,7 +12,6 @@ one-hot placement of unique rows, and the fp_round rounding pins (see
 ops/sparse_update.fp_round).
 """
 
-import warnings
 
 import numpy as np
 import jax
@@ -250,10 +249,19 @@ def test_pallas_train_step_bitexact_hot_rows():
 
 
 def test_pallas_composes_with_lookahead():
-    """LookaheadEngine(strategy='pallas') at lookahead=1 is bit-exact vs
-    the monolithic pallas step (the drain stage dispatches through the
-    same fused kernels), and compile counts hold at one executable per
-    stage per (plan, batch-shape)."""
+    """LookaheadEngine(strategy='pallas') at lookahead=1 reproduces the
+    monolithic pallas step (the drain stage dispatches through the same
+    fused kernels) to f32 rounding, and compile counts hold at one
+    executable per stage per (plan, batch-shape).
+
+    Not bit-for-bit: the engine differentiates the dense stage w.r.t. the
+    carried activations in its own program, and jaxlib 0.9.0's XLA:CPU
+    rounds that backward (the 2*(logit-y)/B * w chain) one ulp apart from
+    the monolithic program's at these shapes — the tap gradients entering
+    the identical drain stage already differ by 3e-8 on step 0, with
+    strategy='sort' exactly as with 'pallas'. The kernels' own
+    bit-exactness against the sort path is pinned above, inside one
+    program."""
     from distributed_embeddings_tpu.schedule import LookaheadEngine
 
     specs = [(80, 8, "sum"), (50, 8, "sum")]
@@ -271,14 +279,15 @@ def test_pallas_composes_with_lookahead():
                         jnp.asarray(r2.randn(BATCH).astype(np.float32))))
 
     from jax.sharding import NamedSharding, PartitionSpec as P
-    # replicated head, like test_schedule._build: an uncommitted
-    # single-device head would re-specialize the fused step once its
-    # first output comes back replicated
-    head_r = jax.device_put(jnp.asarray(head), NamedSharding(mesh, P()))
 
     def params_for(model):
+        # replicated head, like test_schedule._build: an uncommitted
+        # single-device head would re-specialize the fused step once its
+        # first output comes back replicated. One per model: the steps
+        # donate their params
         return {"embedding": model.embedding.set_weights(weights),
-                "head": {"w": head_r}}
+                "head": {"w": jax.device_put(jnp.asarray(head),
+                                             NamedSharding(mesh, P()))}}
 
     m1 = TinyModel(specs, mesh)
     init_fn, step_fn = make_sparse_train_step(m1, "adagrad", lr=0.05,
@@ -304,59 +313,83 @@ def test_pallas_composes_with_lookahead():
         nxt = batches[i + 1] if i + 1 < len(batches) else None
         p2, s2, loss = engine.step(p2, s2, b, nxt)
         eng.append(float(loss))
-    assert eng == mono
+    np.testing.assert_allclose(eng, mono, rtol=2e-6, atol=0)
     assert engine.compile_counts() == {"prefetch": 1, "fused": 1}
     for t, (a, b) in enumerate(zip(m1.embedding.get_weights(
             p1["embedding"]), m2.embedding.get_weights(p2["embedding"]))):
-        np.testing.assert_array_equal(b, a, err_msg=f"table {t}")
+        np.testing.assert_allclose(b, a, rtol=0, atol=2e-7,
+                                   err_msg=f"table {t}")
 
 
 # ------------------------------------------------- gate + dispatch edges
-def test_kernel_gate_fallback_loud_and_harmless(monkeypatch):
-    """Forced probe failure on a 'TPU' backend: the requested pallas path
-    warns LOUDLY and falls back with NO behavior change (output equals
-    the XLA path bit-for-bit — the gate never silently alters
-    numerics)."""
-    g, table, v, w = _grad_case(11)
-    want, _ = su.sparse_adagrad(table, jnp.full((v, w), 0.1), g, 0.05,
-                                strategy="sort")
-
+def test_kernel_check_failure_raises(monkeypatch):
+    """A requested kernel family that cannot run is an error, never a
+    quiet XLA run: a compile failure in the eager compiled check
+    propagates as itself, a numerics mismatch raises, and neither is
+    remembered as a verdict that a later dispatch could consult."""
     def boom(width):
-        raise RuntimeError("remote_compile HTTP 500 (simulated)")
+        raise RuntimeError("Mosaic failed to compile TPU kernel (simulated)")
 
-    gate = su._ShapedKernelGate(boom, "DET_SCATTER_IMPL=pallas (test)")
-    monkeypatch.setattr(su, "_PALLAS_FUSED_GATE", gate)
+    check = su._KernelCheck(boom, "DET_SCATTER_IMPL=pallas (test)")
+    monkeypatch.setattr(su, "_PALLAS_FUSED_CHECK", check)
     monkeypatch.setattr(su.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(su, "_PALLAS_FALLBACK_WARNED", set())
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        got, _ = su.sparse_adagrad(table, jnp.full((v, w), 0.1), g, 0.05,
-                                   strategy="pallas")
-    msgs = [str(c.message) for c in caught]
-    assert any("failed to compile" in m for m in msgs), msgs
-    assert any("dispatches to the xla path" in m for m in msgs), msgs
-    assert gate.verdicts == {8: False}
-    monkeypatch.setattr(su.jax, "default_backend", lambda: "cpu")
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        su.prevalidate_active_impl(strategy="pallas", widths=(8,))
+    assert not check.validated
+    wrong = su._KernelCheck(lambda width: False, "test-check")
+    with pytest.raises(RuntimeError, match="disagree with the XLA"):
+        wrong.prevalidate(16)
+    assert not wrong.validated
+    # two knobs that cannot both be served: refused, not rerouted
+    monkeypatch.setenv("DET_DEDUP_IMPL", "cumsum")
+    with pytest.raises(ValueError, match="DET_DEDUP_IMPL=cumsum"):
+        su._scatter_route("pallas")
 
 
-def test_kernel_gate_shape_class_cache():
-    """One compile-probe verdict per (backend, width shape-class): a
-    second prevalidate at the same class consults the cache instead of
-    re-running the validator."""
+def test_kernel_check_shape_class_cache():
+    """One compiled check per (process, width shape-class): a second
+    prevalidate at the same class does not re-run the validator."""
     calls = []
 
     def validator(cls):
         calls.append(cls)
         return True
 
-    gate = su._ShapedKernelGate(validator, "test-gate")
-    assert gate.prevalidate(16)
-    assert gate.prevalidate(12)       # same pow2 class
-    assert gate.prevalidate(100)      # class 128
+    check = su._KernelCheck(validator, "test-check")
+    assert check.prevalidate(16)
+    assert check.prevalidate(12)       # same pow2 class
+    assert check.prevalidate(100)      # class 128
     assert calls == [16, 128]
     assert su._width_class(8) == 8 and su._width_class(9) == 16
     assert su._width_class(4096) == 512
+
+
+def test_row_dma_kernels_refuse_unaddressable_widths(monkeypatch):
+    """The per-row DMA kernels can address only float32 rows of width
+    128 on the chip: compiled use at any other width raises, naming the
+    kernel and the width — at the kernel, at prevalidation
+    (DET_SCATTER_IMPL=pallas-dma) and at layer construction
+    (DET_LOOKUP_PATH=pallas)."""
+    from distributed_embeddings_tpu.ops import pallas_lookup as pll
+    from distributed_embeddings_tpu.ops import pallas_scatter as ps
+    table = jnp.zeros((64, 16), jnp.float32)
+    ids = jnp.arange(8, dtype=jnp.int32)
+    with pytest.raises(ValueError, match="scatter_add_sorted_unique.*width 16"):
+        ps.scatter_add_sorted_unique(table, ids, jnp.ones((8, 16)),
+                                     interpret=False)
+    with pytest.raises(ValueError, match="adagrad_rows_sorted_unique.*width 16"):
+        ps.adagrad_rows_sorted_unique(table, table, ids, jnp.ones((8, 16)),
+                                      0.1, interpret=False)
+    monkeypatch.setattr(su.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("DET_SCATTER_IMPL", "pallas-dma")
+    with pytest.raises(ValueError, match="pallas-dma.*width 16"):
+        su.prevalidate_active_impl(widths=(16, 128))
+    pll.check_lookup_kernel(100, 16, jnp.float32)      # one-hot MXU kernel
+    pll.check_lookup_kernel(10 ** 6, 128, jnp.float32)  # row DMA, legal
+    with pytest.raises(ValueError, match="_dma_gather_lookup.*width 256"):
+        pll.check_lookup_kernel(10 ** 6, 256, jnp.float32)
+    with pytest.raises(ValueError, match="dtype bfloat16"):
+        pll.check_lookup_kernel(10 ** 6, 128, jnp.bfloat16)
 
 
 def test_interpret_probe_cached_per_process(monkeypatch):
@@ -376,9 +409,9 @@ def test_pallas_requested_env_inert_off_tpu(monkeypatch):
     numerics); explicit strategy='pallas' opts into interpret kernels."""
     monkeypatch.setenv("DET_SCATTER_IMPL", "pallas")
     assert not su._pallas_requested("auto")
-    assert su._scatter_route("auto", jnp.zeros((4, 4))) == "xla"
+    assert su._scatter_route("auto") == "xla"
     assert su._pallas_requested("pallas")
-    assert su._scatter_route("pallas", jnp.zeros((4, 4))) == "pallas"
+    assert su._scatter_route("pallas") == "pallas"
     assert su.active_scatter_impl("auto") == "xla"
     assert su.active_scatter_impl("pallas") == "pallas"
 

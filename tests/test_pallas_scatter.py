@@ -1,10 +1,11 @@
 """Interpret-mode correctness for the Pallas sorted-unique scatter-add RMW
 kernel (ops/pallas_scatter.py) vs the XLA .at[].add reference.
 
-Compiled-path validation is hardware-gated (tools/tpu_mosaic_probe.py) —
-the kernel exists because XLA's scatter costs 100-280 ns/row on TPU
-(round-3 prims) and dedup_sum's sorted-unique output makes a conflict-free
-DMA stream legal.
+The compiled path is held to the chip's compiler in
+tests/test_chip_compile.py and to XLA on the chip in chip_smoke.py. The
+kernel exists because XLA's scatter cost 100-280 ns/row on TPU when last
+measured, and dedup_sum's sorted-unique output makes a conflict-free DMA
+stream legal.
 """
 
 import numpy as np

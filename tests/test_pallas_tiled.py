@@ -1,8 +1,8 @@
 """Tiled one-hot-matmul sparse kernels (ops/pallas_tiled.py) vs XLA
 reference semantics, interpret mode.
 
-These kernels are the round-4 answer to the measured scatter bottleneck
-(docs/round3_notes.md): every memory access is a regular BlockSpec block
+These kernels answer the scatter bottleneck (ops/pallas_tiled.py): every
+memory access is a regular BlockSpec block
 stream, duplicates aggregate inside an MXU matmul. The tests pin:
   * gather == jnp.take for valid ids, zero rows for invalid ids
   * sgd/adagrad == the sparse_update XLA paths (duplicates, invalid ids,

@@ -65,7 +65,9 @@ def test_touched_row_keys_cover_update():
     weights = [rng.randn(v, w).astype(np.float32) * 0.1 for v, w in SIZES]
     params = dist.set_weights(weights)
     model = EmbOnlyModel(dist)
-    init_fn, step_fn = make_sparse_train_step(model, "adagrad", lr=0.1)
+    # donate=False: the test reads the tables it passed in after the step
+    init_fn, step_fn = make_sparse_train_step(model, "adagrad", lr=0.1,
+                                              donate=False)
     p = {"embedding": params}
     s = init_fn(p)
     cats = [jnp.asarray(rng.randint(0, v, (BATCH,)).astype(np.int32))

@@ -2,14 +2,13 @@
 
 The true-splits exchange (reference dist_model_parallel.py:169-288 —
 `hvd.alltoall` with per-destination `splits` paying exactly nnz) maps to
-`lax.ragged_all_to_all` on TPU. Round 2 deferred it because XLA:CPU has no
-lowering, making it untestable on the virtual mesh (docs/round2_notes.md).
-This stage answers the half that needs only one real chip: does the TPU
-backend compile AND execute the op with correct semantics on a 1-device
-mesh? A pass green-lights building the true-splits exchange behind a flag;
-a fail records the concrete error for the round notes.
+`lax.ragged_all_to_all` on TPU. XLA:CPU has no lowering, so the virtual
+mesh cannot test the op itself. This script answers the half that needs
+only one real chip: does the TPU backend compile AND execute the op with
+correct semantics on a 1-device mesh? (`chip_smoke.py --chips 4` runs the
+whole exchange across four.)
 
-Run via tools/tpu_validate.py (stage 'ragged') — own process + timeout.
+Run on the chip: `chiprun -- python tools/tpu_ragged_check.py`.
 """
 
 import time

@@ -201,8 +201,8 @@ def main():
                           "n=720k V=25M")
     os.environ.pop("DET_DEDUP_IMPL", None)
 
-    # Pallas RMW scatter kernel vs the flagged XLA scatter — only if this
-    # toolchain can compile it (see tools/tpu_mosaic_probe.py)
+    # Pallas RMW scatter kernel vs the flagged XLA scatter (width-128 f32
+    # rows only: see pallas_lookup.check_row_dma)
     try:
         from distributed_embeddings_tpu.ops import pallas_scatter as ps
         n_u = 655_360                       # unique sorted rows
