@@ -74,10 +74,8 @@ def _slope_time_scan(step_fn, params, opt_state, batches, nb, iters,
     params/opt_state are DONATED — callers must not reuse them.
 
     `span_path` (ISSUE 14): open an obs span around ONLY the timed t1/t2
-    runs — the attribution window for `--profile` modes. Deliberately
-    excludes the warmup/compile run above it: a window that swallowed
-    compile-time device ops would settle perf_model projections against
-    numbers that are not steady-state step time.
+    runs, so a `--profile` capture marks them. Deliberately excludes the
+    warmup/compile run above it.
     """
     @functools.partial(jax.jit, donate_argnums=(0, 1), static_argnums=(3,))
     def run_steps(params, opt_state, batches, n):
@@ -482,7 +480,7 @@ def serve_main(argv=None) -> int:
     args = p.parse_args(argv)
     if os.environ.get("DET_BENCH_FORCE_CPU") == "1":
         jax.config.update("jax_platforms", "cpu")
-    record = _run_with_device_attribution(
+    record = _run_profiled(
         lambda: run_serve_bench(
             requests=args.requests, batch=args.batch,
             capacity=args.capacity, alpha=args.alpha,
@@ -582,44 +580,29 @@ def _stamp_metrics_snapshot(record: dict) -> dict:
     return record
 
 
-def _run_with_device_attribution(run_fn, enabled: bool) -> dict:
-    """Run one bench mode under a jax profiler capture and stamp the
-    ``device_attribution`` block onto its record (ISSUE 14,
-    ``--profile``): per-span device seconds attributed from the
-    capture's chrome trace to the obs span annotations the mode opened,
-    plus the unattributed remainder — the two sum to the total device
-    time by construction — and the collective-exposure breakdown. The
-    ``device/*`` gauges land on the default registry, so the record's
-    ``metrics_snapshot`` carries them too. Mode-specific reconciliation
-    (the kernels projections table, the lookahead exposed-exchange
-    stamp) happens in the mode mains, where the arm<->span mapping and
-    per-step normalization are known.
-
-    Attribution failures never lose the record (an ``error`` stamp
-    rides instead); a failure in the RUN propagates exactly as it
-    would unprofiled."""
+def _run_profiled(run_fn, enabled: bool) -> dict:
+    """Run one bench mode under a jax profiler capture (``--profile``) and
+    stamp the capture's directory onto its record as ``profile_logdir``.
+    The capture is kept: its device operations carry the program's
+    ``det.*`` stage scopes in their names (obs/stages.py), for TensorBoard
+    or Perfetto. The host-window ``device_attribution`` block of ISSUE 14
+    is gone (ISSUE 25: on a chip dispatch is asynchronous and no host
+    window holds the operations it dispatched); per-stage device time is
+    `benchmark.run --trace 1`'s. A failure in the RUN propagates exactly
+    as it would unprofiled."""
     if not enabled:
         return run_fn()
-    import shutil
     import tempfile
 
     from distributed_embeddings_tpu.utils import profiling
     logdir = tempfile.mkdtemp(prefix="det_bench_profile_")
-    try:
-        # python tracer OFF: a bench run's per-python-call events
-        # overflow the profiler's host buffer and silently drop the
-        # late span annotations attribution needs (see profiling.trace)
-        with profiling.trace(logdir, python_tracer_level=0):
-            record = run_fn()
-        try:
-            from distributed_embeddings_tpu import obs
-            record["device_attribution"] = obs.attribution.attribute_logdir(
-                logdir, registry=obs.default_registry())
-        except Exception as e:  # noqa: BLE001 - keep the record
-            record["device_attribution"] = {"error": str(e)[:300]}
-        return record
-    finally:
-        shutil.rmtree(logdir, ignore_errors=True)
+    # python tracer OFF: a bench run's per-python-call events overflow
+    # the profiler's host buffer and silently drop the late span
+    # annotations (see profiling.trace)
+    with profiling.trace(logdir, python_tracer_level=0):
+        record = run_fn()
+    record["profile_logdir"] = logdir
+    return record
 
 
 # kernels_tpu_projections key -> (bench span, how its device seconds
@@ -695,11 +678,9 @@ def _kernels_reconcile(record: dict, iters: int,
 def _add_profile_arg(parser) -> None:
     parser.add_argument(
         "--profile", action="store_true",
-        help="capture a jax profiler trace around the run and stamp the "
-             "device_attribution block (per-span device seconds, "
-             "unattributed remainder, collective exposure) into the "
-             "record — every chip-run arm runs with this on "
-             "(docs/perf_model.md)")
+        help="capture a jax profiler trace around the run, keep it and "
+             "stamp its directory into the record as profile_logdir "
+             "(device operations carry the det.* stage scopes)")
 
 
 # --------------------------------------------------------------- hotrows
@@ -875,7 +856,7 @@ def hotrows_main(argv=None) -> int:
     if os.environ.get("DET_BENCH_FORCE_CPU") == "1":
         jax.config.update("jax_platforms", "cpu")
     try:
-        record = _run_with_device_attribution(
+        record = _run_profiled(
             lambda: run_hotrows_bench(
                 vocab=args.vocab, width=args.width, batch=args.batch,
                 hotness=args.hotness, alpha=args.alpha,
@@ -1056,7 +1037,7 @@ def vocab_main(argv=None) -> int:
     if os.environ.get("DET_BENCH_FORCE_CPU") == "1":
         jax.config.update("jax_platforms", "cpu")
     try:
-        record = _run_with_device_attribution(
+        record = _run_profiled(
             lambda: run_vocab_bench(
                 steps=args.steps, batch=args.batch, tables=args.tables,
                 vocab=args.vocab, slack=args.slack, width=args.width,
@@ -1206,7 +1187,7 @@ def wire_main(argv=None) -> int:
     # of the XLA_FLAGS dance; a real pod ignores it and uses its world)
     _load_hlo_audit()._ensure_world(max(2, args.world))
     try:
-        record = _run_with_device_attribution(
+        record = _run_profiled(
             lambda: run_wire_bench(
                 vocab=args.vocab, width=args.width, tables=args.tables,
                 batch=args.batch, hotness=args.hotness, world=args.world,
@@ -1693,7 +1674,7 @@ def lookahead_main(argv=None) -> int:
         jax.config.update("jax_platforms", "cpu")
     _load_hlo_audit()._ensure_world(max(2, args.world))
     try:
-        record = _run_with_device_attribution(
+        record = _run_profiled(
             lambda: run_lookahead_bench(
                 vocab=args.vocab, width=args.width, tables=args.tables,
                 batch=args.batch, hotness=args.hotness, world=args.world,
@@ -2001,7 +1982,7 @@ def ingest_main(argv=None) -> int:
     if os.environ.get("DET_BENCH_FORCE_CPU") == "1":
         jax.config.update("jax_platforms", "cpu")
     try:
-        record = _run_with_device_attribution(
+        record = _run_profiled(
             lambda: run_ingest_bench(
                 batches=args.batches, batch=args.batch,
                 features=args.features, numerical=args.numerical,
@@ -2213,7 +2194,7 @@ def kernels_main(argv=None) -> int:
         _load_hlo_audit()._ensure_world(8)
     _isolate_from_measured_defaults()
     try:
-        record = _run_with_device_attribution(
+        record = _run_profiled(
             lambda: run_kernels_bench(
                 vocab=args.vocab, width=args.width, batch=args.batch,
                 hotness=args.hotness, iters=args.iters,
@@ -2855,7 +2836,7 @@ def soak_main(argv=None) -> int:
             # --steps 0 is an error, not "no override")
             scenario = load_soak_scenario(scenario)
         _load_hlo_audit()._ensure_world(max(2, int(scenario["world"])))
-        record = _run_with_device_attribution(
+        record = _run_profiled(
             lambda: run_soak_bench(scenario), args.profile)
     except Exception as e:  # noqa: BLE001 - one JSON line, like main()
         import traceback
@@ -3318,7 +3299,7 @@ def fleet_main(argv=None) -> int:
         if args.steps is not None or args.replicas is not None:
             scenario = load_soak_scenario(scenario)
         _load_hlo_audit()._ensure_world(max(2, int(scenario["world"])))
-        record = _run_with_device_attribution(
+        record = _run_profiled(
             lambda: run_fleet_bench(scenario), args.profile)
     except Exception as e:  # noqa: BLE001 - one JSON line, like main()
         import traceback
@@ -3693,7 +3674,7 @@ def tune_main(argv=None) -> int:
             shape[dim] = v
     _load_hlo_audit()._ensure_world(max(2, shape["world"]))
     try:
-        record = _run_with_device_attribution(
+        record = _run_profiled(
             lambda: run_tune_bench(
                 args.workload, shape, survivors=args.survivors,
                 optimizer=args.optimizer, seed=args.seed),

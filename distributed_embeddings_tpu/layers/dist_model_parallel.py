@@ -47,6 +47,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_embeddings_tpu import compat
+from distributed_embeddings_tpu.obs.stages import stage, staged
 from distributed_embeddings_tpu.ops import embedding_ops, pallas_lookup
 from distributed_embeddings_tpu.ops import sparse_update as sparse_update_ops
 from distributed_embeddings_tpu.ops import wire as wire_ops
@@ -828,6 +829,7 @@ class DistributedEmbedding:
         return out
 
     # ----------------------------------------------------------- input prep
+    @staged("ids")
     def _prepare_one(self, x, max_hotness: Optional[int]) -> _PreparedInput:
         if isinstance(x, tuple) and len(x) == 2 and not isinstance(x, RaggedIds):
             ids, weights = x
@@ -1342,6 +1344,7 @@ class DistributedEmbedding:
             sort_g.sid[None], sort_g.perm[None], sort_g.seg_start[None],
             None if sort_g.inv is None else sort_g.inv[None])
 
+    @staged("lookup")
     def _group_lookup(self, table: jax.Array, ids: jax.Array,
                       weights: Optional[jax.Array],
                       combiner: Optional[str],
@@ -1498,7 +1501,11 @@ class DistributedEmbedding:
                         f"dp table {t_dp}: (ids, weights) inputs are not "
                         "supported for custom embedding layer classes — "
                         "the layer's own __call__ defines its semantics")
-                out = layer({"embeddings": table}, ids)
+                # custom outputs honor the compute_dtype policy like stock
+                # tables (ADVICE r5): without the cast, a mixed-precision
+                # model would see f32 here and bf16 everywhere else
+                with stage("lookup"):
+                    out = self._cast(layer({"embeddings": table}, ids))
                 want_rank = 2 if cfg.get("combiner") else 3
                 if out.ndim != want_rank:
                     raise ValueError(
@@ -1506,13 +1513,11 @@ class DistributedEmbedding:
                         f"rank-{out.ndim} output, expected rank "
                         f"{want_rank} ([batch, width] with a combiner, "
                         "[batch, hotness, width] without)")
-                # custom outputs honor the compute_dtype policy like stock
-                # tables (ADVICE r5): without the cast, a mixed-precision
-                # model would see f32 here and bf16 everywhere else
-                dp_outs.append(self._cast(out))
+                dp_outs.append(out)
                 continue
-            emb = self._cast(jnp.take(table, ids, axis=0))   # [B_l, k, w]
-            dp_outs.append(_combine(emb, weights, cfg.get("combiner")))
+            with stage("lookup"):
+                emb = self._cast(jnp.take(table, ids, axis=0))  # [B_l, k, w]
+                dp_outs.append(_combine(emb, weights, cfg.get("combiner")))
 
         # ---- table-parallel: per-group all_to_all id exchange (dp->mp),
         # local fused lookup, all_to_all back (mp->dp). Each destination
@@ -1541,50 +1546,54 @@ class DistributedEmbedding:
                    if (hot_params is not None and bucket.hot_rows > 0
                        and not offloaded) else None)
             hot_info = None
-            if hot is not None:
-                send_m, w_send_m, hot_pos, hot_w = self._hot_split_send(
-                    grp, ids, group_w[g], world, blocal, hot)
-                ids_x, w_x = self._exchange_send(grp, send_m, w_send_m,
-                                                 world, blocal)
-                if w_x is None:
-                    # unweighted input: the sentinel is receiver-
-                    # detectable — real ids are < their lane's segment
-                    # rows <= rows_max and hit lanes are EXACTLY rows_max
-                    # — so the 0/scale effective weights reconstruct
-                    # locally, bit-identical to exchanging them. (An
-                    # INVALID input id == rows_max reads as weight 0 here
-                    # where the baseline clamps it onto the last row;
-                    # ids past rows_max keep the baseline clamp.)
-                    _, scale = _effective_weights(None, grp.k,
-                                                  bucket.combiner)
-                    w_x = jnp.where(
-                        ids_x == jnp.int32(max(bucket.rows_max, 1)),
-                        jnp.float32(0.0), jnp.float32(scale))
-                hot_info = (hot_pos, hot_w)
-            elif self._use_ragged_exchange(grp, world):
-                ids_x, w_x = self._ragged_id_exchange(
-                    grp, ids, group_w[g], world, blocal)
-            else:
-                ids_x, w_x = self._padded_id_exchange(
-                    grp, ids, group_w[g], world, blocal)
-            offs = self._device_const(grp.offs)              # [f_max]
-            ids_x = ids_x + offs[None, :, None].astype(ids_x.dtype)
+            with stage("ids"):
+                if hot is not None:
+                    send_m, w_send_m, hot_pos, hot_w = self._hot_split_send(
+                        grp, ids, group_w[g], world, blocal, hot)
+                    ids_x, w_x = self._exchange_send(grp, send_m, w_send_m,
+                                                     world, blocal)
+                    if w_x is None:
+                        # unweighted input: the sentinel is receiver-
+                        # detectable — real ids are < their lane's segment
+                        # rows <= rows_max and hit lanes are EXACTLY rows_max
+                        # — so the 0/scale effective weights reconstruct
+                        # locally, bit-identical to exchanging them. (An
+                        # INVALID input id == rows_max reads as weight 0 here
+                        # where the baseline clamps it onto the last row;
+                        # ids past rows_max keep the baseline clamp.)
+                        _, scale = _effective_weights(None, grp.k,
+                                                      bucket.combiner)
+                        w_x = jnp.where(
+                            ids_x == jnp.int32(max(bucket.rows_max, 1)),
+                            jnp.float32(0.0), jnp.float32(scale))
+                    hot_info = (hot_pos, hot_w)
+                elif self._use_ragged_exchange(grp, world):
+                    ids_x, w_x = self._ragged_id_exchange(
+                        grp, ids, group_w[g], world, blocal)
+                else:
+                    ids_x, w_x = self._padded_id_exchange(
+                        grp, ids, group_w[g], world, blocal)
+                offs = self._device_const(grp.offs)              # [f_max]
+                ids_x = ids_x + offs[None, :, None].astype(ids_x.dtype)
             # sort folding: ONE canonical sort of this group's exchanged id
             # stream, consumed by the tiled forward gather below (when the
             # plan says "inv") and by the sparse update via the residuals
             sort_g = None
             if (want_res and sort_plan is not None and sort_plan[g]
                     and not offloaded):
-                sort_g = canonical_id_sort(
-                    ids_x, max(bucket.rows_max, 1),
-                    want_inv=(sort_plan[g] == "inv"))
+                with stage("dedup"):
+                    sort_g = canonical_id_sort(
+                        ids_x, max(bucket.rows_max, 1),
+                        want_inv=(sort_plan[g] == "inv"))
             if offloaded:
                 # id exchange happens on-device (above); the lookup itself
                 # runs host-side outside the shard_map (reference /CPU:0
                 # lookup :829-831) — export the exchanged ids/weights
-                eff_w, _ = _effective_weights(w_x, grp.k, bucket.combiner)
-                off_ids.append(ids_x[None].astype(jnp.int32))
-                off_w.append(None if eff_w is None else eff_w[None])
+                with stage("ids"):
+                    eff_w, _ = _effective_weights(w_x, grp.k,
+                                                  bucket.combiner)
+                    off_ids.append(ids_x[None].astype(jnp.int32))
+                    off_w.append(None if eff_w is None else eff_w[None])
                 ex_list.append(None)
             elif hot_info is not None:
                 off_ids.append(None)
@@ -1595,17 +1604,20 @@ class DistributedEmbedding:
                 # default OOB mode is fill-with-NaN, and 0 * NaN = NaN —
                 # the residual/sort streams keep the raw sentinel so the
                 # update still drops those lanes outright.
-                ids_lu = jnp.minimum(ids_x, max(bucket.rows_max, 1) - 1)
-                out = self._group_lookup(tp_params[grp.bucket][0], ids_lu,
-                                         w_x, "sum", presorted=sort_g)
-                tap_g = None if taps is None else taps["tp"][g]
-                if tap_g is not None:
-                    out = out + tap_g[0].astype(out.dtype)
+                with stage("lookup"):
+                    ids_lu = jnp.minimum(ids_x, max(bucket.rows_max, 1) - 1)
+                    out = self._group_lookup(tp_params[grp.bucket][0],
+                                             ids_lu, w_x, "sum",
+                                             presorted=sort_g)
+                    tap_g = None if taps is None else taps["tp"][g]
+                    if tap_g is not None:
+                        out = out + tap_g[0].astype(out.dtype)
                 ex = self._tp_bucket_exchange(out, bucket.wire_dtype)
                 hot_tap = None if hot_taps is None else hot_taps[g]
                 contrib = self._hot_contrib(grp, bucket, hot, hot_info[0],
                                             hot_info[1], hot_tap)
-                ex_list.append(ex + contrib.astype(ex.dtype))
+                with stage("lookup"):
+                    ex_list.append(ex + contrib.astype(ex.dtype))
             else:
                 off_ids.append(None)
                 off_w.append(None)
@@ -1618,19 +1630,20 @@ class DistributedEmbedding:
                 ex_list.append(self._tp_bucket_exchange(
                     out, bucket.wire_dtype))
             if want_res:
-                if hot_info is not None:
-                    # w_x IS the effective weight stream (see above)
-                    eff_w = w_x
-                else:
-                    eff_w, _ = _effective_weights(w_x, grp.k,
-                                                  bucket.combiner)
-                tp_res_ids.append(ids_x[None].astype(jnp.int32))
-                tp_res_w.append(None if eff_w is None else eff_w[None])
-                tp_res_sort.append(self._stack_sort(sort_g))
-                hot_res_pos.append(None if hot_info is None
-                                   else hot_info[0][None])
-                hot_res_w.append(None if hot_info is None
-                                 else hot_info[1][None])
+                with stage("ids"):
+                    if hot_info is not None:
+                        # w_x IS the effective weight stream (see above)
+                        eff_w = w_x
+                    else:
+                        eff_w, _ = _effective_weights(w_x, grp.k,
+                                                      bucket.combiner)
+                    tp_res_ids.append(ids_x[None].astype(jnp.int32))
+                    tp_res_w.append(None if eff_w is None else eff_w[None])
+                    tp_res_sort.append(self._stack_sort(sort_g))
+                    hot_res_pos.append(None if hot_info is None
+                                       else hot_info[0][None])
+                    hot_res_w.append(None if hot_info is None
+                                     else hot_info[1][None])
 
         # ---- row-sliced tables: all_gather ids, masked lookup, psum_scatter
         row_outs, row_res = self._row_slice_local(
@@ -1907,6 +1920,7 @@ class DistributedEmbedding:
         return exchange(send), (None if w_send is None
                                 else exchange(w_send))
 
+    @staged("lookup")
     def _hot_contrib(self, grp, bucket, hot, hot_pos, hot_w, hot_tap):
         """The hit lanes' locally-computed output contribution
         [world, B_l, f_max, w]: gather from the replicated hot shard,
@@ -1923,6 +1937,7 @@ class DistributedEmbedding:
             contrib = contrib + hot_tap.astype(contrib.dtype)
         return contrib
 
+    @staged("lookup")
     def _tp_group_out(self, tp_params, grp, ids_x, w_x, tap, presorted=None,
                       scale_s=None):
         """One exchange group's local bucket output [B, f, w_out], via the
@@ -2071,6 +2086,7 @@ class DistributedEmbedding:
                 self._offload_lookup_override = prev
         return scope()
 
+    @staged("lookup")
     def _offload_group_out(self, g, grp, table, scale, off_id, off_w,
                            tap_g):
         """One offloaded group's output: the serving override when scoped
@@ -2087,6 +2103,7 @@ class DistributedEmbedding:
         return self._host_group_exchange(table, grp, off_id, off_w, tap_g,
                                          g, scale_h=scale)
 
+    @staged("acts")
     def _tp_bucket_exchange(self, out: jax.Array,
                             wire: str = "f32") -> jax.Array:
         """mp->dp movement of one bucket's outputs: [B, f, wf] ->
@@ -2119,49 +2136,57 @@ class DistributedEmbedding:
                 # wire formats (ISSUE 5) from the row-table plan: int16
                 # id wire where the TOTAL row count provably fits, the
                 # float wire on the weight broadcast
-                ids = wire_ops.wire_id_all_gather(ids, self.axis,
-                                                  rt.id_wire_dtype)
-                if weights is not None:
-                    weights = wire_ops.wire_all_gather(
-                        weights, self.axis, rt.wire_dtype, world)
-            base = self._device_const(rt.row_base)
-            nrows = self._device_const(np.asarray(rt.rows_per_rank, np.int32))
-            local = ids - base.astype(ids.dtype)
-            valid = (local >= 0) & (local < nrows.astype(ids.dtype))
-            local = jnp.clip(local, 0, max(rt.rows_max - 1, 0))
-            table = row_params[t][0]
-            emb = self._cast(jnp.take(table, local, axis=0))
-            vmask = valid.astype(jnp.float32)
-            # explicit weighted-sum form (see _effective_weights): the valid
-            # mask folds into the weights so the tapped backward sees the
-            # exact per-contribution coefficients
-            eff_w, scale = _effective_weights(weights, ids.shape[-1],
-                                              rt.combiner)
-            w_full = vmask if eff_w is None else eff_w * vmask
-            if rt.combiner is None:
-                out = emb * vmask[..., None].astype(emb.dtype)     # [B, k, w]
-            else:
-                out = jnp.einsum("bk,bkw->bw", w_full.astype(emb.dtype), emb)
-                if scale != 1.0:
-                    out = out * jnp.asarray(scale, out.dtype)
-            if row_taps is not None:
-                out = out + row_taps[j][0].astype(out.dtype)
+                with stage("ids"):
+                    ids = wire_ops.wire_id_all_gather(ids, self.axis,
+                                                      rt.id_wire_dtype)
+                    if weights is not None:
+                        weights = wire_ops.wire_all_gather(
+                            weights, self.axis, rt.wire_dtype, world)
+            with stage("lookup"):
+                base = self._device_const(rt.row_base)
+                nrows = self._device_const(
+                    np.asarray(rt.rows_per_rank, np.int32))
+                local = ids - base.astype(ids.dtype)
+                valid = (local >= 0) & (local < nrows.astype(ids.dtype))
+                local = jnp.clip(local, 0, max(rt.rows_max - 1, 0))
+                table = row_params[t][0]
+                emb = self._cast(jnp.take(table, local, axis=0))
+                vmask = valid.astype(jnp.float32)
+                # explicit weighted-sum form (see _effective_weights): the
+                # valid mask folds into the weights so the tapped backward
+                # sees the exact per-contribution coefficients
+                eff_w, scale = _effective_weights(weights, ids.shape[-1],
+                                                  rt.combiner)
+                w_full = vmask if eff_w is None else eff_w * vmask
+                if rt.combiner is None:
+                    out = emb * vmask[..., None].astype(emb.dtype)  # [B, k, w]
+                else:
+                    out = jnp.einsum("bk,bkw->bw", w_full.astype(emb.dtype),
+                                     emb)
+                    if scale != 1.0:
+                        out = out * jnp.asarray(scale, out.dtype)
+                if row_taps is not None:
+                    out = out + row_taps[j][0].astype(out.dtype)
             if world > 1:
                 # the partial-sum return rides the float wire; under a
                 # compressed wire the reduce-scatter re-expresses as
                 # all_to_all + LOCAL f32 accumulation, so cross-device
                 # adds never run at wire precision (ops/wire.py)
-                out = wire_ops.wire_psum_scatter(out, self.axis,
-                                                 rt.wire_dtype, world)
+                with stage("acts"):
+                    out = wire_ops.wire_psum_scatter(out, self.axis,
+                                                     rt.wire_dtype, world)
             row_outs.append(out)
             if want_res:
                 # OOB sentinel rows_max: dropped by the sparse scatter
-                sent = jnp.where(valid, local, rt.rows_max).astype(jnp.int32)
-                res_ids.append(sent[None])
-                res_w.append((w_full * scale)[None])
+                with stage("ids"):
+                    sent = jnp.where(valid, local,
+                                     rt.rows_max).astype(jnp.int32)
+                    res_ids.append(sent[None])
+                    res_w.append((w_full * scale)[None])
                 sort_j = None
                 if sort_plan is not None and sort_plan[j]:
-                    sort_j = canonical_id_sort(sent, max(rt.rows_max, 1))
+                    with stage("dedup"):
+                        sort_j = canonical_id_sort(sent, max(rt.rows_max, 1))
                 res_sort.append(self._stack_sort(sort_j))
         return row_outs, (res_ids, res_w, res_sort)
 
@@ -2240,15 +2265,13 @@ class DistributedEmbedding:
             groups, assembly = self._exchange_groups(tp_prep)
             for grp in groups:
                 members = [tp_prep[i] for i in grp.class_inputs]
-                group_ids.append(jnp.stack(
-                    [p.ids.astype(jnp.int32) for p in members], axis=1))
-                if grp.need_w:
+                with stage("ids"):
+                    group_ids.append(jnp.stack(
+                        [p.ids.astype(jnp.int32) for p in members], axis=1))
                     group_w.append(jnp.stack(
                         [(p.weights if p.weights is not None
                           else jnp.ones((batch, p.k), jnp.float32))
-                         for p in members], axis=1))
-                else:
-                    group_w.append(None)
+                         for p in members], axis=1) if grp.need_w else None)
 
         dp_in = [(p.ids, p.weights) for p in dp_prep]
         row_in = [(p.ids, p.weights) for p in row_prep]
@@ -2419,6 +2442,7 @@ class DistributedEmbedding:
                                          res[4], res[5], res[6], res[7])
         return outputs
 
+    @staged("acts")
     def _assemble_tp_outputs(self, ex_list, tp_preps, batch, groups,
                              assembly) -> List[jax.Array]:
         """Slice the exchanged group outputs back into per-input arrays:
@@ -2514,8 +2538,10 @@ class DistributedEmbedding:
                     "staged_exchange_scope does not support custom "
                     "embedding layer classes on dp tables (their forward "
                     "is defined per-device under shard_map)")
-            rows = self._cast(jnp.take(params["dp"][t_dp], p.ids, axis=0))
-            dp_outs.append(_combine(rows, p.weights, cfg.get("combiner")))
+            with stage("lookup"):
+                rows = self._cast(jnp.take(params["dp"][t_dp], p.ids, axis=0))
+                dp_outs.append(_combine(rows, p.weights,
+                                        cfg.get("combiner")))
         dp_final = []
         for j, out in enumerate(dp_outs):
             cfg = strat.dp_configs[strat.map_groups[0][j]]
@@ -2532,6 +2558,7 @@ class DistributedEmbedding:
         outputs = dp_final + tp_final + row_final
         return [outputs[idx] for idx in strat.rev_group_ids]
 
+    @staged("acts")
     def exchange_transpose(self, g_ex, g_row, key) -> dict:
         """Drain-stage gradient transpose (ISSUE 9): move the dense
         stage's activation cotangents dp->mp, producing the exact
@@ -2821,6 +2848,7 @@ class DistributedEmbedding:
         tp_preps = [input_prep[i] for i in range(len(strat.input_groups[1]))]
         groups, assembly = self._exchange_groups(tp_preps)
 
+        @staged("ids")
         def rank_block(grp, r):
             """One rank's [B, f_max, k] ids (+ weights) for one group."""
             cols_i, cols_w = [], []
@@ -2871,9 +2899,10 @@ class DistributedEmbedding:
                     "jit/grad tracing; stage arrays eagerly first")
             for grp in groups:
                 blocks = [rank_block(grp, r) for r in range(world)]
-                group_ids.append(jnp.stack([b[0] for b in blocks]))
-                group_w.append(jnp.stack([b[1] for b in blocks])
-                               if grp.need_w else None)
+                with stage("ids"):
+                    group_ids.append(jnp.stack([b[0] for b in blocks]))
+                    group_w.append(jnp.stack([b[1] for b in blocks])
+                                   if grp.need_w else None)
 
         offloaded_groups = [
             g for g, grp in enumerate(groups)
@@ -2896,20 +2925,24 @@ class DistributedEmbedding:
             ex_list, off_ids, off_w = [], [], []
             res_ids, res_w, res_sort = [], [], []
             for g, grp in enumerate(groups):
-                ids_l = group_ids[g][0]                         # [B, f, k]
-                offs = self._device_const(grp.offs)
-                ids_l = ids_l + offs[None, :, None].astype(ids_l.dtype)
-                w_l = group_w[g][0] if group_w[g] is not None else None
+                with stage("ids"):
+                    ids_l = group_ids[g][0]                     # [B, f, k]
+                    offs = self._device_const(grp.offs)
+                    ids_l = ids_l + offs[None, :, None].astype(ids_l.dtype)
+                    w_l = group_w[g][0] if group_w[g] is not None else None
                 bucket = self.plan.tp_buckets[grp.bucket]
                 sort_g = None
                 if return_residuals and sort_plan[g]:
-                    sort_g = canonical_id_sort(
-                        ids_l, max(bucket.rows_max, 1),
-                        want_inv=(sort_plan[g] == "inv"))
+                    with stage("dedup"):
+                        sort_g = canonical_id_sort(
+                            ids_l, max(bucket.rows_max, 1),
+                            want_inv=(sort_plan[g] == "inv"))
                 if g in offloaded_groups:
-                    eff_w, _ = _effective_weights(w_l, grp.k, bucket.combiner)
-                    off_ids.append(ids_l[None].astype(jnp.int32))
-                    off_w.append(None if eff_w is None else eff_w[None])
+                    with stage("ids"):
+                        eff_w, _ = _effective_weights(w_l, grp.k,
+                                                      bucket.combiner)
+                        off_ids.append(ids_l[None].astype(jnp.int32))
+                        off_w.append(None if eff_w is None else eff_w[None])
                     ex_list.append(None)
                 else:
                     off_ids.append(None)
@@ -2923,10 +2956,12 @@ class DistributedEmbedding:
                     ex_list.append(self._tp_bucket_exchange(
                         out, bucket.wire_dtype))
                 if return_residuals:
-                    eff_w, _ = _effective_weights(w_l, grp.k, bucket.combiner)
-                    res_ids.append(ids_l[None].astype(jnp.int32))
-                    res_w.append(None if eff_w is None else eff_w[None])
-                    res_sort.append(self._stack_sort(sort_g))
+                    with stage("ids"):
+                        eff_w, _ = _effective_weights(w_l, grp.k,
+                                                      bucket.combiner)
+                        res_ids.append(ids_l[None].astype(jnp.int32))
+                        res_w.append(None if eff_w is None else eff_w[None])
+                        res_sort.append(self._stack_sort(sort_g))
             res = ((res_ids, res_w, res_sort) if return_residuals
                    else None)
             return ex_list, off_ids, off_w, res
@@ -3303,9 +3338,13 @@ class DistributedEmbedding:
                           for b in self._hot_buckets]
         return out
 
+    @staged("contrib")
     def sparse_update(self, params: dict, opt_states: dict, tap_grads: dict,
                       residuals: "TapResiduals", opt: SparseOptimizer):
-        """Row-wise sparse optimizer step for tp/row tables.
+        """Row-wise sparse optimizer step for tp/row tables. Traced under
+        the stage `contrib` (tap gradients and residuals -> per-row
+        contributions); the duplicate sum and the row rules it calls open
+        `dedup` and `apply` inside it.
 
         Args:
           params: full param pytree (dp untouched, returned as-is).
@@ -3920,6 +3959,7 @@ class DistributedEmbedding:
         return out_table, tuple(out_state)
 
     @staticmethod
+    @staged("acts")
     def _restore_shape(out, p: _PreparedInput, combiner, width):
         if combiner is not None:
             return out

@@ -1,7 +1,7 @@
-"""Unified runtime telemetry (ISSUE 11/14): one metric registry,
+"""Unified runtime telemetry (ISSUE 11/14/25): one metric registry,
 host-side step-span tracing, declarative SLO evaluation, a bounded
-flight recorder with version-lineage tracks, and device-time
-attribution from profiler captures — across
+flight recorder with version-lineage tracks, and the stage scopes that
+name the work inside a jitted train step (`obs.stages`) — across
 train/serve/vocab/store/lookahead.
 
 See docs/observability.md for the full API and schema; the short form:
@@ -15,7 +15,8 @@ See docs/observability.md for the full API and schema; the short form:
     snap = reg.snapshot()
     findings = obs.evaluate_rules(obs.load_rules("slo.json"), snap)
     obs.default_recorder().export("trace.json")   # Perfetto-loadable
-    obs.attribution.attribute_logdir(profiler_logdir, registry=reg)
+    with obs.stages.stage("lookup"):              # inside a traced step
+        ...
 """
 
 from distributed_embeddings_tpu.obs.registry import (  # noqa: F401
@@ -30,7 +31,7 @@ from distributed_embeddings_tpu.obs.instrument import (  # noqa: F401
 from distributed_embeddings_tpu.obs.trace import (  # noqa: F401
     FlightRecorder, default_recorder, dump_postmortem,
     reset_default_recorder)
-from distributed_embeddings_tpu.obs import attribution  # noqa: F401
+from distributed_embeddings_tpu.obs import stages  # noqa: F401
 
 __all__ = [
     "Counter", "Gauge", "LatencyHistogram", "MetricRegistry",
@@ -39,5 +40,5 @@ __all__ = [
     "load_rules", "evaluate_rules", "metric_value", "summarize",
     "export_exchange_gauges", "export_kernel_gauges",
     "FlightRecorder", "default_recorder", "reset_default_recorder",
-    "dump_postmortem", "attribution",
+    "dump_postmortem", "stages",
 ]

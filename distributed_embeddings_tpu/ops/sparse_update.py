@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distributed_embeddings_tpu.obs.stages import staged
+
 # auto-strategy threshold: buckets up to this many elements aggregate through
 # a dense temp (64 MiB at f32 width 16); larger buckets use the sort path.
 # Tunable per hardware via DET_SPARSE_DENSE_MAX.
@@ -433,6 +435,7 @@ def concat_grads(grads) -> "SparseRowGrad":
         jnp.concatenate([g.contribs for g in grads], axis=0))
 
 
+@staged("dedup")
 def dedup_sum(ids: jax.Array, contribs: jax.Array, sentinel: int,
               presorted=None):
     """Aggregate duplicate row ids: returns (rep_ids [N], sums [N, w]) where
@@ -509,6 +512,7 @@ def _dedup_sum_cumsum(sid, rows, is_start, sentinel, iota):
     return rep, sums.astype(rows.dtype)
 
 
+@staged("dedup")
 def _dense_sum(ids, contribs, rows):
     """[V, w] dense aggregation: scatter-add (OOB ids dropped), plus a row
     contribution COUNT so the updater can skip untouched rows (and so
@@ -533,6 +537,7 @@ def _dense_sum(ids, contribs, rows):
     return dense_ext[:, :w], dense_ext[:, w]
 
 
+@staged("apply")
 def apply_dense_rows(kind: str, table, state, g, touched, lr, **hp):
     """Apply a DENSE aggregated gradient `g` [rows, w] with a boolean
     `touched` row mask to a (small) table + optimizer state — the exact
@@ -587,6 +592,7 @@ def _usable_presorted(presorted, grad: SparseRowGrad, rows: int):
 
 
 # ------------------------------------------------------------------ SGD
+@staged("apply")
 def sparse_sgd(table: jax.Array, grad: SparseRowGrad, lr,
                strategy: str = "auto", presorted=None) -> jax.Array:
     """table[ids] -= lr * contribs. Under 'auto'/'dense', duplicates need
@@ -627,6 +633,7 @@ def sparse_sgd(table: jax.Array, grad: SparseRowGrad, lr,
 
 
 # -------------------------------------------------------------- Adagrad
+@staged("apply")
 def sparse_adagrad(table: jax.Array, accum: jax.Array, grad: SparseRowGrad,
                    lr, eps: float = 1e-10, strategy: str = "auto",
                    presorted=None):
@@ -696,6 +703,7 @@ def sparse_adagrad(table: jax.Array, accum: jax.Array, grad: SparseRowGrad,
 
 
 # ----------------------------------------------------------------- Adam
+@staged("apply")
 def sparse_adam(table: jax.Array, mu: jax.Array, nu: jax.Array, count,
                 grad: SparseRowGrad, lr, b1: float = 0.9, b2: float = 0.999,
                 eps: float = 1e-8, strategy: str = "auto", presorted=None):
@@ -768,6 +776,7 @@ def sparse_adam(table: jax.Array, mu: jax.Array, nu: jax.Array, count,
 QUANTIZED_ROW_KINDS = ("sgd", "adagrad")
 
 
+@staged("apply")
 def quantized_row_update(kind: str, payload: jax.Array, scale: jax.Array,
                          state, grad: SparseRowGrad, store_dtype: str, lr,
                          eps: float = 1e-10, presorted=None):

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from distributed_embeddings_tpu.layers.dist_model_parallel import (
     broadcast_variables)
+from distributed_embeddings_tpu.obs.stages import stage
 from distributed_embeddings_tpu.ops.sparse_update import (
     drain_sparse_apply, make_sparse_optimizer, prevalidate_active_impl)
 
@@ -145,12 +146,15 @@ def make_train_step(loss_fn: Callable, optimizer,
         the step's params output (keeps placement stable across steps).
 
     Returns:
-      step(params, opt_state, *batch) -> (params, opt_state, loss), jitted.
+      step(params, opt_state, *batch) -> (params, opt_state, loss), jitted
+      under the name `obs.stages.STEP_NAME`.
     """
-    def step(params, opt_state, *batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = apply_updates(params, updates)
+    def det_train_step(params, opt_state, *batch):
+        with stage("model"):
+            loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
+        with stage("dense_opt"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = apply_updates(params, updates)
         return params, opt_state, loss
 
     if donate is None:
@@ -158,7 +162,7 @@ def make_train_step(loss_fn: Callable, optimizer,
     donate_argnums = (0, 1) if donate else ()
     out_shardings = ((param_shardings, None, None)
                      if param_shardings is not None else None)
-    return jax.jit(step, donate_argnums=donate_argnums,
+    return jax.jit(det_train_step, donate_argnums=donate_argnums,
                    out_shardings=out_shardings)
 
 
@@ -262,6 +266,12 @@ def make_sparse_train_step(model, optimizer: str = "adagrad", lr=0.01,
       init_fn(params) -> opt_state
       step_fn(params, opt_state, numerical, cats, labels)
         -> (params, opt_state, loss);  jit with donated params/opt_state.
+      `step_fn.name` is the jitted function's name (`obs.stages.STEP_NAME`:
+      traces say ``jit(det_train_step)``) and `step_fn.lower` the `lower` of
+      the very `jax.jit` object a call dispatches to: arrays or
+      ShapeDtypeStructs in, and from its ``.compile()`` the step's
+      `memory_analysis()`, cost analysis and text (it returns the offloaded
+      buckets' pending rows as a fourth output).
     """
     emb = model.embedding
     scheduled, sopt_for, dense_optimizer = _sparse_optimizer_setup(
@@ -280,10 +290,12 @@ def make_sparse_train_step(model, optimizer: str = "adagrad", lr=0.01,
                    if emb._bucket_memory_kind(b)]
     sort_spec = (optimizer, strategy) if fold_sort else None
 
-    def step_fn(params, opt_state, numerical, cats, labels):
+    def det_train_step(params, opt_state, numerical, cats, labels):
         cats = list(cats)
-        taps = emb.make_taps(cats)
-        sopt_t = sopt_for(opt_state)
+        with stage("lookup"):
+            taps = emb.make_taps(cats)
+        with stage("apply"):
+            sopt_t = sopt_for(opt_state)     # a schedule's lr(count)
 
         def loss_with_taps(dense, taps):
             p = _merge_dense(dense, params)
@@ -294,7 +306,7 @@ def make_sparse_train_step(model, optimizer: str = "adagrad", lr=0.01,
         # residual_sort_scope is trace-time state: the model's loss_fn
         # reaches emb.apply without a residual_sort channel of its own, so
         # the fold spec rides the layer for exactly this traced region
-        with emb.residual_sort_scope(sort_spec):
+        with emb.residual_sort_scope(sort_spec), stage("model"):
             (loss, res), (g_dense, g_taps) = jax.value_and_grad(
                 loss_with_taps, argnums=(0, 1), has_aux=True)(dense0, taps)
         # the shared drain-stage tail (also the lookahead engine's): sparse
@@ -302,16 +314,19 @@ def make_sparse_train_step(model, optimizer: str = "adagrad", lr=0.01,
         new_emb, new_emb_state, pending = drain_sparse_apply(
             emb, params["embedding"], opt_state["emb"], g_taps, res, sopt_t,
             off_buckets)
-        updates, new_dense_state = dense_optimizer.update(
-            g_dense, opt_state["dense"], dense0)
-        new_dense = apply_updates(dense0, updates)
+        with stage("dense_opt"):
+            updates, new_dense_state = dense_optimizer.update(
+                g_dense, opt_state["dense"], dense0)
+            new_dense = apply_updates(dense0, updates)
         new_params = _merge_dense(new_dense, {**params, "embedding": new_emb})
         new_state = {"emb": new_emb_state, "dense": new_dense_state}
         if scheduled:
-            new_state["count"] = opt_state["count"] + 1
+            with stage("dense_opt"):
+                new_state["count"] = opt_state["count"] + 1
             # concrete per-step lr for the out-of-jit host apply (offload)
-            pending = {b: v + (lr(opt_state["count"]),)
-                       for b, v in pending.items()}
+            with stage("apply"):
+                pending = {b: v + (lr(opt_state["count"]),)
+                           for b, v in pending.items()}
         return new_params, new_state, loss, pending
 
     # jit is load-bearing, not just speed: memory-kind placement (offloaded
@@ -319,20 +334,26 @@ def make_sparse_train_step(model, optimizer: str = "adagrad", lr=0.01,
     # a top-level jit boundary; donation lets XLA update tables in place.
     if donate is None:
         donate = default_donate()
+
+    def with_handle(run, core):
+        run.name, run.lower = det_train_step.__name__, core.lower
+        return init_fn, run
+
     if not off_buckets:
-        core = jax.jit(step_fn, donate_argnums=(0, 1) if donate else ())
+        core = jax.jit(det_train_step,
+                       donate_argnums=(0, 1) if donate else ())
 
         def run(params, opt_state, numerical, cats, labels):
             p, s, loss, _ = core(params, opt_state, numerical, cats, labels)
             return p, s, loss
-        return init_fn, run
+        return with_handle(run, core)
 
     # Offloaded buckets: host tables/state are READ-ONLY inside the jitted
     # step (forward lookups + dedup happen there); the host-memory row apply
     # runs afterwards at top level, where XLA honors pinned_host output
     # placement. Donation skips params/opt_state because the host leaves
     # must survive the call.
-    core = jax.jit(step_fn)
+    core = jax.jit(det_train_step)
 
     def run(params, opt_state, numerical, cats, labels):
         new_params, new_state, loss, pending = core(
@@ -364,7 +385,7 @@ def make_sparse_train_step(model, optimizer: str = "adagrad", lr=0.01,
         new_state = {**new_state, "emb": {**new_state["emb"], "tp": tp_s}}
         return new_params, new_state, loss
 
-    return init_fn, run
+    return with_handle(run, core)
 
 
 def fit(model, params, data, steps: int, optimizer: str = "adagrad",

@@ -3,7 +3,8 @@
 The system exposes ~15 orthogonal performance knobs (scatter impl,
 lookup path, exchange wire, id wire, storage dtypes, hot rows,
 lookahead, pipeline depth, publish cadence, admission limits, ...),
-per-span device-second attribution (obs/attribution.py) and static cost
+per-stage device time (the `det.*` stage scopes of obs/stages.py, read
+from a trace by `benchmark.run --trace 1`) and static cost
 models (analysis.programs.expected_collective_bytes,
 exchange_padding_report, docs/perf_model.md projections). This package
 closes the measure->decide loop (ROADMAP item 5):

@@ -229,9 +229,8 @@ def trace(logdir: str, host_tracer_level: int = 2,
         knob for long captures (ISSUE 14): the python tracer emits one
         event per interpreted call, and a multi-second bench run
         overflows the profiler's host event buffer with them — observed
-        to silently DROP the later `TraceAnnotation` events the
-        attribution parser needs (`obs.attribution`; the kernels bench's
-        late arms lost their span windows). None (default) keeps the
+        to silently DROP the later `TraceAnnotation` events (the kernels
+        bench's late arms lost their spans). None (default) keeps the
         profiler's stock behavior.
 
     When either knob differs from the stock (2, None) the session is

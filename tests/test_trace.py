@@ -1,5 +1,6 @@
-"""Flight recorder, version lineage, device-time attribution and
-postmortem artifacts (ISSUE 14).
+"""Flight recorder, version lineage and postmortem artifacts (ISSUE 14;
+the device-time attribution of that issue went with ISSUE 25, whose stage
+scopes are held by tests/test_stage_scopes.py).
 
 The contracts under test: (a) the recorder ring stays within its
 configured bound under a long synthetic run and the chrome-trace
@@ -9,16 +10,11 @@ synthetically closed) — including after eviction cut the window;
 the `span_seconds{span=}` nesting; (c) a store version's life is one
 async lineage track — commit opens, publish/scan/apply ride,
 the first predict at >= V closes — version-monotonic across a real
-publish->poll->predict loop; (d) the attribution parser assigns every
-device op to the innermost enclosing span window with the
-spans+unattributed == total identity exact, measures collective
-exposure, exports the `device/*` gauges, and reconciles projections;
-(e) degraded-mode ENTRY dumps a postmortem artifact (ring + snapshot)
-when `DET_OBS_POSTMORTEM_DIR` is set; (f) the registry export
-satellites — per-line JSONL flush/fsync and Prometheus label
-escaping."""
+publish->poll->predict loop; (d) degraded-mode ENTRY dumps a postmortem
+artifact (ring + snapshot) when `DET_OBS_POSTMORTEM_DIR` is set; (e) the
+registry export satellites — per-line JSONL flush/fsync and Prometheus
+label escaping."""
 
-import gzip
 import json
 import os
 import threading
@@ -31,7 +27,6 @@ from distributed_embeddings_tpu import faults, obs
 from distributed_embeddings_tpu.layers.embedding import Embedding
 from distributed_embeddings_tpu.layers.dist_model_parallel import (
     DistributedEmbedding)
-from distributed_embeddings_tpu.obs import attribution
 from distributed_embeddings_tpu.obs.trace import FlightRecorder
 from distributed_embeddings_tpu.parallel.mesh import create_mesh
 from distributed_embeddings_tpu.serving import InferenceEngine
@@ -224,113 +219,6 @@ def test_lineage_rejects_unknown_phase_and_autoopens_consumer_side():
     evs = rec.events()
     assert [e[0] for e in evs] == ["b", "n"]
     assert rec.lineage_versions() == [7]
-
-
-# --------------------------------------------------------- attribution
-def _fixture_events():
-    """Synthetic chrome trace: two nested span windows on a host
-    thread, device ops on a /device: process. Timings in us."""
-    return [
-        {"ph": "M", "pid": 9, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        # span windows (host annotations; the shape heuristic needs a
-        # "/" in the path — exactly what composed span paths carry)
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 0, "dur": 1000,
-         "name": "bench/outer"},
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 100, "dur": 300,
-         "name": "bench/outer/inner"},
-        # python-tracer noise: must never become a window
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 0, "dur": 2000,
-         "name": "$runpy.py:1 run"},
-        # device ops: midpoint decides the window, innermost wins
-        {"ph": "X", "pid": 9, "tid": 2, "ts": 150, "dur": 100,
-         "name": "fusion.1", "args": {"hlo_op": "fusion.1"}},      # inner
-        {"ph": "X", "pid": 9, "tid": 2, "ts": 500, "dur": 200,
-         "name": "all-to-all.2",
-         "args": {"hlo_op": "all-to-all.2"}},                      # outer
-        {"ph": "X", "pid": 9, "tid": 3, "ts": 550, "dur": 100,
-         "name": "fusion.3", "args": {"hlo_op": "fusion.3"}},      # outer
-        {"ph": "X", "pid": 9, "tid": 2, "ts": 1500, "dur": 50,
-         "name": "copy.4", "args": {"hlo_op": "copy.4"}},    # outside all
-    ]
-
-
-def test_attribution_innermost_window_sum_identity_and_exposure():
-    att = attribution.attribute_device_time(_fixture_events())
-    assert att["spans"] == {"bench/outer": pytest.approx(300e-6),
-                            "bench/outer/inner": pytest.approx(100e-6)}
-    assert att["unattributed_seconds"] == pytest.approx(50e-6)
-    assert att["total_device_seconds"] == pytest.approx(450e-6)
-    total = sum(att["spans"].values()) + att["unattributed_seconds"]
-    assert total == pytest.approx(att["total_device_seconds"])
-    assert att["device_op_count"] == 4
-    assert att["span_window_count"] == 2     # the $-frame is excluded
-    # exposure: the 200us all-to-all overlaps fusion.3 on [550, 650]
-    coll = att["collective"]
-    assert coll["device_seconds"] == pytest.approx(200e-6)
-    assert coll["overlapped_seconds"] == pytest.approx(100e-6)
-    assert coll["exposed_seconds"] == pytest.approx(100e-6)
-    assert coll["exposed_fraction"] == pytest.approx(0.5)
-    assert coll["per_span"]["bench/outer"]["exposed_fraction"] == \
-        pytest.approx(0.5)
-    # single host thread: nothing is cross-thread ambiguous
-    assert att["ambiguous_seconds"] == 0.0
-    # explicit span set: restricting to the outer span folds inner's
-    # ops into it
-    att2 = attribution.attribute_device_time(
-        _fixture_events(), span_paths={"bench/outer"})
-    assert att2["spans"] == {"bench/outer": pytest.approx(400e-6)}
-
-
-def test_attribution_flags_cross_thread_window_ambiguity():
-    """Concurrent spans on DIFFERENT host threads (a serving span under
-    a background trainer's window) make midpoint attribution a guess —
-    the overlap region's device time must be totaled as ambiguous,
-    while single-thread nesting stays unambiguous."""
-    events = [
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 0, "dur": 1000,
-         "name": "train/step"},
-        {"ph": "X", "pid": 1, "tid": 2, "ts": 400, "dur": 200,
-         "name": "serve/predict"},            # overlaps on another thread
-        {"ph": "X", "pid": 9, "tid": 5, "ts": 450, "dur": 100,
-         "name": "fusion.1", "args": {"hlo_op": "fusion.1"}},  # in both
-        {"ph": "X", "pid": 9, "tid": 5, "ts": 700, "dur": 100,
-         "name": "fusion.2", "args": {"hlo_op": "fusion.2"}},  # train only
-    ]
-    att = attribution.attribute_device_time(events)
-    # the contested op went to the shortest window; flagged ambiguous
-    assert att["spans"]["serve/predict"] == pytest.approx(100e-6)
-    assert att["spans"]["train/step"] == pytest.approx(100e-6)
-    assert att["ambiguous_seconds"] == pytest.approx(100e-6)
-
-
-def test_attribution_logdir_gauges_and_reconciliation(tmp_path):
-    run = tmp_path / "plugins" / "profile" / "2026_01_01"
-    os.makedirs(run)
-    with gzip.open(run / "host.trace.json.gz", "wb") as f:
-        f.write(json.dumps(
-            {"traceEvents": _fixture_events()}).encode())
-    reg = obs.MetricRegistry()
-    # the registry's recorded span paths pin the window set
-    reg.histogram("span_seconds", span="bench/outer").record(0.001)
-    reg.histogram("span_seconds", span="bench/outer/inner").record(0.0003)
-    att = attribution.attribute_logdir(str(tmp_path), registry=reg)
-    assert att["trace_file"] == "host.trace.json.gz"
-    g = reg.snapshot()["gauges"]
-    assert g["device/span_seconds{span=bench/outer/inner}"] == \
-        pytest.approx(100e-6)
-    assert g["device/unattributed_seconds"] == pytest.approx(50e-6)
-    assert g["device/total_seconds"] == pytest.approx(450e-6)
-    assert g["device/exposed_exchange_fraction"] == pytest.approx(0.5)
-    rows = attribution.reconciliation_table(
-        att, {"bench/outer/inner": 0.1, "bench/outer": 10.0,
-              "nope": 1.0})
-    by = {r["phase"]: r for r in rows}
-    assert by["bench/outer/inner"]["verdict"] == "settled"  # 0.1 ~ 0.1ms
-    assert by["bench/outer"]["verdict"] == "falsified"      # 0.3 vs 10ms
-    assert by["nope"]["verdict"] == "unmeasured"
-    with pytest.raises(FileNotFoundError, match="chrome trace"):
-        attribution.find_trace_file(str(tmp_path / "empty"))
 
 
 # ---------------------------------------------------------- postmortem

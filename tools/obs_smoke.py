@@ -1,15 +1,13 @@
-"""Tier-1 observability smoke (ISSUE 11/14): one registry across a real
-fit -> publish -> serve loop, schema-checked, SLO-gated, device-time
-attributed.
+"""Tier-1 observability smoke (ISSUE 11/14/25): one registry across a
+real fit -> publish -> serve loop, schema-checked, SLO-gated, and the
+train step's stage scopes checked in its compiled program.
 
 What it drives (tiny shapes, CPU, ~a minute):
 
   1. `training.fit` with the lookahead engine AND a publishing
      `TableStore`, all reporting into ONE `obs.MetricRegistry` — train
      spans/counters, ingest stage histograms, lookahead patch/compile
-     metrics, store publish counters land in the same namespace. The
-     fit runs under a REAL jax profiler capture (CPU backend), so the
-     attribution parser below works on genuine profiler output.
+     metrics, store publish counters land in the same namespace.
   2. An `InferenceEngine` replica consuming the published stream
      (`poll_updates`) and serving requests through a `MicroBatcher` on
      the SAME registry — apply/staleness/latency metrics join the
@@ -22,11 +20,12 @@ What it drives (tiny shapes, CPU, ~a minute):
      (tools/slo_tier1.json) evaluated over the snapshot — compile-count
      and audit-findings rules active, NO perf rules (CI hosts are
      steal-noisy; perf gates live in docs/perf_model.md).
-  5. Device-time attribution (ISSUE 14): the fit's profiler capture is
-     parsed by `obs.attribution`, asserting NONZERO span coverage
-     (device ops attributed to the span annotations PR 11 opened), the
-     attribution-record schema (spans + unattributed == total), and
-     the exported ``device/*`` gauges in the snapshot.
+  5. Stage scopes (ISSUE 25): the smoke's own train step
+     (`make_sparse_train_step` over the same model and batch shapes),
+     lowered through the step's handle `step_fn.lower` and compiled, is
+     named ``jit_det_train_step`` and its operations carry every
+     ``det.*`` stage of `obs.stages.STAGES` that a tables-only model
+     runs in their ``op_name`` paths.
   6. Flight-recorder checks: the ring holds the run's spans, the
      chrome-trace export loads and balances, and the lineage tracks
      cover every published version.
@@ -90,29 +89,22 @@ def check(cond, msg):
 
 def main() -> int:
     from distributed_embeddings_tpu.parallel.mesh import create_mesh
-    from distributed_embeddings_tpu.utils import profiling
     mesh = create_mesh(jax.devices()[:WORLD])
     rng = np.random.RandomState(0)
     reg = obs.default_registry()
     obs.reset_default_recorder()      # this run's ring only (check 6)
     tmp = tempfile.mkdtemp(prefix="det_obs_smoke_")
-    profile_dir = os.path.join(tmp, "profile")
     try:
         # ---- 1. publisher fit: lookahead engine + weight streaming --
-        # under a REAL profiler capture (CPU): the attribution check
-        # below must parse genuine jax profiler output, not a fixture
         model = _programs.build_model(VOCAB, WIDTH, "sum", tables=TABLES,
                                       mesh=mesh)
         params = {"embedding": model.embedding.init(jax.random.PRNGKey(0))}
         store = TableStore(model.embedding, params["embedding"])
-        # python tracer off: per-call python events would overflow the
-        # host buffer and drop late span annotations (profiling.trace)
-        with profiling.trace(profile_dir, python_tracer_level=0):
-            params, opt_state, history = training.fit(
-                model, params, make_batches(rng, STEPS), steps=STEPS,
-                optimizer="adagrad", lr=0.05, log_every=0, lookahead=1,
-                store=store, publish_every=PUBLISH_EVERY, publish_dir=tmp,
-                registry=reg)
+        params, opt_state, history = training.fit(
+            model, params, make_batches(rng, STEPS), steps=STEPS,
+            optimizer="adagrad", lr=0.05, log_every=0, lookahead=1,
+            store=store, publish_every=PUBLISH_EVERY, publish_dir=tmp,
+            registry=reg)
         check("metrics_snapshot" in history,
               "fit history has no metrics_snapshot")
         check("metrics_error" not in history,
@@ -147,25 +139,25 @@ def main() -> int:
         if audit_ids:
             print(f"audit findings: {audit_ids}", file=sys.stderr)
 
-        # ---- 5. device-time attribution over the real capture ------
-        att = obs.attribution.attribute_logdir(profile_dir, registry=reg)
-        for field in ("total_device_seconds", "spans",
-                      "unattributed_seconds", "ambiguous_seconds",
-                      "coverage_frac", "device_op_count",
-                      "span_window_count", "collective"):
-            check(field in att, f"attribution record missing {field!r}")
-        check(att["device_op_count"] > 0, "no device ops in the capture")
-        check(att["span_window_count"] > 0,
-              "no span annotation windows in the capture")
-        check(att["spans"] and sum(att["spans"].values()) > 0,
-              "zero span coverage: no device time attributed to spans")
-        total = sum(att["spans"].values()) + att["unattributed_seconds"]
-        check(abs(total - att["total_device_seconds"]) < 1e-6,
-              f"attribution does not sum: {total} != "
-              f"{att['total_device_seconds']}")
-        check(any(p.startswith("train/step") for p in att["spans"]),
-              f"train/step not among attributed spans: "
-              f"{sorted(att['spans'])}")
+        # ---- 5. stage scopes in the step's own compiled program -----
+        from distributed_embeddings_tpu.obs import stages
+        init_fn, step_fn = training.make_sparse_train_step(
+            model, "adagrad", lr=0.05)
+        num, cats, lab = make_batches(rng, 1)[0]
+        compiled = step_fn.lower(params, init_fn(params), num, cats,
+                                 lab).compile()
+        text = compiled.as_text()
+        check(step_fn.name == stages.STEP_NAME
+              and f"HloModule jit_{stages.STEP_NAME}" in text,
+              f"the step is not named {stages.STEP_NAME}")
+        import re as _re
+        staged = set(_re.findall(
+            r'op_name="[^"]*/' + _re.escape(stages.PREFIX) + r'([a-z_]+)/',
+            text))
+        # the smoke's model is tables only: no dense optimizer runs
+        want = set(stages.STAGES) - {"dense_opt"}
+        check(staged == want,
+              f"compiled step's stages {sorted(staged)} != {sorted(want)}")
 
         # ---- 6. flight recorder: ring, export, lineage --------------
         rec = obs.default_recorder()
@@ -219,12 +211,6 @@ def main() -> int:
               "request latency count")
         check(any(k.startswith("ingest/stage_seconds") for k in h),
               "ingest stage histograms")
-        # ISSUE 14: the attribution gauges joined the same namespace
-        check(any(k.startswith("device/span_seconds") for k in g),
-              "device/span_seconds gauges")
-        check("device/unattributed_seconds" in g
-              and "device/total_seconds" in g, "device totals gauges")
-
         # ---- 4b. export round trips --------------------------------
         jsonl = os.path.join(tmp, "metrics.jsonl")
         reg.export_jsonl(jsonl, extra={"source": "obs_smoke"})
@@ -253,7 +239,6 @@ def main() -> int:
             os.path.abspath(__file__))), "docs", "observability.md")
         with open(doc_path) as f:
             doc_text = f.read()
-        import re as _re
         wildcards = [m.group(1) + "/"
                      for m in _re.finditer(r"`([\w/]+)/\*`", doc_text)]
         families = sorted({key.split("{", 1)[0]
@@ -275,8 +260,7 @@ def main() -> int:
             "fused_compiles": g["lookahead/compiles{stage=fused}"],
             "audit_findings": len(audit_ids),
             "slo_rules_evaluated": len(obs.load_rules(rules_path)),
-            "device_coverage_frac": att["coverage_frac"],
-            "device_spans": len(att["spans"]),
+            "step_stages": sorted(staged),
             "flight_events": len(doc["traceEvents"]),
             "lineage_versions": sorted(lineage),
             "metric_families_checked": len(families),
