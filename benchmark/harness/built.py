@@ -1,6 +1,13 @@
 """What a builder hands the harness: the system under test behind its
 public entry points, and the plain facts the generator, the reference check
-and the roofline need about it."""
+and the roofline need about it.
+
+A batch is ``(inputs, cats, labels)``: `cats` the ids per model input, the
+first and the last whatever the cell's generator made and the model's
+``loss_fn(params, inputs, cats, labels, taps=, return_residuals=)`` takes:
+numerical features and ``[B, 1]`` f32 clicks for a click model, document
+boundaries and ``[T]`` int32 next-token ids for a language model. The
+harness stages them and passes them through."""
 
 import dataclasses
 from typing import Any, Callable, List, Optional, Tuple
@@ -13,11 +20,12 @@ class Built:
     tables: List[Tuple[int, int]]    # (rows, width) per table
     table_map: List[int]             # input -> table
     hotness: List[int]               # ids per sample, per input
-    num_numerical: int
-    numerical_scale: float
+    num_numerical: int               # these two are parameters the builder
+    numerical_scale: float           # hands the cell's generator
     global_batch: int
     optimizer: dict                  # the config's, for the reference's rule
-    reference: str                   # key of benchmark.reference.LOGITS
+    #                                  (sgd, adagrad, adam) and the roofline
+    reference: str                   # benchmark/references/<reference>.py
     dense_params: Callable[[Any], dict]   # program params -> reference's tree
     mlp_flops_per_sample: int        # forward + backward matmul flops
     ids_1d: bool = False             # one-hot inputs are passed as [B]

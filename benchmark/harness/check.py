@@ -1,5 +1,12 @@
 """The comparison that decides ``correct``: the system's first steps against
-the plain reference (``benchmark/reference.py``), during set-up.
+the plain reference, during set-up. The reference is the configuration's
+module ``benchmark/references/<Built.reference>.py``, whose ``loss(dense,
+embs, inputs, labels)`` is the model's forward and loss, under the steps,
+the embedding stage and the optimizer rules (sgd, adagrad, adam) of
+``benchmark/reference.py``. A batch is ``(inputs, cats, labels)``: the check
+reads the ids, and hands the first and the last array to the program's
+``loss_fn`` and to the reference's ``loss`` as the cell's generator made
+them.
 
 The reference reads the same initial arrays as the system: the tables as
 ``DistributedEmbedding.get_weights`` exports them to the host (the library's
@@ -17,7 +24,8 @@ between the two is what the model's precision explains: measured on this
 device, not assumed, and next to nothing on a CPU and where the
 configuration says ``highest``.
 
-Held, on the first ``CHECK_STEPS`` steps (which are also the warm-up):
+Held, on the first `check_steps` steps (which are also the warm-up): two,
+and three under adam, whose rule needs a hit, a miss and a hit to show:
 
 (a) the embedding stage's outputs for batch 0 against take-and-sum, at
     rtol 1e-5 of the value plus 1e-6 of the input's largest value: f32
@@ -35,7 +43,42 @@ Held, on the first ``CHECK_STEPS`` steps (which are also the warm-up):
     have moved had no two contributions cancelled: 4e-5 (f32 summation
     order over up to 1e5 duplicates) plus four times the share by which the
     precision moves the gradients of the table's inputs. A duplicate lost
-    from a row hit twice is half the change;
+    from a row hit twice is half the change.
+    That last term is an error in the gradient's sum, carried to the row by
+    `size`, d change / d gradient. Under sgd and adagrad a step is linear in
+    the summed gradient (adagrad's accumulator starts at 0.1, far above a
+    squared gradient, and is held), `size` is the step size and "what the
+    row would have moved by" is `size` times the sum of the contributions'
+    absolute values, the largest of the row's elements: the matrix product
+    that made a contribution rounds each element by a share of the row's
+    scale. Adam's step is not linear: the change is ``-size * mu`` with
+    ``size = lr / ((1 - b1**t) * (sqrt(nu / (1 - b2**t)) + eps))``, the
+    gradient is in both moments, and on a row's first hit the change is
+    ``-lr * g / (|g| + eps)`` times the count's correction: lr in size
+    whatever the gradient's. So under adam `size` is what multiplies the
+    first moment, the reference sums ``-size * mu`` as the row's change, and
+    "what it would have moved by" is, **element by element**, the
+    first-order bound of what an error of a share of the row's largest
+    absolute sum does to that product: ``size * (mu_abs + |mu| * nu_x /
+    ((1 - b2**t) * root * (root + eps)))``, with ``mu_abs`` and ``nu_x``
+    the same two moments taken of that largest sum
+    (``benchmark.reference._adam``): the first term is the error in ``mu``,
+    the second the error in the root under it. A row is held by its worst
+    element, each against its own tolerance. On a first hit that is ``2 *
+    lr * (the row's largest sum) / |g|`` for an element: one whose own
+    gradient is a ten-thousandth of the row's scale, or whose contributions all
+    but cancel, has a sign that rounding decides and a tolerance as wide as
+    its step, while the row's other elements are held to ~1e-4 of lr (one
+    tolerance for the row, as under the other rules, would fail a sound run
+    at such an element or hold no element tighter than that one; PERF.md
+    section 6, PR 28, has the readings). A duplicate lost from a row flips the sign of every element in which the
+    lost contribution outweighed the rest (2 lr off) and changes the ratio
+    of two steps' gradients in the moments; where all contributions of a
+    first hit agree in sign, adam itself moves the row alike with and
+    without it, and no reading of rows can tell until the row's next hit.
+    Moments decayed on a row that a step did not hit show at the row's next
+    hit, a tenth of the earlier gradient's part of that step: three steps,
+    hit, not hit, hit, which is why `check_steps` gives adam three;
 (d) probed rows that no batch touched: bit-identical.
 
 Rows are read back through the layer's own forward on one-hot probe inputs,
@@ -47,11 +90,21 @@ from typing import NamedTuple
 import numpy as np
 
 CHECK_STEPS = 2          # DLRM's schedule gives lr 0 on step 0: rows move on 1
+ADAM_CHECK_STEPS = 3     # moments decayed on a row not hit show at its next hit
 PROBE_ROWS = 256         # per probe-able table: half touched, half untouched
 
 
+def check_steps(optimizer):
+    """How many first steps the system is held to under this optimizer."""
+    return ADAM_CHECK_STEPS if optimizer["kind"] == "adam" else CHECK_STEPS
+
+
 class CheckFailed(Exception):
-    pass
+    """`summary`: what `compare` had read when it failed."""
+
+    def __init__(self, message, summary=None):
+        super().__init__(message)
+        self.summary = summary or {}
 
 
 class Probe(NamedTuple):
@@ -137,11 +190,13 @@ def compact(built, weights, batches, touched):
     return tables, renumbered
 
 
-def reference_results(built, weights, dense, batches, touched, precision):
+def reference_results(built, model_loss, weights, dense, batches, touched,
+                      precision):
     """Everything the comparison needs from the reference, computed before
-    the system takes a step: its CHECK_STEPS steps at the configuration's
-    matmul `precision`, results on the host. `dense` is the reference's
-    dense tree as host arrays."""
+    the system takes a step: one step per batch at the configuration's
+    matmul `precision`, results on the host. `model_loss` is the
+    configuration's ``loss(dense, embs, inputs, labels)``, `dense` the
+    reference's dense tree as host arrays."""
     import jax
 
     from benchmark import reference
@@ -154,7 +209,7 @@ def reference_results(built, weights, dense, batches, touched, precision):
     with jax.default_matmul_precision(precision):
         embs, losses, losses_high, change, moved, share = (
             reference.train_steps(
-                reference.LOGITS[built.reference], built.optimizer, tables,
+                model_loss, built.optimizer, tables,
                 built.table_map, dense, renumbered, sparse_device=host))
     return {"kept": {t: seen for t, (seen, _) in touched.items()},
             "before": tables,
@@ -179,15 +234,25 @@ class Check:
         `phase(name)` is told when a part of the work ends."""
         import jax
 
+        from benchmark.harness import spec
+
         self.built = built
+        # a configuration whose reference is not there fails before the export
+        model_loss = spec.plugin("references", built.reference).loss
         touched = touched_rows(built, host_batches)
         self.probes = select_probes(built, touched)
         weights = built.model.embedding.get_weights(params["embedding"])
         dense = jax.tree.map(np.asarray, built.dense_params(params))
         phase("export")
-        self.ref = reference_results(built, weights, dense, host_batches,
-                                     touched, precision)
+        self.ref = reference_results(built, model_loss, weights, dense,
+                                     host_batches, touched, precision)
         del weights
+        # the fullest chip when the reference has taken its steps beside the
+        # system's arguments (None where the backend keeps no count)
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in jax.devices()]
+        self.reference_peak_bytes = max(
+            (b for b in peaks if b is not None), default=None)
         phase("reference")
         self._forward = jax.jit(
             lambda p, cats: built.model.embedding(p, cats))
@@ -211,20 +276,36 @@ class Check:
 
 
 def compare(built, ref, probes, sys_embs, sys_losses, rows_before, rows_after):
-    """Raise CheckFailed on the first of (a)-(d) that does not hold; return
-    a summary of how close the system came otherwise.
+    """Raise CheckFailed on the first of (a)-(d) that does not hold, with
+    what was read until then as its `summary`; return a summary of how close
+    the system came otherwise. ``summary["compared"]`` holds every number
+    compared beside its limit, ``{name: [number, limit]}``.
 
     `sys_embs`: the system's embedding outputs for batch 0. `rows_before` /
     `rows_after`: {table: [PROBE_ROWS, width]} read through the forward."""
-    ref_embs, ref_change, ref_moved = ref["embs"], ref["change"], ref["moved"]
-    summary = {}
+    summary = {"compared": {}}
+    try:
+        _compare(summary, built, ref, probes, sys_embs, sys_losses,
+                 rows_before, rows_after)
+    except CheckFailed as e:
+        e.summary = summary
+        raise
+    return summary
 
-    worst = 0.0
+
+def _compare(summary, built, ref, probes, sys_embs, sys_losses, rows_before,
+             rows_after):
+    ref_embs, ref_change, ref_moved = ref["embs"], ref["change"], ref["moved"]
+    compared = summary["compared"]
+
+    worst = excess = 0.0
     for inp, (got, want) in enumerate(zip(sys_embs, ref_embs)):
         got = np.asarray(got, np.float32).reshape(want.shape)
         scale = float(np.max(np.abs(want))) or 1.0
         err = np.abs(got - want) - 1e-5 * np.abs(want)
         worst = max(worst, float(np.max(np.abs(got - want))) / scale)
+        excess = max(excess, float(np.max(err)) / scale)
+        compared["emb_err_beyond_rtol_over_largest"] = [excess, 1e-6]
         if np.max(err) > 1e-6 * scale:
             raise CheckFailed(
                 f"(a) embedding output of input {inp} is off by "
@@ -240,7 +321,9 @@ def compare(built, ref, probes, sys_embs, sys_losses, rows_before, rows_after):
         summary["loss"].append({"step": i, "system": got, "reference": want,
                                 "reference_at_highest": high,
                                 "tolerance": tol})
-        if not min(abs(got - want), abs(got - high)) <= tol:
+        off = min(abs(got - want), abs(got - high))
+        compared[f"loss{i}_off"] = [off, tol]
+        if not off <= tol:
             raise CheckFailed(
                 f"(b) loss of step {i} is {got!r}, the reference's {want!r} "
                 f"({high!r} under `highest`; tolerance {tol:.3e}, of which "
@@ -248,10 +331,13 @@ def compare(built, ref, probes, sys_embs, sys_losses, rows_before, rows_after):
 
     moved = touched = untouched = 0
     worst_rel = 0.0
+    compared["untouched_rows_changed"] = [0, 0]
     for t, (_, ids, kind, hits) in probes.items():
         before, after = rows_before[t], rows_after[t]
         same = kind == 2
         if not np.array_equal(before[same], after[same]):
+            compared["untouched_rows_changed"] = [
+                int(np.any(before[same] != after[same], axis=1).sum()), 0]
             raise CheckFailed(f"(d) rows of table {t} that no batch touched "
                               "changed")
         untouched += int(same.sum())
@@ -262,27 +348,34 @@ def compare(built, ref, probes, sys_embs, sys_losses, rows_before, rows_after):
                 f"(c) table {t}: the forward reads other rows than "
                 "get_weights exported")
         want = ref_change[t][at]
-        could = ref_moved[t][at].max(axis=1)      # had nothing cancelled
+        could = ref_moved[t][at]                  # had nothing cancelled
+        if built.optimizer["kind"] != "adam":     # the row's: see (c)
+            could = could.max(axis=1, keepdims=True)
         got = after[hit] - before[hit]
-        tol = (1e-4 * np.abs(want).max(axis=1)
-               + (hits[hit] + 1) * 2.0 ** -24 * np.abs(after[hit]).max(axis=1)
+        tol = ((1e-4 * np.abs(want).max(axis=1)
+                + (hits[hit] + 1) * 2.0 ** -24
+                * np.abs(after[hit]).max(axis=1))[:, None]
                + (4e-5 + 4 * ref["precision_share"][t]) * could)
-        err = np.abs(got - want).max(axis=1)
-        bad = np.flatnonzero(err > tol)
+        err = np.abs(got - want)
+        tol = np.broadcast_to(tol, err.shape)
+        rel = (err / tol).max(axis=1)             # a row by its worst element
+        if len(rel):
+            worst_rel = max(worst_rel, float(np.max(rel)))
+        compared["row_err_over_tolerance"] = [worst_rel, 1.0]
+        bad = np.flatnonzero(np.any(err > tol, axis=1))
         if len(bad):
             r = bad[0]
+            e = int(np.argmax(err[r] / tol[r]))
             raise CheckFailed(
                 f"(c) table {t} row {int(ids[hit][r])}: changed by "
                 f"{got[r][:4]}..., the reference's rule gives "
-                f"{want[r][:4]}... (off by {err[r]:.3e}, tolerance "
-                f"{tol[r]:.3e}; {len(bad)} of {int(hit.sum())} probed rows)")
+                f"{want[r][:4]}... (off by {err[r][e]:.3e}, tolerance "
+                f"{tol[r][e]:.3e}; {len(bad)} of {int(hit.sum())} probed "
+                "rows)")
         touched += int(hit.sum())
         moved += int(np.any(got != 0, axis=1).sum())
-        if len(err):
-            worst_rel = max(worst_rel, float(np.max(err / tol)))
     summary.update(probed_tables=len(probes), touched_rows=touched,
                    touched_rows_moved=moved, untouched_rows=untouched,
                    row_err_over_tolerance_max=worst_rel,
                    gradient_share_explained_by_precision=max(
                        ref["precision_share"][t] for t in probes))
-    return summary

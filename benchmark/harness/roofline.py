@@ -11,7 +11,10 @@ optimizer:
   writes it;
 * adagrad, 7 transfers: the forward reads the row, the backward's
   scatter-add reads and writes it, and the update reads and writes both the
-  row and its accumulator (``bench.py``'s count).
+  row and its accumulator (``bench.py``'s count);
+* adam, 9 transfers: the forward reads the row, the backward's scatter-add
+  reads and writes it, and the lazy row-wise update reads and writes the
+  row and both of its moments (the same count with one more state array).
 
 That is the optimistic bound: no ids, no gradients, no duplicates removed.
 The dense model is bound by the matrix unit's published bf16 peak.
@@ -20,7 +23,7 @@ The dense model is bound by the matrix unit's published bf16 peak.
 import json
 import os
 
-ROW_TRANSFERS = {"sgd": 3, "adagrad": 7}
+ROW_TRANSFERS = {"sgd": 3, "adagrad": 7, "adam": 9}
 _PEAKS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "peaks.json")
 

@@ -1,8 +1,19 @@
-"""Plain references: the two models' forward, loss, gradients and optimizer
-rules in straightforward ``jax.numpy``.
+"""What every plain reference shares: the embedding stage, the optimizer
+rules and the training steps, in straightforward ``jax.numpy``.
 
-Nothing here imports ``distributed_embeddings_tpu``. Every function is f32.
-The callers run the steps under the configuration's own
+A configuration's model is a module of its own, ``benchmark/references/
+<name>.py`` (`Built.reference` names it): it exposes ``loss(dense, embs,
+inputs, labels) -> f32 scalar``, the model's forward pass from the embedding
+stage's outputs on and its loss. ``dense`` is the reference's dense tree
+(`Built.dense_params`), ``embs`` one ``[B, width]`` array per input, and
+``inputs`` and ``labels`` are the first and the last array of a batch
+``(inputs, cats, labels)`` as the cell's generator made them and the
+program's ``loss_fn`` takes them: numerical features and ``[B, 1]`` f32
+clicks for a click model, document boundaries and ``[T]`` int32 next-token
+ids for a language model. Nothing here reads, reshapes or casts them.
+
+Nothing here or there imports ``distributed_embeddings_tpu``. Every function
+is f32. The callers run the steps under the configuration's own
 ``jax.default_matmul_precision`` (on a TPU an f32 matmul otherwise runs in
 bf16 passes, and the system's does), and every step's loss and embedding
 gradients are evaluated once more under ``"highest"`` on the same state:
@@ -13,19 +24,6 @@ no fusion of tables into buckets, no exchange, no dedup: a table is one
 summed, and the gradient of a row that several ids hit is written out as
 the sum over every one of them.
 
-Published descriptions followed:
-
-* Synthetic models (NVIDIA-Merlin/distributed-embeddings,
-  ``examples/benchmarks/synthetic_models/synthetic_models.py``): embeddings
-  with the ``sum`` combiner, concatenated in input order with the numerical
-  features appended, an MLP with ReLU between layers, one logit, sigmoid
-  binary cross-entropy averaged over the global batch.
-* DLRM (Naumov et al., arXiv:1906.00091, as MLPerf and the reference's
-  ``examples/dlrm`` run it): bottom MLP with ReLU after every layer, the
-  pairwise dot products of the bottom output and the 26 embeddings (strictly
-  lower triangle, row-major) concatenated in front of the bottom output, top
-  MLP with ReLU between layers, one logit, the same loss.
-
 Optimizer rules, as the repo applies them (``training._sparse_optimizer_setup``,
 ``ops/sparse_update``; optax for the dense part):
 
@@ -33,7 +31,18 @@ Optimizer rules, as the repo applies them (``training._sparse_optimizer_setup``,
 * adagrad:  ``acc += g**2; p -= lr * g * rsqrt(acc + eps)`` with
   ``acc0 = initial_accumulator_value`` (0.1) and ``eps = 1e-7``; for an
   embedding row ``g`` is the row's summed gradient.
+* adam:     ``t += 1; mu = b1 * mu + (1 - b1) * g; nu = b2 * nu + (1 - b2) *
+  g**2; p -= lr * (mu / (1 - b1**t)) / (sqrt(nu / (1 - b2**t)) + eps)`` with
+  ``mu0 = nu0 = 0``, ``t0 = 0`` and the configuration's ``b1``, ``b2``,
+  ``eps`` (optax's and ``sparse_adam``'s defaults: 0.9, 0.999, 1e-8). The
+  dense tree takes it whole (optax). A table takes it lazily and row-wise
+  (``ops/sparse_update.sparse_adam``): ``t`` is one count for the table,
+  advances every step and corrects the bias of every row alike; a row's
+  moments decay, take ``g`` (the row's summed gradient) and move the row
+  only in a step in which an id hit it, and stay as they are otherwise.
 """
+
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -53,23 +62,6 @@ def mlp(layers, x, final_activation=False):
         if i < len(layers) - 1 or final_activation:
             x = jnp.maximum(x, 0.0)
     return x
-
-
-def synthetic_logits(dense, embs, numerical):
-    x = jnp.concatenate(list(embs) + [numerical], axis=1)
-    return mlp(dense["mlp"], x)[:, 0]
-
-
-def dlrm_logits(dense, embs, numerical):
-    bottom = mlp(dense["bottom"], numerical, final_activation=True)
-    feats = jnp.stack([bottom] + list(embs), axis=1)          # [B, F+1, d]
-    gram = jnp.einsum("bfd,bgd->bfg", feats, feats)
-    rows, cols = np.tril_indices(feats.shape[1], k=-1)
-    pairwise = gram[:, rows, cols]
-    return mlp(dense["top"], jnp.concatenate([pairwise, bottom], axis=1))[:, 0]
-
-
-LOGITS = {"synthetic": synthetic_logits, "dlrm": dlrm_logits}
 
 
 def bce_with_logits(logits, labels):
@@ -95,32 +87,127 @@ def learning_rate(optimizer, step):
     return sched["base_lr"] * factor
 
 
-def init_state(optimizer, tree):
-    """The optimizer's initial state for `tree`, as host arrays."""
-    if optimizer["kind"] == "sgd":
+def init_state(optimizer, tree, sums_abs=False):
+    """The optimizer's initial state for `tree`, as host arrays. `sums_abs`:
+    `tree` is the tables, and `apply_rule` will be given the sums of the
+    contributions' absolute values (adam keeps two moments of them)."""
+    kind = optimizer["kind"]
+    if kind == "sgd":
         return None
-    acc0 = optimizer["initial_accumulator_value"]
-    return jax.tree.map(lambda p: np.full(np.shape(p), acc0, np.float32), tree)
+
+    def full(value):
+        return jax.tree.map(lambda p: np.full(np.shape(p), value, np.float32),
+                            tree)
+    if kind == "adagrad":
+        return full(optimizer["initial_accumulator_value"])
+    if kind != "adam":
+        raise ValueError(f"the reference has no rule for optimizer {kind!r} "
+                         "(it has sgd, adagrad and adam)")
+    state = {"count": np.zeros((), np.int32), "mu": full(0.0), "nu": full(0.0)}
+    if sums_abs:
+        state.update(mu_abs=full(0.0), nu_x=full(0.0))
+    return state
 
 
-def apply_rule(optimizer, lr, params, grads, state):
-    """One optimizer step over a pytree -> (params, state, step size): the
-    last is d(change)/d(gradient), by which an error in a gradient sum
-    shows in the parameter."""
-    if optimizer["kind"] == "sgd":
+class Applied(NamedTuple):
+    """One optimizer step over a pytree. The step's change of a parameter is
+    ``-size * step``; how far an error of a share `e` of every single
+    contribution to a gradient (adam: of the largest sum of contributions in
+    the parameter's row) could move it is ``e * size * step_abs``, to first
+    order."""
+    params: Any
+    state: Any
+    size: Any        # sgd: lr; adagrad: lr * rsqrt(acc + eps); adam:
+    #                  lr / ((1 - b1**t) * (sqrt(nu / (1 - b2**t)) + eps))
+    step: Any        # what `size` multiplies: the gradient; adam: `mu`
+    step_abs: Any    # None without `grads_abs`
+
+
+def _adam(optimizer, lr, params, grads, state, grads_abs):
+    b1, b2, eps = optimizer["b1"], optimizer["b2"], optimizer["eps"]
+    tmap = jax.tree.map
+    count = state["count"] + 1
+    c1 = 1.0 - b1 ** count.astype(jnp.float32)
+    c2 = 1.0 - b2 ** count.astype(jnp.float32)
+    mu = tmap(lambda m, g: b1 * m + (1 - b1) * g, state["mu"], grads)
+    nu = tmap(lambda v, g: b2 * v + (1 - b2) * (g * g), state["nu"], grads)
+    root = tmap(lambda v: jnp.sqrt(v / c2), nu)
+    size = tmap(lambda r: lr / (c1 * (r + eps)), root)
+    new = tmap(lambda p, s, m: p - s * m, params, size, mu)
+    state = dict(state, count=count, mu=mu, nu=nu)
+    if grads_abs is None:
+        return Applied(new, state, size, mu, None)
+    # the same two moments of what the contributions could have summed to
+    # had none cancelled: `mu_abs` of the sums of their absolute values,
+    # `nu_x` of |gradient| times that sum (the derivative of g**2). An error
+    # of a share e of every contribution moves `mu` by at most e * mu_abs
+    # and `nu` by at most 2 e * nu_x, so the root by e * nu_x / (c2 * root)
+    # and `size` by that over (root + eps) of itself. The sum is the largest
+    # of the row: the matrix product that made a contribution rounds every
+    # element of it by a share of the row's scale, not of the element's, and
+    # adam's step, unlike the other two, carries that to an element whose
+    # own gradient is next to nothing at lr's size
+    grads_abs = tmap(lambda a: jnp.max(a, axis=-1, keepdims=True), grads_abs)
+    mu_abs = tmap(lambda m, a: b1 * m + (1 - b1) * a, state["mu_abs"],
+                  grads_abs)
+    nu_x = tmap(lambda x, g, a: b2 * x + (1 - b2) * (jnp.abs(g) * a),
+                state["nu_x"], grads, grads_abs)
+    state.update(mu_abs=mu_abs, nu_x=nu_x)
+    step_abs = tmap(
+        lambda ma, m, x, r: ma + jnp.abs(m) * jnp.where(
+            r > 0, x / (c2 * jnp.maximum(r, 1e-30) * (r + eps)), 0.0),
+        mu_abs, mu, nu_x, root)
+    return Applied(new, state, size, mu, step_abs)
+
+
+def apply_rule(optimizer, lr, params, grads, state, grads_abs=None, hit=None):
+    """One optimizer step over a pytree -> `Applied`.
+
+    `grads_abs`, shaped like `grads`: per gradient the sum of its
+    contributions' absolute values. `hit`: `params` is a list of tables,
+    and per table these are the rows some id hit: the sparse update never
+    visits another row, so its row and its state stay as they are (adam's
+    count is the table's, and advances)."""
+    kind, old = optimizer["kind"], state
+    if kind == "sgd":
         new = jax.tree.map(lambda p, g: p - lr * g, params, grads)
-        return new, None, jax.tree.map(lambda p: jnp.full_like(p, lr), params)
-    eps = optimizer["eps"]
-    state = jax.tree.map(lambda a, g: a + g * g, state, grads)
-    size = jax.tree.map(lambda a: lr * jax.lax.rsqrt(a + eps), state)
-    new = jax.tree.map(lambda p, g, s: p - s * g, params, grads, size)
-    return new, state, size
+        size = jax.tree.map(lambda p: jnp.full_like(p, lr), params)
+        applied = Applied(new, None, size, grads, grads_abs)
+    elif kind == "adagrad":
+        eps = optimizer["eps"]
+        state = jax.tree.map(lambda a, g: a + g * g, state, grads)
+        size = jax.tree.map(lambda a: lr * jax.lax.rsqrt(a + eps), state)
+        new = jax.tree.map(lambda p, g, s: p - s * g, params, grads, size)
+        applied = Applied(new, state, size, grads, grads_abs)
+    else:
+        applied = _adam(optimizer, lr, params, grads, state, grads_abs)
+    if hit is None:
+        return applied
+
+    def rows(new, old):       # lists of tables, or of arrays shaped like them
+        return [jnp.where(h[:, None], n, o) for n, o, h in zip(new, old, hit)]
+
+    new_state = applied.state
+    if kind == "adagrad":
+        new_state = rows(new_state, old)
+    elif kind == "adam":
+        new_state = {k: v if k == "count" else rows(v, old[k])
+                     for k, v in new_state.items()}
+        # a moment of earlier steps moves no row that no id hit in this one
+        nothing = [jnp.zeros_like(p) for p in params]
+        applied = applied._replace(
+            step=rows(applied.step, nothing),
+            step_abs=rows(applied.step_abs, nothing))
+    return applied._replace(params=rows(applied.params, params),
+                            state=new_state)
 
 
-def train_steps(logits_fn, optimizer, tables, table_map, dense, batches,
+def train_steps(model_loss, optimizer, tables, table_map, dense, batches,
                 sparse_device=None):
-    """Run one step per batch from the given initial host arrays, under the
-    matmul precision the caller has set.
+    """Run one step per batch ``(inputs, cats, labels)`` from the given
+    initial host arrays, under the matmul precision the caller has set.
+    `model_loss` is the ``loss(dense, embs, inputs, labels)`` of the
+    configuration's module under ``benchmark/references/``.
 
     Returns (embedding outputs of the first batch; per step the loss and the
     loss of the same state under ``"highest"``; per table two arrays shaped
@@ -148,9 +235,9 @@ def train_steps(logits_fn, optimizer, tables, table_map, dense, batches,
         return embed(tables, table_map, cats)
 
     @jax.jit
-    def dense_step(embs, dense, d_state, lr, numerical, labels):
+    def dense_step(embs, dense, d_state, lr, inputs, labels):
         def loss_fn(embs, dense):
-            return bce_with_logits(logits_fn(dense, embs, numerical), labels)
+            return model_loss(dense, embs, inputs, labels)
 
         loss, (g_embs, g_dense) = jax.value_and_grad(loss_fn, argnums=(0, 1))(
             embs, dense)
@@ -162,7 +249,7 @@ def train_steps(logits_fn, optimizer, tables, table_map, dense, batches,
         share = jnp.stack([sum(off[i] for i in inputs)
                            / jnp.maximum(sum(size[i] for i in inputs), 1e-30)
                            for inputs in inputs_of])
-        dense, d_state, _ = apply_rule(optimizer, lr, dense, g_dense, d_state)
+        dense, d_state = apply_rule(optimizer, lr, dense, g_dense, d_state)[:2]
         return loss, loss_high, share, g_embs, dense, d_state
 
     @jax.jit
@@ -182,34 +269,30 @@ def train_steps(logits_fn, optimizer, tables, table_map, dense, batches,
         g_tables = [s[:, :w] for s, w in zip(sums, widths)]
         g_abs = [s[:, w:2 * w] for s, w in zip(sums, widths)]
         hit = [s[:, -1] > 0 for s in sums]
-        new_tables, new_state, size = apply_rule(optimizer, lr, tables,
-                                                 g_tables, t_state)
         # the sparse update never visits a row that no id hit
-        keep = lambda new, old, h: jnp.where(h[:, None], new, old)  # noqa: E731
-        new_tables = [keep(n, o, h) for n, o, h in zip(new_tables, tables, hit)]
-        if new_state is not None:
-            new_state = [keep(n, o, h)
-                         for n, o, h in zip(new_state, t_state, hit)]
-        change = [c - s * g for c, s, g in zip(change, size, g_tables)]
-        moved = [m + s * a for m, s, a in zip(moved, size, g_abs)]
+        new_tables, new_state, size, step, step_abs = apply_rule(
+            optimizer, lr, tables, g_tables, t_state, g_abs, hit)
+        change = [c - s * g for c, s, g in zip(change, size, step)]
+        moved = [m + s * a for m, s, a in zip(moved, size, step_abs)]
         return new_tables, new_state, change, moved
 
     # state starts as host arrays and is placed, never computed: an eager
     # `zeros_like` per table is a program of its own to compile
     zeros = [np.zeros(np.shape(t), np.float32) for t in tables]
     t_state, change, moved = jax.device_put(
-        (init_state(optimizer, tables), zeros, zeros), sparse_device)
+        (init_state(optimizer, tables, sums_abs=True), zeros, zeros),
+        sparse_device)
     d_state = jax.device_put(init_state(optimizer, dense), dense_device)
     tables = jax.device_put(list(tables), sparse_device)
     dense = jax.device_put(dense, dense_device)
     losses, losses_high, shares, first_embs = [], [], [], None
-    for i, (numerical, cats, labels) in enumerate(batches):
+    for i, (inputs, cats, labels) in enumerate(batches):
         lr = np.float32(learning_rate(optimizer, i))
         cats = jax.device_put(list(cats), sparse_device)
         embs = lookup(tables, cats)
         loss, loss_high, share, g_embs, dense, d_state = dense_step(
             jax.device_put(embs, dense_device), dense, d_state, lr,
-            jax.device_put(numerical, dense_device),
+            jax.device_put(inputs, dense_device),
             jax.device_put(labels, dense_device))
         tables, t_state, change, moved = sparse_step(
             tables, t_state, change, moved, lr, cats,

@@ -76,12 +76,12 @@ def stage(built, batch):
     `fit` stages it, and plain arrays on one chip."""
     import jax.numpy as jnp
 
-    numerical, cats, labels = batch
+    inputs, cats, labels = batch
     cats = built.shape_ids(cats)
     if built.mesh is not None:
         from distributed_embeddings_tpu.parallel.staging import stage_dp_batch
-        return stage_dp_batch(built.mesh, (numerical, cats, labels))
-    return (jnp.asarray(numerical), [jnp.asarray(c) for c in cats],
+        return stage_dp_batch(built.mesh, (inputs, cats, labels))
+    return (jnp.asarray(inputs), [jnp.asarray(c) for c in cats],
             jnp.asarray(labels))
 
 
@@ -224,7 +224,8 @@ def main(argv=None) -> int:
 
         # ---- the first steps, held to the plain reference; they are the
         # warm-up too: the second runs on the first's donated outputs
-        first = [i % len(batches) for i in range(check.CHECK_STEPS)]
+        first = [i % len(batches)
+                 for i in range(check.check_steps(built.optimizer))]
         chk = check.Check(built, params, [host_batches[i] for i in first],
                           batches[0][1], config["matmul_precision"],
                           phases.done)
@@ -232,8 +233,8 @@ def main(argv=None) -> int:
         opt_state = init_fn(params)
         sys_losses = []
         for i in first:
-            numerical, cats, labels = batches[i]
-            params, opt_state, loss = step_fn(params, opt_state, numerical,
+            inputs, cats, labels = batches[i]
+            params, opt_state, loss = step_fn(params, opt_state, inputs,
                                               cats, labels)
             sys_losses.append(float(loss))
         phases.done("compile+warmup")
@@ -241,11 +242,13 @@ def main(argv=None) -> int:
         try:
             summary = chk.finish(params, sys_losses)
         except check.CheckFailed as e:
-            check_error, summary = str(e), {}
+            check_error, summary = str(e), e.summary
+        reference_peak = chk.reference_peak_bytes
         del chk
         phases.done("check")
         log("REFERENCE_CHECK " + json.dumps(
-            {"ok": check_error is None, "error": check_error, **summary}))
+            {"ok": check_error is None, "error": check_error, **summary,
+             "reference_peak_bytes": reference_peak}))
 
         # ---- the window
         state = (params, opt_state)

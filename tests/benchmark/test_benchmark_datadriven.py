@@ -1,12 +1,16 @@
 """A later PR adds a cell and a metric as files and entries, and edits
 nothing that is there: a scratch configuration, traffic mix, reader and
-layer metric dropped into a copy of the benchmark are found by name."""
+layer metric dropped into a copy of the benchmark are found by name. So are
+a model that is no click model (a next-token model under adam), its plain
+reference, its loss and its generator."""
 
 import json
 import os
 import shutil
 import subprocess
 import sys
+
+import pytest
 
 from benchmark.harness import spec
 
@@ -30,6 +34,117 @@ READER = '''"""Steps the traced window ran: a count, so a CPU run can show it.""
 
 def read(ctx, params):
     return ctx.steps * params["times"]
+'''
+
+# ---- a next-token model small enough for the CPU: one [T] one-hot input
+# into a DistributedEmbedding table, one dense layer, an untied head over
+# the table's rows, softmax cross-entropy against the next id, counted
+# where the next id belongs to the same document
+LM_CONFIG = {
+    "name": "scratch-lm", "source": "https://example.org/scratch-lm",
+    "builder": "scratch_lm", "sample_unit": "token",
+    "vocab_size": 512, "hidden_size": 16, "intermediate_size": 32,
+    "tokens_per_step": 256,
+    "optimizer": {"kind": "adam", "lr": 0.001, "b1": 0.9, "b2": 0.999,
+                  "eps": 1e-08},
+    "matmul_precision": "highest", "sync_every": 2, "trace_steps": 4,
+    "reduced": [], "rehearse": {}}
+LM_TRAFFIC = {"generator": "scratch_tokens", "num_batches": 3, "skew": 2.0,
+              "mean_document": 32}
+LM_BUILDER = '''"""Scratch: configuration file -> a next-token model on the repo's public
+training path, ``make_sparse_train_step(model, "adam")``."""
+
+import jax
+import jax.numpy as jnp
+
+from benchmark.harness.built import Built, mlp_train_flops
+
+
+class NextToken:
+    def __init__(self, vocab, width, hidden, mesh):
+        from distributed_embeddings_tpu.layers.dist_model_parallel import (
+            DistributedEmbedding)
+        from distributed_embeddings_tpu.layers.embedding import Embedding
+
+        self.sizes = vocab, width, hidden
+        self.embedding = DistributedEmbedding([Embedding(vocab, width)],
+                                              mesh=mesh)
+
+    def init(self, key):
+        vocab, width, hidden = self.sizes
+        ke, kd, kh = jax.random.split(key, 3)
+        return {"embedding": self.embedding.init(ke),
+                "dense": {"w": jax.random.normal(kd, (width, hidden))
+                          / width ** 0.5, "b": jnp.zeros(hidden)},
+                "head": jax.random.normal(kh, (hidden, vocab)) / hidden ** 0.5}
+
+    def loss_fn(self, params, same_document, cats, next_ids, taps=None,
+                return_residuals=False):
+        (x,), res = self.embedding(params["embedding"], list(cats), taps=taps,
+                                   return_residuals=True)
+        dense = params["dense"]
+        logits = jnp.maximum(x @ dense["w"] + dense["b"], 0.0) @ params["head"]
+        nll = (jax.nn.logsumexp(logits, axis=1)
+               - jnp.take_along_axis(logits, next_ids[:, None], axis=1)[:, 0])
+        loss = jnp.sum(nll * same_document) / jnp.sum(same_document)
+        return (loss, res) if return_residuals else loss
+
+
+def build(config, mesh, rehearse):
+    from distributed_embeddings_tpu.training import make_sparse_train_step
+
+    vocab, width, hidden = (config["vocab_size"], config["hidden_size"],
+                            config["intermediate_size"])
+    model = NextToken(vocab, width, hidden, mesh)
+    opt = config["optimizer"]
+    return Built(
+        model=model,
+        make_step=lambda: make_sparse_train_step(model, opt["kind"],
+                                                 lr=opt["lr"]),
+        tables=[(vocab, width)], table_map=[0], hotness=[1],
+        num_numerical=0, numerical_scale=0.0,
+        global_batch=config["tokens_per_step"], optimizer=opt,
+        reference="scratch_lm",
+        dense_params=lambda p: {"dense": p["dense"], "head": p["head"]},
+        mlp_flops_per_sample=mlp_train_flops([width, hidden, vocab]),
+        ids_1d=True, mesh=mesh)
+'''
+LM_REFERENCE = '''"""Scratch: the next-token model's forward and loss, plainly. ``inputs``
+is ``[T]`` f32, 1 where the next id belongs to the same document; ``labels``
+is ``[T]`` int32, the next ids."""
+
+import jax.numpy as jnp
+
+LABEL_OFFSET = 0
+
+
+def loss(dense, embs, inputs, labels):
+    (x,) = embs
+    hidden = jnp.maximum(x @ dense["dense"]["w"] + dense["dense"]["b"], 0.0)
+    logits = hidden @ dense["head"]
+    top = jnp.max(logits, axis=1)
+    log_sum = top + jnp.log(jnp.sum(jnp.exp(logits - top[:, None]), axis=1))
+    labels = jnp.roll(labels, LABEL_OFFSET)
+    nll = log_sum - logits[jnp.arange(labels.shape[0]), labels]
+    return jnp.sum(nll * inputs) / jnp.sum(inputs)
+'''
+LM_GENERATOR = '''"""Scratch: packed documents of skewed token ids. Returns per batch
+(same-document mask [T] f32, [ids [T, 1] int32], next ids [T] int32)."""
+
+import numpy as np
+
+
+def generate(traffic, inputs, batch, num_numerical, numerical_scale, seed):
+    ((rows, _),) = inputs
+    rng = np.random.RandomState(seed)
+    batches = []
+    for _ in range(int(traffic["num_batches"])):
+        ids = (rows * rng.rand(batch + 1) ** float(traffic["skew"])
+               ).astype(np.int32)
+        ends = rng.rand(batch) < 1.0 / float(traffic["mean_document"])
+        batches.append(((~ends).astype(np.float32), [ids[:-1, None]],
+                        ids[1:].copy()))
+    return batches
 '''
 
 
@@ -120,4 +235,46 @@ def test_the_four_chip_cell_comes_back_by_entries_alone(tmp_path):
     assert last["attempted"] == held["trace_steps"]
     # no chip, so nothing to read: the exchange's readers return nothing
     assert _line(lines, "REHEARSED_LAYER_METRICS") == {}
+    assert all(p.read_bytes() == data for p, data in before.items())
+
+
+@pytest.mark.parametrize("label_offset, outcome", [(0, None), (1, "(b)")])
+def test_a_next_token_model_under_adam_by_new_files_alone(
+        tmp_path, label_offset, outcome):
+    """Builder, plain reference (forward and softmax cross-entropy over the
+    vocabulary), generator (int32 [T] labels), configuration
+    (`sample_unit: token`, adam: so three held steps), traffic and two entries:
+    the check holds the system to the reference's loss and adam rule. With
+    the reference's labels one position off, the loss (b) fails."""
+    before = _copy_of_the_benchmark(tmp_path)
+    for path, text in (
+            ("builders/scratch_lm.py", LM_BUILDER),
+            ("references/scratch_lm.py", LM_REFERENCE.replace(
+                "LABEL_OFFSET = 0", f"LABEL_OFFSET = {label_offset}")),
+            ("generators/scratch_tokens.py", LM_GENERATOR),
+            ("configs/scratch-lm.json", json.dumps(LM_CONFIG)),
+            ("traffic/scratch-docs.json", json.dumps(LM_TRAFFIC))):
+        assert not (tmp_path / "benchmark" / path).exists()
+        (tmp_path / "benchmark" / path).write_text(text)
+    bench = spec.load_json("BENCHMARK.json")
+    bench["configs"].append({
+        "name": "scratch-lm", "source": LM_CONFIG["source"],
+        "file": "benchmark/configs/scratch-lm.json", "reduced": [],
+        "why": "scratch"})
+    bench["workloads"].append({
+        "name": "scratch-lm.docs", "config": "scratch-lm",
+        "traffic": "scratch-docs", "chips": 1, "why": "scratch"})
+    lines = _rehearse(tmp_path, bench, "scratch-lm.docs", 0)
+    check = _line(lines, "REFERENCE_CHECK")
+    if outcome is None:
+        assert check["ok"] is True, check
+        assert len(check["loss"]) == 3 and check["probed_tables"] == 1
+        assert check["touched_rows_moved"] > 0 and check["untouched_rows"] > 0
+        assert check["row_err_over_tolerance_max"] <= 1.0
+    else:
+        assert check["ok"] is False and check["error"].startswith(outcome)
+        assert (check["compared"]["loss0_off"][0]
+                > check["compared"]["loss0_off"][1])
+    last = json.loads(lines[-1])
+    assert last["failed"] == 0 and last["attempted"] > 0
     assert all(p.read_bytes() == data for p, data in before.items())

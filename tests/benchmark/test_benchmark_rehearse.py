@@ -37,6 +37,12 @@ def test_cell_rehearses_and_agrees_with_the_reference(workload, capsys):
     check = json.loads(next(ln for ln in lines if ln.startswith(
         "REFERENCE_CHECK ")).split(" ", 1)[1])
     assert check["ok"] is True, check
+    assert "reference_peak_bytes" in check     # None: the CPU keeps no count
+    # every number the check compared, beside its limit
+    assert {"emb_err_beyond_rtol_over_largest", "loss0_off", "loss1_off",
+            "row_err_over_tolerance", "untouched_rows_changed"} == set(
+                check["compared"])
+    assert all(number <= limit for number, limit in check["compared"].values())
     assert check["touched_rows"] > 0 and check["untouched_rows"] > 0
     assert check["touched_rows_moved"] > 0
     assert all(abs(s["system"] - s["reference"]) <= s["tolerance"]
@@ -50,3 +56,19 @@ def test_traced_rehearsal_reports_no_device_metric(capsys):
     steps = spec.load_cell("dlrm-mlperf.zipf").config["trace_steps"]
     assert last["attempted"] == steps and last["metrics"] == {}
     assert "breakdown" not in last and "busy_s" not in last["device"]
+
+
+def test_a_stalled_block_moves_the_rate_and_not_the_median():
+    """One slow loss fetch among a window's sync blocks lowers
+    `samples_per_s` and leaves `step_ms_p50`: the rate is taken over all the
+    window's time (PERF.md section 2: four such windows in 36 on one machine,
+    cause not found)."""
+    from types import SimpleNamespace
+
+    cell = spec.load_cell("tiny-v3.zipf")
+    win = SimpleNamespace(attempted=4, elapsed_s=9.7155,
+                          block_ms=[1238.0, 1239.0, 6000.0, 1238.5])
+    got = run.timed_metrics(cell, SimpleNamespace(global_batch=65536), win,
+                            35.0)
+    assert got["step_ms_p50"]["value"] == 1238.75
+    assert got["samples_per_s"]["value"] == 4 * 65536 / 9.7155

@@ -55,3 +55,12 @@ def test_least_step_says_which_bound():
 def test_an_unknown_device_kind_is_an_error_not_a_default():
     with pytest.raises(KeyError, match="TPU v9"):
         roofline.chip_peaks("TPU v9")
+
+
+def test_adam_nine_transfers_a_row():
+    """Forward read, the backward's read and write, and the lazy update's
+    read and write of the row and of each of its two moments."""
+    assert roofline.embedding_bytes_per_sample(
+        [2048], [1], "adam") == 9 * 2048 * 4
+    with pytest.raises(KeyError):
+        roofline.embedding_bytes_per_sample([8], [1], "lion")
