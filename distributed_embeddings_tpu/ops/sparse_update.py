@@ -23,8 +23,10 @@ gradient and the optimizer update O(touched rows):
     rows.
 
 Aggregation strategy is selectable (`strategy=`):
-  * 'sort'  — lax.sort + cumulative-sum differencing (scatter-free until the
-    final row update). O(N log^2 N) comparator passes but no [V, w] temp.
+  * 'sort'  — lax.sort, then a segmented doubling scan over the sorted
+    contributions and a shift network that moves each run's total to the
+    front (no row scatter until the final row update). O(N log N)
+    streamed work but no [V, w] temp.
   * 'dense' — scatter-add into a dense [V, w] zeros then a *masked* row
     update. Simple and fast when V*w is small; O(V, w) memory.
   Auto mode picks 'dense' below `DENSE_ELEMS_MAX` elements, 'sort' above.
@@ -96,15 +98,17 @@ def measured_default(knob: str, fallback: str) -> str:
 
 
 def _dedup_impl() -> str:
-    """'sort' (default): segment_sum aggregation — EXACT, and rep comes out
-    strictly increasing so downstream ops promise unique+sorted.
-    'cumsum': scatter-free aggregation (cumsum + cummax + one sorted
-    gather) — round-3 prims measured jax.ops.segment_sum at ~45 ns/row on
-    TPU (it is a sorted-dupes scatter underneath) while cumsum streams at
-    bandwidth; costs ~sqrt(N)*eps relative precision and downgrades the
-    rep promise to unique-only (totals stay at segment-END rows, so OOB
-    fillers interleave). Opt-in until tools/tpu_scatter_probe.py data
-    lands."""
+    """'sort' (default): each sorted run is summed by a segmented doubling
+    scan (pairwise f32 adds of the run's own rows, nothing differenced)
+    and its total shifted to the front, so rep comes out strictly
+    increasing and downstream ops promise unique+sorted. No row scatter:
+    the jax.ops.segment_sum it replaced (ISSUE 31) was a sorted-dupes
+    scatter underneath, 311 ms of Tiny V3's 1,236 ms step (ledger, PR 28).
+    'cumsum': a whole-stream cumsum differenced at the run ends — costs
+    ~sqrt(N)*eps relative precision and downgrades the rep promise to
+    unique-only (totals stay at segment-END rows, so OOB fillers
+    interleave). Opt-in; no cell runs it, and the scan took its reason to
+    exist (ROADMAP D2 removes it)."""
     return measured_default("DET_DEDUP_IMPL", "sort")
 
 
@@ -435,6 +439,14 @@ def concat_grads(grads) -> "SparseRowGrad":
         jnp.concatenate([g.contribs for g in grads], axis=0))
 
 
+def _shift(a: jax.Array, d: int) -> jax.Array:
+    """out[i] = a[i - d] along axis 0 (d of either sign), zeros moved in.
+    One pad with a negative edge: the chip's compiler fuses it into its
+    consumer, where a slice followed by a pad is copied out first."""
+    edges = [(d, -d, 0)] + [(0, 0, 0)] * (a.ndim - 1)
+    return lax.pad(a, jnp.zeros((), a.dtype), edges)
+
+
 @staged("dedup")
 def dedup_sum(ids: jax.Array, contribs: jax.Array, sentinel: int,
               presorted=None):
@@ -443,10 +455,19 @@ def dedup_sum(ids: jax.Array, contribs: jax.Array, sentinel: int,
     carry rep_ids >= sentinel (dropped by the subsequent scatter).
 
     Sort by id, derive exact integer segment indices from the sorted key
-    boundaries, and segment-sum the permuted rows. (A cumsum-difference
-    formulation would avoid the segment scatter but loses ~N*eps relative
-    precision at N in the millions — exactness wins here, matching the
-    reference's sort+unique+sum contract, .cu:645-661.)
+    boundaries, and sum each run of the permuted rows in place with a
+    segmented doubling scan: at level d = 1, 2, 4, ... slot i adds slot
+    i - d's partial sum if that slot lies in i's run, so after log2(N)
+    levels a run's last slot holds its total — a pairwise tree of rounded
+    f32 adds over the run's own rows, every duplicate summed (the
+    reference's sort+unique+sum contract, .cu:645-661). As many levels of
+    conditional shifts then move the totals, and their ids, to the front.
+    Nothing scatters or gathers an [N, w] array after the sort's
+    permutation: on the TPU a scattered narrow row costs ~100 ns and a
+    gathered one 23-47 (PERF.md sections 5 and 6), a level of either
+    network one streamed pass of under a millisecond. (A whole-stream cumsum
+    differenced at the run ends would also avoid the scatter, but loses
+    ~N*eps relative precision at N in the millions: `_dedup_sum_cumsum`.)
 
     `presorted` optionally carries this id stream's sort artifacts (an
     `embedding_ops.GroupSort` — sid/perm/seg_start under the SAME canonical
@@ -485,10 +506,35 @@ def dedup_sum(ids: jax.Array, contribs: jax.Array, sentinel: int,
     if _dedup_impl() == "cumsum":
         return _dedup_sum_cumsum(sid, rows, is_start, sentinel, iota)
     seg = jnp.cumsum(is_start.astype(jnp.int32)) - 1      # exact int prefix
-    sums = jax.ops.segment_sum(rows, seg, num_segments=n,
-                               indices_are_sorted=True)
-    rep = (jnp.int32(sentinel) + iota).at[seg].set(
-        sid, mode="drop", indices_are_sorted=True)
+    # slot i sits `off[i]` slots after the start of its run
+    off = iota - lax.cummax(jnp.where(is_start, iota, -1))
+    x = rows.astype(jnp.float32)
+    d = 1
+    while d < n:
+        # a run is contiguous: slot i - d is in i's run iff off[i] >= d
+        x = x + jnp.where((off >= d)[:, None], _shift(x, d), 0.0)
+        d *= 2
+    # x[i] = its run's sum up to i, so a run's last slot holds the total.
+    # Segment s's total sits at the slot that ends its run and belongs at
+    # slot s, `k` slots further down. k never decreases along the stream,
+    # so moving the totals down by k's binary digits, lowest first, never
+    # lands one on another that still has to stay (the compress network of
+    # Hacker's Delight 7-4): log2(n) streamed passes instead of a gather,
+    # which costs this column-major stream 38-47 ns a slot when its
+    # indices are in order (PERF.md section 6, PR 31)
+    is_end = jnp.concatenate([is_start[1:], jnp.ones((1,), bool)])
+    k = jnp.where(is_end, iota - seg, 0)
+    d = 1
+    while d < n:
+        go = (k & d) != 0
+        come = _shift(go, -d)
+        x = jnp.where(come[:, None], _shift(x, -d), x)
+        sid = jnp.where(come, _shift(sid, -d), sid)
+        k = jnp.where(come, _shift(k, -d), jnp.where(go, 0, k))
+        d *= 2
+    real = iota <= seg[-1]
+    sums = jnp.where(real[:, None], x, 0.0)
+    rep = jnp.where(real, sid, jnp.int32(sentinel) + iota)
     return rep, sums.astype(contribs.dtype)
 
 

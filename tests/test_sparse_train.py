@@ -297,8 +297,10 @@ def test_sparse_step_hlo_scatter_promises(monkeypatch):
     """The lowered train step must carry the scatter promises the round-3
     hardware data demands (XLA's duplicate-safe scatter measured at
     100-280 ns/row): both row-update scatters say unique_indices=true, and
-    the cumsum dedup impl removes the segment-sum + rep-build scatters
-    (2 fewer stablehlo.scatter ops per bucket)."""
+    they are the step's ONLY scatters under either dedup impl — the
+    default sums its sorted runs with a scan (ISSUE 31), as the cumsum
+    impl always did, so neither holds a segment-sum or a rep-build
+    scatter."""
     import re
     from distributed_embeddings_tpu.layers.dist_model_parallel import (
         DistributedEmbedding)
@@ -342,6 +344,6 @@ def test_sparse_step_hlo_scatter_promises(monkeypatch):
     txt_cs = lower_text()
     n_scatter_cs = len(re.findall(r'"stablehlo.scatter"', txt_cs))
     assert len(re.findall(r"unique_indices\s*=\s*true", txt_cs)) >= 2
-    assert n_scatter_cs <= n_scatter_sort - 2, (
-        f"cumsum impl should drop >=2 scatters: {n_scatter_sort} -> "
-        f"{n_scatter_cs}")
+    assert n_scatter_sort == n_scatter_cs == 2, (
+        f"only the accumulator's and the table's row updates scatter: "
+        f"sort {n_scatter_sort}, cumsum {n_scatter_cs}")

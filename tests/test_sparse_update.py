@@ -46,6 +46,103 @@ def test_dedup_sum_exact():
     assert (np.diff(rep.astype(np.int64)) > 0).all()
 
 
+def _runs(lengths):
+    """An id stream whose id r occurs lengths[r] times, shuffled."""
+    ids = np.repeat(np.arange(len(lengths), dtype=np.int32), lengths)
+    return np.random.default_rng(len(ids)).permutation(ids), len(lengths)
+
+
+def _oob_ids():
+    # negative and too-large ids share ONE dropped segment at the sentinel
+    ids = np.random.default_rng(5).integers(0, 40, size=300).astype(np.int32)
+    ids[::5] = -1 - ids[::5]
+    ids[1::7] = 40 + ids[1::7]
+    ids[2::11] = np.iinfo(np.int32).max
+    return ids, 40
+
+
+DEDUP_STREAMS = {
+    "runs_of_1": lambda: _runs([1] * 333),
+    "one_run": lambda: _runs([777]),
+    "n_1": lambda: _runs([1]),
+    "oob_onto_sentinel": _oob_ids,
+    **{f"runs_near_2^{k}": (lambda k=k: _runs([2 ** k - 1, 2 ** k,
+                                               2 ** k + 1]))
+       for k in range(1, 12)},
+}
+
+
+@pytest.mark.parametrize("width", [8, 16, 128])
+@pytest.mark.parametrize("stream", list(DEDUP_STREAMS))
+def test_dedup_sum_scan(stream, width):
+    """The segmented doubling scan's totals against a float64 np.add.at,
+    each within run_length * eps * sum|x|, and dedup_sum's contract: rep
+    strictly increasing, the real runs compacted at the front, every
+    filler slot zero and out of bounds."""
+    ids, sentinel = DEDUP_STREAMS[stream]()
+    n = len(ids)
+    contribs = np.random.default_rng(n + width).standard_normal(
+        (n, width)).astype(np.float32)
+    rep, sums = jax.jit(su.dedup_sum, static_argnames="sentinel")(
+        jnp.asarray(ids), jnp.asarray(contribs), sentinel=sentinel)
+    rep, sums = np.asarray(rep), np.asarray(sums)
+    assert sums.dtype == np.float32 and sums.shape == (n, width)
+
+    keys = np.where((ids < 0) | (ids > sentinel), sentinel, ids)
+    uniq, inverse, counts = np.unique(keys, return_inverse=True,
+                                      return_counts=True)
+    want = np.zeros((len(uniq), width), np.float64)
+    mass = np.zeros((len(uniq), width), np.float64)
+    np.add.at(want, inverse, contribs.astype(np.float64))
+    np.add.at(mass, inverse, np.abs(contribs).astype(np.float64))
+    s = len(uniq)
+    np.testing.assert_array_equal(rep[:s], uniq)
+    bound = counts[:, None] * np.finfo(np.float32).eps * mass
+    assert (np.abs(sums[:s] - want) <= bound).all()
+    # a run of one is its contribution, to the bit
+    single = counts == 1
+    np.testing.assert_array_equal(
+        sums[:s][single], contribs[np.argsort(keys, kind="stable")][
+            np.cumsum(counts)[single] - 1])
+    np.testing.assert_array_equal(rep[s:], sentinel + np.arange(s, n))
+    assert not sums[s:].any()
+    assert (np.diff(rep.astype(np.int64)) > 0).all()
+
+
+@pytest.mark.parametrize("width", [8, 16, 128])
+def test_dedup_sum_presorted_bit_identical(width):
+    """A GroupSort made earlier (the forward's) serves the scan as a fresh
+    sort does: same rep, same sums, to the bit."""
+    from distributed_embeddings_tpu.ops.embedding_ops import (
+        canonical_id_sort)
+    ids, sentinel = _oob_ids()
+    ids = np.concatenate([ids, _runs([129, 1, 64, 7])[0]])
+    contribs = np.random.default_rng(width).standard_normal(
+        (len(ids), width)).astype(np.float32)
+    fresh = su.dedup_sum(jnp.asarray(ids), jnp.asarray(contribs),
+                         sentinel=sentinel)
+    folded = su.dedup_sum(
+        jnp.asarray(ids), jnp.asarray(contribs), sentinel=sentinel,
+        presorted=canonical_id_sort(jnp.asarray(ids), sentinel))
+    for a, b in zip(fresh, folded):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_dedup_sum_scatters_nothing():
+    """The totals come from the scan and the shift network: the lowered
+    program holds no scatter at all (the parent's held two: the segment-sum
+    of the [n, w] contributions and the 1-D one that set rep) and one
+    gather, the contributions by the sort's permutation. Keeps a later
+    refactor from bringing the segment scatter-add back unseen."""
+    text = jax.jit(su.dedup_sum, static_argnames="sentinel").lower(
+        jax.ShapeDtypeStruct((4096,), jnp.int32),
+        jax.ShapeDtypeStruct((4096, 16), jnp.float32),
+        sentinel=1000).as_text()
+    assert "4096x16xf32" in text and "stablehlo.sort" in text
+    assert "scatter" not in text
+    assert text.count('"stablehlo.gather"(') == 1
+
+
 def test_dedup_sum_cumsum_impl(monkeypatch):
     """DET_DEDUP_IMPL=cumsum: scatter-free aggregation must match the exact
     sort impl to f32-cumsum tolerance, keep rep unique, and drop OOB."""
