@@ -6,9 +6,9 @@ monolithic train step, lookahead fused + prefetch, serve forward,
 vocab-slack plan — lowered ONCE each over an 8-virtual-device mesh
 (``program_matrix``: one lowering per program, shared by every pass —
 the <=60s CI budget lives or dies on that cache), plus the legacy
-per-arm audit entry points ``bench.py`` embeds in its records, plus
-``mutation_cases()``: for every pass, a program that deliberately
-violates its invariant and MUST produce exactly the expected finding.
+per-arm audit entry points, plus ``mutation_cases()``: for every pass,
+a program that deliberately violates its invariant and MUST produce
+exactly the expected finding.
 An auditor that cannot fail is not a gate.
 
 ``expected_collective_bytes`` is the reconciled byte model (ISSUE 10
@@ -65,9 +65,7 @@ def build_model(vocab: int, width: int, combiner: str, hot_rows: int = 0,
                 storage_dtype=None):
     """Minimal tapped model (the shape make_sparse_train_step expects)
     around a DistributedEmbedding — THE one copy of this harness, shared
-    by the audit program matrix, the legacy sort/byte/overlap arms, and
-    bench.py's --mode wire / --mode lookahead A/Bs, so the audit and the
-    bench always lower the same program.
+    by the audit program matrix and the legacy sort/byte/overlap arms.
 
     ``dense_head=True`` puts a real matmul between the embedding outputs
     and the loss (params gain a ``head`` kernel, built by
@@ -637,10 +635,9 @@ def mutation_cases() -> List[MutationCase]:
 
 # ------------------------------------------------------------ legacy arms
 # Per-arm audit entry points predating the pass matrix, kept because
-# bench.py embeds them in every hardware record (`hlo_sort_audit`,
-# `wire_hlo`) and their bounds are shape-parameterized in ways the fixed
-# matrix is not (30M-row vocabs, tiled lookup, hot shards). They run on
-# the same IR measurements as the passes.
+# their bounds are shape-parameterized in ways the fixed matrix is not
+# (30M-row vocabs, tiled lookup, hot shards). They run on the same IR
+# measurements as the passes.
 
 def audit_tapped_step(vocab: int = 30_000_000, width: int = 8,
                       batch: int = 8, hotness: int = 4,

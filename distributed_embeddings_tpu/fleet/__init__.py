@@ -11,8 +11,8 @@ typed load shedding driven by the batcher's queue instruments, replicas
 that join/leave at runtime with bounded key movement, and published
 versions that serve fleet-wide only after canaries report bit-exact
 parity against the publisher — with automatic rollback to the pinned
-version when one lands degraded. Driven end-to-end by
-``bench.py --mode fleet``; semantics in docs/serving.md "Fleet tier".
+version when one lands degraded. Semantics in docs/serving.md
+"Fleet tier".
 """
 
 from distributed_embeddings_tpu.fleet.admission import (AdmissionController,

@@ -67,12 +67,11 @@ class AdmissionController:
 
     def __init__(self, max_queue_depth: Optional[int] = None,
                  max_queue_rows: Optional[int] = None):
-        from distributed_embeddings_tpu.tune import resolve as _tune_resolve
         if max_queue_depth is None:
-            max_queue_depth = int(_tune_resolve.knob_value(
+            max_queue_depth = int(os.environ.get(
                 "DET_FLEET_MAX_QUEUE_DEPTH", "64"))
         if max_queue_rows is None:
-            raw = _tune_resolve.knob_value("DET_FLEET_MAX_QUEUE_ROWS", "")
+            raw = os.environ.get("DET_FLEET_MAX_QUEUE_ROWS", "")
             max_queue_rows = int(raw) if raw else None
         self.max_queue_depth = int(max_queue_depth)
         self.max_queue_rows = (None if max_queue_rows is None

@@ -462,8 +462,8 @@ class DistributedEmbedding:
         # here (compiled vs XLA, raising on a mismatch), and a request
         # this plan's tables cannot serve is refused before any step
         from distributed_embeddings_tpu.ops.sparse_update import (
-            measured_default, prevalidate_active_impl)
-        lookup_path = measured_default("DET_LOOKUP_PATH", "auto")
+            prevalidate_active_impl)
+        lookup_path = os.environ.get("DET_LOOKUP_PATH", "auto")
         if lookup_path in ("tiled", "fused"):
             prevalidate_active_impl(widths=self.plan_widths())
         if (lookup_path == "pallas" and use_custom_kernel
@@ -1021,8 +1021,7 @@ class DistributedEmbedding:
                             previous batch's touched rows all reappearing
                             in the prefetched batch, i.e. exactly
                             `touched_rows_per_step` (the dedup bound
-                            carries over; the measured intersection is
-                            what `bench.py --mode lookahead` reports)
+                            carries over)
           prefetch_patch_bytes_per_step the patch recompute's wire cost
                             model at that bound: patched rows x (id wire
                             + one activation slot at the bucket's float
@@ -1280,7 +1279,7 @@ class DistributedEmbedding:
         or the ISSUE 12 fused gather->combine) for this (bucket,
         hotness)? Mirrors its dispatch statically (trace-safe) — both
         paths consume the residual sort's inverse permutation."""
-        path = sparse_update_ops.measured_default("DET_LOOKUP_PATH", "auto")
+        path = os.environ.get("DET_LOOKUP_PATH", "auto")
         if path not in ("tiled", "fused") or not self.use_custom_kernel:
             return False
         # flatten path (no combiner at hotness > 1) has no sorted gather
@@ -1365,7 +1364,7 @@ class DistributedEmbedding:
         would otherwise compute itself fold onto the residual sort.
         """
         b_sz, f, k = ids.shape
-        path = sparse_update_ops.measured_default("DET_LOOKUP_PATH", "auto")
+        path = os.environ.get("DET_LOOKUP_PATH", "auto")
         if combiner is None and k == 1 and path in ("pallas", "tiled",
                                                     "fused"):
             combiner = "sum"     # identical result at hotness 1
@@ -1420,12 +1419,6 @@ class DistributedEmbedding:
                 table, ids.reshape(b_sz * f, k), w.reshape(b_sz * f, k),
                 combiner)
             return self._cast(out.reshape(b_sz, f, out.shape[-1]))
-        # (The round-3 DET_SORTED_GATHER sort+sorted-gather+unpermute
-        # variant was removed in round 5: DET_LOOKUP_PATH=tiled IS that
-        # composite done properly — sort + block-streamed tiled gather +
-        # scatter-free unpermute — and the knob never earned its own
-        # hardware number. The 'sort+sortedgather+unperm' prim composite in
-        # tools/tpu_scatter_probe.py still measures the hypothesis.)
         emb = self._cast(jnp.take(table, ids, axis=0))      # [B, f, k, w]
         return _combine(emb, weights, combiner)
 

@@ -8,9 +8,9 @@ the lookahead engine its compile counts — and nothing could read,
 export, or gate any of it in one place. `MetricRegistry` is that place:
 named counters, gauges, and histograms with labeled families
 (``table=``, ``group=``, ``stage=``), a point-in-time ``snapshot()``
-dict every driver can embed (``bench.py`` records, ``fit`` history, the
-tier-1 smoke), JSONL append export for soak runs, and a
-Prometheus-style text dump for scraping.
+dict every driver can embed (``fit`` history, the tier-1 smoke), JSONL
+append export for soak runs, and a Prometheus-style text dump for
+scraping.
 
 `LatencyHistogram` — the geometric-bucket histogram `serving` and the
 ingest pipeline always used — moved here and IS the registry's
@@ -25,10 +25,8 @@ no registry creates a private one (per-instance accounting, the
 historical behavior) — and `default_registry()` is the process-local
 instance drivers use to unify a run (`training.fit` threads ONE
 registry through the pipeline, engine, store, and vocab manager it
-drives; `bench.py` stamps ``metrics_snapshot`` from the default
-registry into every record). Instruments are plain Python objects
-updated from host-side driver code only — nothing here may run under a
-jit trace.
+drives). Instruments are plain Python objects updated from host-side
+driver code only — nothing here may run under a jit trace.
 """
 
 import json
@@ -335,11 +333,10 @@ _default: Optional[MetricRegistry] = None
 
 
 def default_registry() -> MetricRegistry:
-    """The process-local registry drivers share (`bench.py` snapshot
-    stamping, the tier-1 obs smoke). Long-lived processes composing
-    several independent runs should create per-run `MetricRegistry`
-    instances instead — counts here accumulate for the process
-    lifetime (that is the point)."""
+    """The process-local registry drivers share (the tier-1 obs smoke).
+    Long-lived processes composing several independent runs should create
+    per-run `MetricRegistry` instances instead — counts here accumulate
+    for the process lifetime (that is the point)."""
     global _default
     with _default_lock:
         if _default is None:

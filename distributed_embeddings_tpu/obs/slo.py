@@ -28,9 +28,9 @@ mutually exclusively, so ``lookahead/compiles`` is absent from half
 the runs by design, not by failure).
 
 Violations come back in `analysis.passes.Finding` shape — the same
-typed finding `bench.py` and CI already gate audit results through —
-with stable content-derived ids (``slo:<name>``), so an SLO breach and
-a static-invariant breach flow through one reporting path.
+typed finding CI gates audit results through — with stable
+content-derived ids (``slo:<name>``), so an SLO breach and a
+static-invariant breach flow through one reporting path.
 """
 
 import json

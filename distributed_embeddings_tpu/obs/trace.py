@@ -45,8 +45,8 @@ exported JSON always validates regardless of where the ring was cut.
 `dump_postmortem` is the incident artifact: ring + registry snapshot +
 caller context in one timestamped JSON file. `InferenceEngine.
 poll_updates` calls it on every degraded-mode ENTRY when
-``DET_OBS_POSTMORTEM_DIR`` is set, and `bench.py` dumps on SLO breach
-— see docs/observability.md "Flight recorder & postmortems".
+``DET_OBS_POSTMORTEM_DIR`` is set — see docs/observability.md "Flight
+recorder & postmortems".
 """
 
 import collections

@@ -41,8 +41,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Vocab size at or below which the MXU one-hot-matmul kernel is used. The
 # default has no on-chip measurement behind it. DET_ONEHOT_MAX_VOCAB
-# overrides per trace (read per call like DET_SPARSE_DENSE_MAX, so
-# in-process A/B works); 0 disables the MXU kernel entirely.
+# overrides per trace (read per call, so in-process A/B works); 0 disables the MXU kernel entirely.
 ONEHOT_MAX_VOCAB = 8192
 
 

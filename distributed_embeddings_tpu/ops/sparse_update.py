@@ -32,6 +32,7 @@ Aggregation strategy is selectable (`strategy=`):
   Auto mode picks 'dense' below `DENSE_ELEMS_MAX` elements, 'sort' above.
 """
 
+import os
 from functools import partial
 from typing import Any, NamedTuple, Optional
 
@@ -43,11 +44,11 @@ from distributed_embeddings_tpu.obs.stages import staged
 
 # auto-strategy threshold: buckets up to this many elements aggregate through
 # a dense temp (64 MiB at f32 width 16); larger buckets use the sort path.
-# Tunable per hardware via DET_SPARSE_DENSE_MAX.
-import os
-
-DENSE_ELEMS_MAX = int(os.environ.get("DET_SPARSE_DENSE_MAX",
-                                     16 * 1024 * 1024))
+# One value has ever run, with one traced reading either side of it (Tiny V3,
+# ROADMAP S1's first question): 14 ns a contribution into the width-8
+# bucket's dense target below it, 108 ns a contribution through the width-16
+# bucket's sorted path above it.
+DENSE_ELEMS_MAX = 16 * 1024 * 1024
 
 
 def fp_round(x: jax.Array, zero: jax.Array) -> jax.Array:
@@ -82,41 +83,9 @@ def round_pin(traced_int: jax.Array) -> jax.Array:
             * jnp.float32(0.0))
 
 
-def measured_default(knob: str, fallback: str) -> str:
-    """Resolved default for a DET_* dispatch knob.
-
-    Thin delegate to ``tune.resolve.knob_value`` (ISSUE 18), which owns
-    the resolution order: env var > the workload's config-of-record
-    ``tools/tuned/<workload>.json`` (explicit opt-in via
-    DET_TUNED_WORKLOAD / DET_TUNED_PATH, written by ``bench.py --mode
-    tune``) > the file DET_MEASURED_DEFAULTS_PATH names, if any (TPU
-    backend only, or DET_MEASURED_DEFAULTS_CONSULT=1) > ``fallback``.
-    Every tuned/measured adoption leaves a ``tune/adopt``
-    flight-recorder event."""
-    from ..tune import resolve as _tune_resolve
-    return _tune_resolve.knob_value(knob, fallback)
-
-
-def _dedup_impl() -> str:
-    """'sort' (default): each sorted run is summed by a segmented doubling
-    scan (pairwise f32 adds of the run's own rows, nothing differenced)
-    and its total shifted to the front, so rep comes out strictly
-    increasing and downstream ops promise unique+sorted. No row scatter:
-    the jax.ops.segment_sum it replaced (ISSUE 31) was a sorted-dupes
-    scatter underneath, 311 ms of Tiny V3's 1,236 ms step (ledger, PR 28).
-    'cumsum': a whole-stream cumsum differenced at the run ends — costs
-    ~sqrt(N)*eps relative precision and downgrades the rep promise to
-    unique-only (totals stay at segment-END rows, so OOB fillers
-    interleave). Opt-in; no cell runs it, and the scan took its reason to
-    exist (ROADMAP D2 removes it)."""
-    return measured_default("DET_DEDUP_IMPL", "sort")
-
-
-def dedup_flags() -> dict:
-    """Scatter/gather promise kwargs legal for dedup_sum's rep output under
-    the active implementation (see _dedup_impl)."""
-    return {"unique_indices": True,
-            "indices_are_sorted": _dedup_impl() == "sort"}
+# scatter/gather promise kwargs legal for dedup_sum's rep output, which is
+# strictly increasing (sentinel tail included)
+DEDUP_FLAGS = {"unique_indices": True, "indices_are_sorted": True}
 
 
 # ------------- kernel dispatch + the compiled check
@@ -181,10 +150,10 @@ def _validate_tiled(width: int) -> bool:
     t2, a2 = ptl.tiled_adagrad(table, acc, ids, delta, 0.05,
                                interpret=False)
     rep, sums = dedup_sum(ids, delta, sentinel=v)
-    a_want = acc.at[rep].add(sums * sums, mode="drop", **dedup_flags())
+    a_want = acc.at[rep].add(sums * sums, mode="drop", **DEDUP_FLAGS)
     d_want = -0.05 * sums * lax.rsqrt(
         jnp.take(a_want, jnp.minimum(rep, v - 1), axis=0) + 1e-10)
-    t_want = table.at[rep].add(d_want, mode="drop", **dedup_flags())
+    t_want = table.at[rep].add(d_want, mode="drop", **DEDUP_FLAGS)
     ok = ok and _close(a2, a_want, 1e-3) and _close(t2, t_want, 1e-3)
     g3 = ptl.tiled_gather(table, ids, interpret=False)
     ok = ok and _close(g3, jnp.take(table, ids, axis=0), 1e-4)
@@ -237,17 +206,16 @@ def _validate_pallas_fused(width: int) -> bool:
     delta = jnp.asarray(rng.randn(n, w).astype(np.float32))
     table = jnp.asarray(rng.randn(v, w).astype(np.float32))
     rep, sums = dedup_sum(ids, delta, sentinel=v)
-    fl = dedup_flags()
     got = ptl.tiled_sgd_rows(table, rep, sums, 0.05, interpret=False)
-    want = table.at[rep].add(-0.05 * sums, mode="drop", **fl)
+    want = table.at[rep].add(-0.05 * sums, mode="drop", **DEDUP_FLAGS)
     ok = _close(got, want, 1e-4)
     acc = jnp.full((v, w), 0.1, jnp.float32)
     t2, a2 = ptl.tiled_adagrad_rows(table, acc, rep, sums, 0.05,
                                     interpret=False)
-    a_want = acc.at[rep].add(sums * sums, mode="drop", **fl)
+    a_want = acc.at[rep].add(sums * sums, mode="drop", **DEDUP_FLAGS)
     d_want = -0.05 * sums * lax.rsqrt(
         jnp.take(a_want, jnp.minimum(rep, v - 1), axis=0) + 1e-10)
-    t_want = table.at[rep].add(d_want, mode="drop", **fl)
+    t_want = table.at[rep].add(d_want, mode="drop", **DEDUP_FLAGS)
     ok = ok and _close(a2, a_want, 1e-4) and _close(t2, t_want, 1e-4)
     mu = jnp.zeros((v, w), jnp.float32)
     nu = jnp.zeros((v, w), jnp.float32)
@@ -294,7 +262,7 @@ def prevalidate_pallas_fused(width: int = 16) -> bool:
 def _scatter_env(value: str) -> bool:
     """DET_SCATTER_IMPL == value, honoured on the TPU backend only — the
     env route never flips CPU test numerics."""
-    return (measured_default("DET_SCATTER_IMPL", "xla") == value
+    return (os.environ.get("DET_SCATTER_IMPL", "xla") == value
             and jax.default_backend() == "tpu")
 
 
@@ -309,16 +277,8 @@ def _scatter_route(strategy: str) -> str:
     """Which update family serves a call — 'pallas' | 'tiled' | 'xla' —
     from the request alone. Shared by dispatch, the obs label
     (`active_scatter_impl`) and the fold planner (`update_consumes_sort`)
-    so the three cannot drift. A request that cannot be served raises:
-    the cumsum dedup's rep stream is unique but UNSORTED, which the
-    fused tile walk's chunk layout cannot consume."""
+    so the three cannot drift."""
     if _pallas_requested(strategy):
-        if _dedup_impl() == "cumsum":
-            raise ValueError(
-                "the fused pallas update (DET_SCATTER_IMPL=pallas / "
-                "strategy='pallas') needs the sorted rep stream of "
-                "DET_DEDUP_IMPL=sort; it cannot run with "
-                "DET_DEDUP_IMPL=cumsum — unset one of the two")
         return "pallas"
     if strategy == "tiled" or (strategy == "auto" and _scatter_env("tiled")):
         return "tiled"
@@ -337,7 +297,7 @@ def gate_verdicts() -> dict:
 
 def active_scatter_impl(strategy: str = "auto") -> str:
     """Which update family a step traced now dispatches to — the obs
-    label for the per-strategy update-phase span and bench arm records."""
+    label for the per-strategy update-phase span."""
     return _scatter_route(strategy)
 
 
@@ -351,13 +311,13 @@ def prevalidate_active_impl(strategy: Optional[str] = None,
 
     `widths`: the table lane widths the caller will dispatch at (the
     layer/step factories pass their plan's bucket+row widths); None
-    checks the two bench lane classes (16, 128). The per-row DMA family
+    checks the two cells' lane classes (16, 128). The per-row DMA family
     can address only some widths and raises here, before any step runs,
     for one it cannot (`pallas_scatter.check_row_dma`)."""
     if jax.default_backend() != "tpu":
         return
-    impl = measured_default("DET_SCATTER_IMPL", "xla")
-    lookup = measured_default("DET_LOOKUP_PATH", "auto")
+    impl = os.environ.get("DET_SCATTER_IMPL", "xla")
+    lookup = os.environ.get("DET_LOOKUP_PATH", "auto")
     widths = tuple(widths or (16, 128))
     checks = []
     if impl == "tiled" or strategy == "tiled" or lookup == "tiled":
@@ -396,7 +356,7 @@ def _row_scatter_add(table: jax.Array, rep: jax.Array,
         return ps.scatter_add_sorted_unique(
             table, rep, delta.astype(table.dtype))
     return table.at[rep].add(delta.astype(table.dtype), mode="drop",
-                             **dedup_flags())
+                             **DEDUP_FLAGS)
 
 
 def take_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
@@ -465,9 +425,11 @@ def dedup_sum(ids: jax.Array, contribs: jax.Array, sentinel: int,
     Nothing scatters or gathers an [N, w] array after the sort's
     permutation: on the TPU a scattered narrow row costs ~100 ns and a
     gathered one 23-47 (PERF.md sections 5 and 6), a level of either
-    network one streamed pass of under a millisecond. (A whole-stream cumsum
-    differenced at the run ends would also avoid the scatter, but loses
-    ~N*eps relative precision at N in the millions: `_dedup_sum_cumsum`.)
+    network one streamed pass of under a millisecond. The
+    jax.ops.segment_sum this replaced (ISSUE 31) was a sorted-dupes scatter
+    underneath, 311 ms of Tiny V3's 1,236 ms step (ledger, PR 28). (A
+    whole-stream cumsum differenced at the run ends would also avoid the
+    scatter, but loses ~N*eps relative precision at N in the millions.)
 
     `presorted` optionally carries this id stream's sort artifacts (an
     `embedding_ops.GroupSort` — sid/perm/seg_start under the SAME canonical
@@ -503,8 +465,6 @@ def dedup_sum(ids: jax.Array, contribs: jax.Array, sentinel: int,
         is_start = jnp.concatenate(
             [jnp.ones((1,), bool), sid[1:] != sid[:-1]])
     rows = jnp.take(contribs, perm, axis=0)
-    if _dedup_impl() == "cumsum":
-        return _dedup_sum_cumsum(sid, rows, is_start, sentinel, iota)
     seg = jnp.cumsum(is_start.astype(jnp.int32)) - 1      # exact int prefix
     # slot i sits `off[i]` slots after the start of its run
     off = iota - lax.cummax(jnp.where(is_start, iota, -1))
@@ -536,26 +496,6 @@ def dedup_sum(ids: jax.Array, contribs: jax.Array, sentinel: int,
     sums = jnp.where(real[:, None], x, 0.0)
     rep = jnp.where(real, sid, jnp.int32(sentinel) + iota)
     return rep, sums.astype(contribs.dtype)
-
-
-def _dedup_sum_cumsum(sid, rows, is_start, sentinel, iota):
-    """Scatter-free aggregation (see _dedup_impl): per-segment totals land
-    at each segment's END row; every other slot carries a unique OOB
-    filler. rep is unique but NOT sorted (fillers interleave) — consumers
-    must use dedup_flags() rather than hardcoding promises."""
-    n = sid.shape[0]
-    is_end = jnp.concatenate([sid[1:] != sid[:-1], jnp.ones((1,), bool)])
-    p = jnp.cumsum(rows.astype(jnp.float32), axis=0)
-    begin = lax.cummax(jnp.where(is_start, iota, -1))
-    p_prev = jnp.where(
-        (begin > 0)[:, None],
-        jnp.take(p, jnp.maximum(begin - 1, 0), axis=0,
-                 indices_are_sorted=True), 0.0)
-    sums = jnp.where(is_end[:, None], p - p_prev, 0.0)
-    # fillers start at sentinel+1: sid can itself equal sentinel (collapsed
-    # OOB segment), and a filler must never collide with it
-    rep = jnp.where(is_end, sid, jnp.int32(sentinel) + 1 + iota)
-    return rep, sums.astype(rows.dtype)
 
 
 @staged("dedup")
@@ -620,10 +560,7 @@ def apply_dense_rows(kind: str, table, state, g, touched, lr, **hp):
 def _pick(strategy: str, rows: int, width: int) -> str:
     if strategy != "auto":
         return strategy
-    # env read per call (not at import): lets the bench A/B strategies by
-    # re-tracing with a different DET_SPARSE_DENSE_MAX
-    mx = int(os.environ.get("DET_SPARSE_DENSE_MAX", DENSE_ELEMS_MAX))
-    return "dense" if rows * width <= mx else "sort"
+    return "dense" if rows * width <= DENSE_ELEMS_MAX else "sort"
 
 
 def _usable_presorted(presorted, grad: SparseRowGrad, rows: int):
@@ -650,11 +587,9 @@ def sparse_sgd(table: jax.Array, grad: SparseRowGrad, lr,
     consumes the folded forward sort, and it is the seam that makes the
     fused pallas step bit-exact against the XLA sort path (ISSUE 12 —
     duplicate-heavy streams see last-ulp differences vs the sequential
-    scatter, within every documented tolerance). (The round-3
-    DET_SGD_DEDUP knob this resembles was removed in round 5 without a
-    hardware number; the tiled kernel family and this seam subsume its
-    hypothesis.) `presorted` (GroupSort) feeds the tiled/pallas sorted
-    stream and the sort-strategy dedup; 'auto''s scatter ignores it."""
+    scatter, within every documented tolerance). `presorted` (GroupSort)
+    feeds the tiled/pallas sorted stream and the sort-strategy dedup;
+    'auto''s scatter ignores it."""
     rows = table.shape[0]
     ps = _usable_presorted(presorted, grad, rows)
     route = _scatter_route(strategy)
@@ -670,7 +605,7 @@ def sparse_sgd(table: jax.Array, grad: SparseRowGrad, lr,
             from distributed_embeddings_tpu.ops import pallas_tiled as ptl
             return ptl.tiled_sgd_rows(table, rep, sums, lr)
         return table.at[rep].add((-lr * sums).astype(table.dtype),
-                                 mode="drop", **dedup_flags())
+                                 mode="drop", **DEDUP_FLAGS)
     # negative ids -> dropped OOB row, not NumPy wraparound (see dedup_sum)
     safe_ids = jnp.where(grad.ids < 0, table.shape[0], grad.ids)
     return table.at[safe_ids].add(
@@ -732,18 +667,15 @@ def sparse_adagrad(table: jax.Array, accum: jax.Array, grad: SparseRowGrad,
         from distributed_embeddings_tpu.ops import pallas_scatter as ps
         return ps.adagrad_rows_sorted_unique(table, accum, rep, sums,
                                              lr_static, eps)
-    # rep is strictly increasing under the default impl (dedup_sum
-    # contract) => both scatter promises hold; without them XLA's
-    # duplicate-safe lowering costs ~100-280 ns/row on TPU (round-3 prims
-    # measurement). dedup_flags() downgrades to unique-only under
-    # DET_DEDUP_IMPL=cumsum
-    fl = dedup_flags()
+    # rep is strictly increasing (dedup_sum contract) => both scatter
+    # promises hold; without them XLA's duplicate-safe lowering costs
+    # ~100-280 ns/row on TPU (round-3 prims measurement)
     acc_new = _row_scatter_add(accum, rep, sums * sums)
     # gather with clamped index is safe: sentinel rows multiply a zero
     # update. Clamping collapses the dropped tail onto rows-1, so only the
-    # sorted promise survives (and only under the sort impl)
+    # sorted promise survives
     acc_rows = jnp.take(acc_new, jnp.minimum(rep, rows - 1), axis=0,
-                        indices_are_sorted=fl["indices_are_sorted"])
+                        indices_are_sorted=True)
     delta = -lr * sums * lax.rsqrt(acc_rows + eps)
     return _row_scatter_add(table, rep, delta), acc_new
 
@@ -789,10 +721,8 @@ def sparse_adam(table: jax.Array, mu: jax.Array, nu: jax.Array, count,
     c2 = 1.0 - b2 ** count.astype(jnp.float32)
     rep, sums = dedup_sum(grad.ids, grad.contribs, sentinel=rows,
                           presorted=ps)
-    # promises per the active dedup impl (see sparse_adagrad); clamped
-    # gathers keep at most the sorted promise
-    fl = dedup_flags()
-    srt = fl["indices_are_sorted"]
+    # scatter promises as in sparse_adagrad; clamped gathers keep the
+    # sorted promise only
     safe = jnp.minimum(rep, rows - 1)
     # fp_round pins each moment product's rounding (no context-dependent
     # FMA fusion) — the identical pins live in the fused pallas kernels,
@@ -802,15 +732,16 @@ def sparse_adam(table: jax.Array, mu: jax.Array, nu: jax.Array, count,
     # association to a simplifier.
     zero = round_pin(count)
     mu_rows = (fp_round(b1 * jnp.take(mu, safe, axis=0,
-                                      indices_are_sorted=srt), zero)
+                                      indices_are_sorted=True), zero)
                + fp_round((1 - b1) * sums, zero))
     nu_rows = (fp_round(b2 * jnp.take(nu, safe, axis=0,
-                                      indices_are_sorted=srt), zero)
+                                      indices_are_sorted=True), zero)
                + fp_round((1 - b2) * fp_round(sums * sums, zero), zero))
-    mu_new = mu.at[rep].set(mu_rows, mode="drop", **fl)
-    nu_new = nu.at[rep].set(nu_rows, mode="drop", **fl)
+    mu_new = mu.at[rep].set(mu_rows, mode="drop", **DEDUP_FLAGS)
+    nu_new = nu.at[rep].set(nu_rows, mode="drop", **DEDUP_FLAGS)
     delta = -lr * (mu_rows / c1) / (jnp.sqrt(nu_rows / c2) + eps)
-    return (table.at[rep].add(delta.astype(table.dtype), mode="drop", **fl),
+    return (table.at[rep].add(delta.astype(table.dtype), mode="drop",
+                              **DEDUP_FLAGS),
             mu_new, nu_new, count)
 
 
@@ -862,14 +793,12 @@ def quantized_row_update(kind: str, payload: jax.Array, scale: jax.Array,
     ps = _usable_presorted(presorted, grad, rows)
     rep, sums = dedup_sum(grad.ids, grad.contribs, sentinel=rows,
                           presorted=ps)
-    fl = dedup_flags()
-    srt = fl["indices_are_sorted"]
     # clamped gathers are safe: sentinel slots carry zero sums and their
     # scatter-back is dropped outright (rep >= rows under mode='drop')
     safe = jnp.minimum(rep, rows - 1)
     old = wire_ops.decode_rows(
-        jnp.take(payload, safe, axis=0, indices_are_sorted=srt),
-        jnp.take(scale, safe, axis=0, indices_are_sorted=srt),
+        jnp.take(payload, safe, axis=0, indices_are_sorted=True),
+        jnp.take(scale, safe, axis=0, indices_are_sorted=True),
         store_dtype)
     if kind == "sgd":
         new_rows = old - lr * sums
@@ -877,12 +806,12 @@ def quantized_row_update(kind: str, payload: jax.Array, scale: jax.Array,
     else:  # adagrad — same accumulator math as sparse_adagrad's sort path
         (acc,) = state
         acc = _row_scatter_add(acc, rep, sums * sums)
-        acc_rows = jnp.take(acc, safe, axis=0, indices_are_sorted=srt)
+        acc_rows = jnp.take(acc, safe, axis=0, indices_are_sorted=True)
         new_rows = old - lr * sums * lax.rsqrt(acc_rows + eps)
         new_state = (acc,)
     p_rows, s_rows = wire_ops.encode_rows(new_rows, store_dtype, sr=True)
-    return (payload.at[rep].set(p_rows, mode="drop", **fl),
-            scale.at[rep].set(s_rows, mode="drop", **fl),
+    return (payload.at[rep].set(p_rows, mode="drop", **DEDUP_FLAGS),
+            scale.at[rep].set(s_rows, mode="drop", **DEDUP_FLAGS),
             new_state)
 
 

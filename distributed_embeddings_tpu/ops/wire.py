@@ -124,26 +124,17 @@ FP8_AMAX = 448.0          # float8_e4m3fn largest finite value
 INT16_ID_MAX = 2**15 - 1
 
 
-def _knob(env: str, fallback: str) -> str:
-    """One resolution seam for every wire/storage knob default (ISSUE
-    18): env var > tuned config-of-record > measured defaults >
-    fallback — see tune.resolve."""
-    from ..tune import resolve as _tune_resolve
-    return _tune_resolve.knob_value(env, fallback)
-
-
 def default_exchange_wire() -> str:
     """The ``DET_EXCHANGE_WIRE`` default for the float exchange wire
-    ('f32' unless overridden by env or an adopted tuned config); an
-    explicit ``exchange_wire=`` constructor argument always wins."""
-    return resolve_wire(_knob("DET_EXCHANGE_WIRE", ""))
+    ('f32' unless overridden); an explicit ``exchange_wire=`` constructor argument always wins."""
+    return resolve_wire(os.environ.get("DET_EXCHANGE_WIRE", ""))
 
 
 def default_id_wire() -> str:
     """``DET_ID_WIRE``: 'auto' (default) lets the planner narrow the id
     wire to int16 per bucket where the key space provably fits; 'int32'
     forces the full-width id wire everywhere."""
-    v = _knob("DET_ID_WIRE", "auto")
+    v = os.environ.get("DET_ID_WIRE", "auto")
     if v not in ("auto", "int32"):
         raise ValueError(
             f"DET_ID_WIRE={v!r}: expected 'auto' or 'int32'")
@@ -177,7 +168,7 @@ def default_store_dtype() -> str:
     ``storage_dtype=`` constructor argument always wins. Per-bucket
     eligibility (only cold/offloaded buckets quantize) is decided at
     plan lowering time, like the exchange wire."""
-    return resolve_store_dtype(_knob("DET_STORE_DTYPE", ""))
+    return resolve_store_dtype(os.environ.get("DET_STORE_DTYPE", ""))
 
 
 def default_delta_dtype() -> str:
@@ -186,7 +177,7 @@ def default_delta_dtype() -> str:
     pre-seam container). Independent of the table storage dtype: a
     fleet can stream int8 deltas to serving replicas whose tables are
     f32-resident, and vice versa."""
-    return resolve_store_dtype(_knob("DET_DELTA_DTYPE", ""))
+    return resolve_store_dtype(os.environ.get("DET_DELTA_DTYPE", ""))
 
 
 def resolve_store_dtype(name: Optional[str]) -> str:

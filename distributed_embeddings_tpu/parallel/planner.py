@@ -54,9 +54,8 @@ def default_hot_rows() -> int:
     replicated data-parallel in the training step — see
     layers/dist_model_parallel.py). 0 (the default) disables the hot
     shard; an explicit ``hot_rows=`` argument always wins."""
-    from distributed_embeddings_tpu.tune import resolve as _tune_resolve
     try:
-        return max(0, int(_tune_resolve.knob_value("DET_HOT_ROWS", "0")))
+        return max(0, int(os.environ.get("DET_HOT_ROWS", "0")))
     except ValueError:
         return 0
 

@@ -79,11 +79,8 @@ __all__ = ["LookaheadEngine", "default_lookahead"]
 def default_lookahead() -> int:
     """``DET_LOOKAHEAD`` environment default for `training.fit`'s
     ``lookahead`` argument (0 = the sequential step; an explicit
-    argument always wins). Resolves through the tune seam, so a
-    tuned config-of-record can set it when no env override is
-    present."""
-    from distributed_embeddings_tpu.tune import resolve as _tune_resolve
-    v = _tune_resolve.knob_value("DET_LOOKAHEAD", "0")
+    argument always wins)."""
+    v = os.environ.get("DET_LOOKAHEAD", "0")
     try:
         n = int(v)
     except ValueError:

@@ -280,9 +280,7 @@ class TableStore:
         self._params = params
         self._opt = opt_states
         if snapshot_every is None:
-            from distributed_embeddings_tpu.tune import resolve \
-                as _tune_resolve
-            snapshot_every = int(_tune_resolve.knob_value(
+            snapshot_every = int(os.environ.get(
                 "DET_STORE_SNAPSHOT_EVERY", "0"))
         self.snapshot_every = int(snapshot_every)
         self.delta_dtype = (wire_ops.default_delta_dtype()

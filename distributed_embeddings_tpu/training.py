@@ -436,8 +436,7 @@ def fit(model, params, data, steps: int, optimizer: str = "adagrad",
         inline form (identical batch order — the A/B baseline). Iterable
         `data` only; callable `data` is always pulled inline.
       pipeline_depth: bound of each inter-stage queue (backpressure).
-        ``None`` (default) resolves ``DET_PIPELINE_DEPTH`` through the
-        tune seam (env > tuned config > measured defaults > 2).
+        ``None`` (default) reads ``DET_PIPELINE_DEPTH`` (default 2).
       sync_every: block on the loss every N steps. Default: 1 on
         multi-process runs (keeps per-process collectives in lockstep)
         and on the CPU backend (XLA:CPU's in-process collectives can
@@ -450,9 +449,8 @@ def fit(model, params, data, steps: int, optimizer: str = "adagrad",
         (`store.observe`; per-step numpy work proportional to the
         batch's unique ids — the price of delta completeness, unlike
         the SAMPLED hot-admission feed below), and every
-        `publish_every` steps (``None`` resolves ``DET_PUBLISH_EVERY``
-        through the tune seam, default 0 = disabled) the loop commits
-        the current pytrees and
+        `publish_every` steps (``None`` reads ``DET_PUBLISH_EVERY``,
+        default 0 = disabled) the loop commits the current pytrees and
         writes the next row-delta file (first publish = full snapshot)
         into `publish_dir` for `InferenceEngine.poll_updates` replicas.
         Leftover steps publish once more at the end. Sparse path only.
@@ -527,14 +525,11 @@ def fit(model, params, data, steps: int, optimizer: str = "adagrad",
     """
     from distributed_embeddings_tpu.obs.registry import MetricRegistry
     from distributed_embeddings_tpu.obs.spans import span
-    from distributed_embeddings_tpu.tune import resolve as _tune_resolve
     reg = registry if registry is not None else MetricRegistry()
     if pipeline_depth is None:
-        pipeline_depth = int(_tune_resolve.knob_value(
-            "DET_PIPELINE_DEPTH", "2"))
+        pipeline_depth = int(os.environ.get("DET_PIPELINE_DEPTH", "2"))
     if publish_every is None:
-        publish_every = int(_tune_resolve.knob_value(
-            "DET_PUBLISH_EVERY", "0"))
+        publish_every = int(os.environ.get("DET_PUBLISH_EVERY", "0"))
     if lookahead is None:
         from distributed_embeddings_tpu.schedule import default_lookahead
         lookahead = default_lookahead()

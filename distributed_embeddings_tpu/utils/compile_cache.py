@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point of the repo (chip_smoke.py, bench.py, the
+One rule for every entry point of the repo (chip_smoke.py, the
 example mains, the tests and tools): a cache directory given from outside
 through ``JAX_COMPILATION_CACHE_DIR`` is used as it is — JAX reads that
 variable itself, and no other directory is set in code — and without it

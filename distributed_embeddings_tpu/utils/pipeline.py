@@ -309,9 +309,8 @@ class IngestPipeline:
 
 class SerialPipeline:
     """The same stages run inline in the consumer thread, with the same
-    per-stage accounting — the baseline arm of `bench.py --mode ingest`
-    and the parity reference for tests (pipelined output must be
-    bit-identical to this iteration order)."""
+    per-stage accounting — the parity reference for tests (pipelined
+    output must be bit-identical to this iteration order)."""
 
     def __init__(self, source: Iterable, stages: Sequence[Tuple[str, Callable]],
                  registry: Optional[MetricRegistry] = None):

@@ -86,9 +86,8 @@ def default_admit_threshold() -> int:
     (recent decayed count at which an unknown key earns a private row).
     Default 2: one sighting is noise, a repeat is a signal — the same
     default the serving cache promotes at."""
-    from distributed_embeddings_tpu.tune import resolve as _tune_resolve
     try:
-        return max(1, int(_tune_resolve.knob_value("DET_VOCAB_ADMIT", "2")))
+        return max(1, int(os.environ.get("DET_VOCAB_ADMIT", "2")))
     except ValueError:
         return 2
 

@@ -60,7 +60,7 @@ def test_legacy_parser_parity(name, legacy_expected):
 
 
 def test_profiling_delegates_to_ir():
-    """utils.profiling keeps the public API (bench.py, the audit arms
+    """utils.profiling keeps the public API (the audit arms
     and old tests all import it) but the implementation is the ONE IR
     parse — same outputs on a real lowered text, and Module inputs are
     accepted directly."""

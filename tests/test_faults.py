@@ -685,30 +685,3 @@ def test_slo_if_present_gates_only_when_metric_exists():
     assert [f.fid for f in slo.evaluate_rules(wrules, [breach, absent])] \
         == ["slo:w"]
     assert slo.evaluate_rules(wrules, [absent, absent]) == []
-
-
-# ------------------------------------------------------ soak scenarios
-def test_soak_scenarios_load_and_validate():
-    """Every shipped scenario file parses, validates, and constructs
-    its fault plan; scenario validation refuses unknown keys and the
-    lookahead x vocab-maintenance composition."""
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    from bench import SOAK_SCENARIO_DEFAULTS, load_soak_scenario
-
-    sdir = os.path.join(root, "tools", "soak_scenarios")
-    names = sorted(os.listdir(sdir))
-    assert len(names) >= 5
-    for name in names:
-        sc = load_soak_scenario(os.path.join(sdir, name))
-        assert set(SOAK_SCENARIO_DEFAULTS) <= set(sc)
-    with pytest.raises(ValueError, match="unknown keys"):
-        load_soak_scenario({"name": "x", "stepz": 3})
-    with pytest.raises(ValueError, match="lookahead"):
-        load_soak_scenario({"name": "x", "lookahead": 1,
-                            "vocab_manage": {"every": 4}})
-    with pytest.raises(ValueError, match="cannot fire"):
-        load_soak_scenario({"name": "x", "fault_plan": {"faults": [
-            {"point": "store.scan", "kind": "truncate", "at": [0]}]}})

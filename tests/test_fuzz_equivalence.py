@@ -85,7 +85,6 @@ def gen_config(seed):
     return specs, table_map, kw
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("seed", range(8))
 def test_random_config_equivalence(seed):
     specs, table_map, kw = gen_config(seed)
@@ -139,7 +138,6 @@ def test_random_config_ragged_and_weighted(seed):
         raise
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("seed", range(6))
 def test_storage_dtype_stream_and_stash_fuzz(seed, tmp_path):
     """Storage-dtype axis over the train-to-serve row stores (ISSUE 15):
@@ -390,7 +388,6 @@ def _offload_vs_device_sparse(specs, optimizer, dedup, placement, budget,
                                    err_msg=f"table {t} ({optimizer})")
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("optimizer", ["sgd", "adagrad", "adam"])
 @pytest.mark.parametrize("ragged", [False, True])
 @pytest.mark.parametrize("weighted", [False, True])
@@ -458,8 +455,11 @@ def test_sparse_train_wire_axis(optimizer, ragged, weighted, monkeypatch):
                                    err_msg=f"table {t} ({optimizer})")
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("seed", range(10))
+# seeds 2, 3, 5, 6 and 9 take 42-55 s each alone (the others 6-11): they stay
+# in the `-m slow` tier so this file keeps its share of the tier-1 clock
+@pytest.mark.parametrize("seed", [
+    pytest.param(s, marks=pytest.mark.slow) if s in (2, 3, 5, 6, 9) else s
+    for s in range(10)])
 def test_random_sparse_train_equivalence(seed):
     """Randomized sparse TRAINING equivalence: optimizer x dedup strategy x
     placement x host-offload corners (the named cases in test_sparse_train /

@@ -127,9 +127,6 @@ def test_hot_parity_weighted_inputs():
     _assert_parity("adagrad", weighted=True)
 
 
-# execution-bound on the single-core CPU test host: remaining optimizer x
-# exchange combos run in the `-m slow` tier (same split as sort folding)
-@pytest.mark.slow
 @pytest.mark.parametrize("optimizer", ["sgd", "adam"])
 @pytest.mark.parametrize("exchange", ["padded", "ragged"])
 def test_hot_parity_optimizers(optimizer, exchange):
@@ -137,7 +134,6 @@ def test_hot_parity_optimizers(optimizer, exchange):
         "1" if exchange == "ragged" else "0"))
 
 
-@pytest.mark.slow
 def test_hot_parity_tiled_forward():
     """Hot split x tiled forward gather (DET_LOOKUP_PATH=tiled, interpret
     mode off-TPU): the presorted artifact covers the sentinel-masked

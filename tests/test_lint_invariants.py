@@ -240,6 +240,53 @@ def test_shadow_metric_allowed_in_obs_and_by_escape(tmp_path):
                      rel=os.path.join(PKG, "serving", "other.py")) == []
 
 
+# --------------------------------------------------------- upward-import
+@pytest.mark.parametrize("src", [
+    "from distributed_embeddings_tpu.obs.registry import default_registry\n",
+    "from distributed_embeddings_tpu.obs import trace\n",
+    "from distributed_embeddings_tpu import obs\n",
+    "import distributed_embeddings_tpu.store.table_store\n",
+    "from ..schedule import lookahead\n",
+    "def f():\n    from ..fleet.admission import AdmissionController\n",
+    "from .. import analysis\n",
+])
+def test_upward_import_flagged(tmp_path, src):
+    """A planted import of a package above the hot path is caught in
+    every import form, at module level and inside a function; the same
+    line outside ops/, parallel/, layers/ is nobody's business."""
+    for low in ("ops", "parallel", "layers"):
+        fs = _lint_src(tmp_path, src, rel=os.path.join(PKG, low, "x.py"))
+        assert [f.rule for f in fs] == ["upward-import"], (low, fs)
+    assert _lint_src(tmp_path, src,
+                     rel=os.path.join(PKG, "serving", "x.py")) == []
+
+
+def test_upward_import_tree_clean_but_for_listed_debts(tmp_path,
+                                                       monkeypatch):
+    """The tree passes with its exceptions listed by name, each listed
+    exception still stands for a real import (a stale entry fails here),
+    and what the rule allows stays allowed."""
+    ok = ("from distributed_embeddings_tpu.obs.stages import staged\n"
+          "from distributed_embeddings_tpu.obs import stages\n"
+          "from ..utils import profiling\n"
+          "from . import wire\n"
+          "import jax\n")
+    assert _lint_src(tmp_path, ok,
+                     rel=os.path.join(PKG, "ops", "x.py")) == []
+
+    def upward():
+        return [f for path in lint.default_files()
+                for f in lint.lint_file(path) if f.rule == "upward-import"]
+
+    assert upward() == []
+    listed = lint.UPWARD_EXCEPTIONS
+    monkeypatch.setattr(lint, "UPWARD_EXCEPTIONS", ())
+    bare = upward()
+    assert len(bare) == len(listed)
+    assert sorted(f.path for f in bare) == sorted(
+        os.path.join(PKG, path) for path, _, _ in listed)
+
+
 def test_syntax_error_reported_not_raised(tmp_path):
     fs = _lint_src(tmp_path, "def broken(:\n",
                    rel=os.path.join(PKG, "ops", "x.py"))

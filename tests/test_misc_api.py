@@ -201,3 +201,125 @@ def test_compile_cache_dir_comes_from_outside_or_the_checkout(monkeypatch):
     want = os.path.join(repo, ".jax_cache")
     assert compile_cache.enable_compile_cache() == want
     assert calls == [("jax_compilation_cache_dir", want)]
+
+
+# ---- every DET_* default is read where it is used: unset gives the
+# fallback beside the read, set gives the value, and an explicit argument
+# (where the consumer takes one) still wins
+def _knob_emb(**kw):
+    from distributed_embeddings_tpu.layers.dist_model_parallel import (
+        DistributedEmbedding)
+    from distributed_embeddings_tpu.layers.embedding import Embedding
+    return DistributedEmbedding(
+        [Embedding(40, 8, combiner="sum") for _ in range(2)], mesh=None,
+        vocab_slack=4, **kw)
+
+
+def _knob_store(**kw):
+    from distributed_embeddings_tpu.store import TableStore
+    emb = _knob_emb()
+    return TableStore(emb, emb.init(jax.random.PRNGKey(0)), **kw)
+
+
+def _knob_fit(**kw):
+    """`fit` over no batches: it resolves its knobs, builds its pipeline
+    and takes no step. Returns what it handed the ingest pipeline."""
+    from distributed_embeddings_tpu import training
+    from distributed_embeddings_tpu.utils import pipeline
+    from test_sparse_train import TinyModel
+
+    model = TinyModel([(40, 8, "sum")] * 2, None)
+    params = {"embedding": model.embedding.init(jax.random.PRNGKey(0)),
+              "head": {"w": jnp.zeros((16, 1), jnp.float32)}}
+    seen = {}
+    real = pipeline.staged_batches
+
+    def spy(source, **kwargs):
+        seen["depth"] = kwargs["depth"]
+        return real(source, **kwargs)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pipeline, "staged_batches", spy)
+        training.fit(model, params, iter(()), steps=0, log_every=0, **kw)
+    return seen
+
+
+def _read_id_wire(explicit):
+    from distributed_embeddings_tpu.ops import wire
+    return wire.default_id_wire()
+
+
+def _read_lookahead(explicit):
+    # a dense-path fit refuses any lookahead but 0
+    try:
+        _knob_fit(sparse=False, lookahead=explicit)
+    except ValueError as e:
+        assert "lookahead requires the sparse tapped path" in str(e)
+        return 1
+    return 0
+
+
+def _read_publish_every(explicit):
+    # a publishing fit refuses to run without a directory
+    try:
+        _knob_fit(sparse=True, store=object(), publish_every=explicit)
+    except ValueError as e:
+        assert "publish_every requires publish_dir" in str(e)
+        return "publishing"
+    return "off"
+
+
+def _read_vocab_admit(explicit):
+    from distributed_embeddings_tpu.vocab import VocabManager
+    return VocabManager(_knob_emb(), admit_threshold=explicit,
+                        use_native=False).admit_threshold
+
+
+def _read_fleet(attr):
+    from distributed_embeddings_tpu.fleet import AdmissionController
+    return lambda explicit: getattr(
+        AdmissionController(**{attr: explicit}), attr)
+
+
+# (variable, reader(explicit argument or None), reading when unset,
+#  a value to set, its reading, an explicit argument, its reading)
+_KNOBS = [
+    ("DET_EXCHANGE_WIRE",
+     lambda x: _knob_emb(exchange_wire=x).strategy.exchange_wire,
+     "f32", "bf16", "bf16", "f32", "f32"),
+    ("DET_ID_WIRE", _read_id_wire, "auto", "int32", "int32", None, None),
+    ("DET_STORE_DTYPE",
+     lambda x: _knob_emb(storage_dtype=x).strategy.storage_dtype,
+     "f32", "int8", "int8", "fp8", "fp8"),
+    ("DET_DELTA_DTYPE", lambda x: _knob_store(delta_dtype=x).delta_dtype,
+     "f32", "int8", "int8", "fp8", "fp8"),
+    ("DET_HOT_ROWS", lambda x: _knob_emb(hot_rows=x).strategy.hot_rows,
+     0, "16", 16, 4, 4),
+    ("DET_LOOKAHEAD", _read_lookahead, 0, "1", 1, 0, 0),
+    ("DET_PIPELINE_DEPTH",
+     lambda x: _knob_fit(pipeline_depth=x)["depth"], 2, "5", 5, 3, 3),
+    ("DET_PUBLISH_EVERY", _read_publish_every,
+     "off", "3", "publishing", 0, "off"),
+    ("DET_STORE_SNAPSHOT_EVERY",
+     lambda x: _knob_store(snapshot_every=x).snapshot_every,
+     0, "4", 4, 2, 2),
+    ("DET_VOCAB_ADMIT", _read_vocab_admit, 2, "5", 5, 3, 3),
+    ("DET_FLEET_MAX_QUEUE_DEPTH", _read_fleet("max_queue_depth"),
+     64, "7", 7, 9, 9),
+    ("DET_FLEET_MAX_QUEUE_ROWS", _read_fleet("max_queue_rows"),
+     None, "100", 100, 50, 50),
+]
+
+
+@pytest.mark.parametrize("env,read,unset,value,set_reading,explicit,"
+                         "explicit_reading", _KNOBS,
+                         ids=[k[0] for k in _KNOBS])
+def test_det_default_is_read_at_its_site(monkeypatch, env, read, unset,
+                                         value, set_reading, explicit,
+                                         explicit_reading):
+    monkeypatch.delenv(env, raising=False)
+    assert read(None) == unset
+    monkeypatch.setenv(env, value)
+    assert read(None) == set_reading
+    if explicit is not None:
+        assert read(explicit) == explicit_reading

@@ -80,7 +80,7 @@ def test_rows_appliers_exact_placement():
     placed = pt.tiled_sgd_rows(jnp.zeros((v, w)), rep, sums, -1.0,
                                interpret=True)
     want = jnp.zeros((v, w)).at[rep].add(sums, mode="drop",
-                                         **su.dedup_flags())
+                                         **su.DEDUP_FLAGS)
     np.testing.assert_array_equal(np.asarray(placed), np.asarray(want))
 
 
@@ -363,10 +363,6 @@ def test_kernel_check_failure_raises(monkeypatch):
     with pytest.raises(RuntimeError, match="disagree with the XLA"):
         wrong.prevalidate(16)
     assert not wrong.validated
-    # two knobs that cannot both be served: refused, not rerouted
-    monkeypatch.setenv("DET_DEDUP_IMPL", "cumsum")
-    with pytest.raises(ValueError, match="DET_DEDUP_IMPL=cumsum"):
-        su._scatter_route("pallas")
 
 
 def test_kernel_check_shape_class_cache():

@@ -82,9 +82,6 @@ def test_fold_parity_adagrad(strategy):
     _assert_bitexact("adagrad", strategy)
 
 
-# execution-bound on the single-core CPU test host: the remaining
-# optimizer x strategy combos run in the `-m slow` tier
-@pytest.mark.slow
 @pytest.mark.parametrize("optimizer", ["sgd", "adam"])
 @pytest.mark.parametrize("strategy", ["sort", "tiled"])
 def test_fold_parity_optimizers(optimizer, strategy):

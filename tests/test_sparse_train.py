@@ -293,14 +293,12 @@ def test_sparse_train_weighted_inputs():
     run_equivalence(specs, "adagrad", inputs_fn=inputs_fn)
 
 
-def test_sparse_step_hlo_scatter_promises(monkeypatch):
+def test_sparse_step_hlo_scatter_promises():
     """The lowered train step must carry the scatter promises the round-3
     hardware data demands (XLA's duplicate-safe scatter measured at
     100-280 ns/row): both row-update scatters say unique_indices=true, and
-    they are the step's ONLY scatters under either dedup impl — the
-    default sums its sorted runs with a scan (ISSUE 31), as the cumsum
-    impl always did, so neither holds a segment-sum or a rep-build
-    scatter."""
+    they are the step's ONLY scatters — dedup sums its sorted runs with a
+    scan (ISSUE 31), so it holds no segment-sum or rep-build scatter."""
     import re
     from distributed_embeddings_tpu.layers.dist_model_parallel import (
         DistributedEmbedding)
@@ -335,15 +333,7 @@ def test_sparse_step_hlo_scatter_promises(monkeypatch):
         lab = jax.ShapeDtypeStruct((8,), jnp.float32)
         return jax.jit(step_fn).lower(params, state, num, cats, lab).as_text()
 
-    monkeypatch.setenv("DET_DEDUP_IMPL", "sort")
-    txt_sort = lower_text()
-    n_scatter_sort = len(re.findall(r'"stablehlo.scatter"', txt_sort))
-    assert len(re.findall(r"unique_indices\s*=\s*true", txt_sort)) >= 2
-
-    monkeypatch.setenv("DET_DEDUP_IMPL", "cumsum")
-    txt_cs = lower_text()
-    n_scatter_cs = len(re.findall(r'"stablehlo.scatter"', txt_cs))
-    assert len(re.findall(r"unique_indices\s*=\s*true", txt_cs)) >= 2
-    assert n_scatter_sort == n_scatter_cs == 2, (
-        f"only the accumulator's and the table's row updates scatter: "
-        f"sort {n_scatter_sort}, cumsum {n_scatter_cs}")
+    txt = lower_text()
+    assert len(re.findall(r'"stablehlo.scatter"', txt)) == 2, (
+        "only the accumulator's and the table's row updates scatter")
+    assert len(re.findall(r"unique_indices\s*=\s*true", txt)) == 2

@@ -42,7 +42,7 @@ def test_dedup_sum_exact():
     real = rep[rep < 50]
     assert len(real) == len(set(real.tolist()))
     # promise contract: rep must be strictly increasing (unique AND sorted —
-    # downstream scatters assert these to XLA; see dedup_flags)
+    # downstream scatters assert these to XLA; see DEDUP_FLAGS)
     assert (np.diff(rep.astype(np.int64)) > 0).all()
 
 
@@ -141,55 +141,6 @@ def test_dedup_sum_scatters_nothing():
     assert "4096x16xf32" in text and "stablehlo.sort" in text
     assert "scatter" not in text
     assert text.count('"stablehlo.gather"(') == 1
-
-
-def test_dedup_sum_cumsum_impl(monkeypatch):
-    """DET_DEDUP_IMPL=cumsum: scatter-free aggregation must match the exact
-    sort impl to f32-cumsum tolerance, keep rep unique, and drop OOB."""
-    monkeypatch.setenv("DET_DEDUP_IMPL", "cumsum")
-    rng = np.random.default_rng(3)
-    for oob in (False, True):
-        ids, contribs, dense = make_case(rng, n=1023, oob=oob)
-        rep, sums = su.dedup_sum(jnp.asarray(ids), jnp.asarray(contribs),
-                                 sentinel=50)
-        rep, sums = np.asarray(rep), np.asarray(sums)
-        got = np.zeros_like(dense)
-        for r, s in zip(rep, sums):
-            if r < 50:
-                got[r] += s
-        np.testing.assert_allclose(got, dense, rtol=1e-4, atol=1e-4)
-        assert len(rep) == len(set(rep.tolist()))   # unique incl. fillers
-        flags = su.dedup_flags()
-        assert flags["unique_indices"] and not flags["indices_are_sorted"]
-
-
-@pytest.mark.parametrize("kind", ["adagrad", "adam"])
-def test_sparse_update_cumsum_impl_matches_sort(monkeypatch, kind):
-    """Full row-wise update under the cumsum dedup impl == sort impl to
-    tolerance (the opt-in trades exactness for scatter-free aggregation)."""
-    rng = np.random.default_rng(4)
-    ids, contribs, _ = make_case(rng, n=511, oob=True)
-    table = rng.standard_normal((50, 8)).astype(np.float32)
-    g = su.SparseRowGrad(jnp.asarray(ids), jnp.asarray(contribs))
-
-    def run():
-        if kind == "adagrad":
-            t, acc = su.sparse_adagrad(
-                jnp.asarray(table), jnp.full((50, 8), 0.1, jnp.float32), g,
-                0.05, strategy="sort")
-            return np.asarray(t), np.asarray(acc)
-        t, mu, nu, c = su.sparse_adam(
-            jnp.asarray(table), jnp.zeros((50, 8), jnp.float32),
-            jnp.zeros((50, 8), jnp.float32), jnp.zeros((), jnp.int32), g,
-            0.05, strategy="sort")
-        return np.asarray(t), np.asarray(mu), np.asarray(nu)
-
-    monkeypatch.setenv("DET_DEDUP_IMPL", "sort")
-    want = run()
-    monkeypatch.setenv("DET_DEDUP_IMPL", "cumsum")
-    got = run()
-    for a, b in zip(want, got):
-        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("strategy", ["sort", "dense"])

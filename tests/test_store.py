@@ -32,7 +32,7 @@ BATCH = 16
 
 
 class EmbOnlyModel:
-    """Embedding-only tapped model (the bench/serve idiom): loss over the
+    """Embedding-only tapped model (the serve idiom): loss over the
     concatenated embedding outputs, no dense head."""
 
     def __init__(self, emb):

@@ -34,9 +34,7 @@ is not a gate.
 
 Legacy per-arm records (`audit_tapped_step` sort gates at 30M-row
 vocabs/tiled/hot shards, `wire_byte_arms`, `audit_lookahead_overlap`)
-still run and still gate: bench.py embeds them in every hardware record
-so each measurement carries the op-count fingerprint of the step it
-timed.
+still run and still gate.
 """
 
 import argparse
@@ -49,7 +47,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from distributed_embeddings_tpu.analysis import programs as _programs  # noqa: E402
 from distributed_embeddings_tpu.analysis import ir, passes  # noqa: E402
 
-# bench.py and the test suite reach these by their historical names
+# the test suite reaches these by their historical names
 _build_model = _programs.build_model
 _head_params = _programs.head_params
 _ensure_world = _programs.ensure_world
@@ -170,7 +168,7 @@ def main(argv=None) -> int:
         _ensure_world(8)
     failures = []
 
-    # ---- legacy per-arm sort gates (bench.py embeds the same records)
+    # ---- legacy per-arm sort gates
     for optimizer, strategy, lookup, hot_rows in DEFAULT_ARMS:
         folds = (True, False) if args.unfolded else (True,)
         for fold in folds:

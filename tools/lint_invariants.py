@@ -33,16 +33,16 @@ tests/test_lint_invariants.py):
                      aliases and module-attribute forms cannot evade —
                      and ``collections.Counter`` stays untouched (only
                      names imported from the metric modules count).
-
-  scenario-knobs     every ``"knobs"`` override in a checked-in
-                     ``tools/soak_scenarios/*.json`` scenario names a
-                     tune-registry knob with a legal value (ISSUE 18) —
-                     the same validation ``bench.load_soak_scenario``
-                     enforces at load, moved up to CI so a typo'd env
-                     var or out-of-domain value is flagged at review,
-                     not on the soak host. JSON rule: runs whenever the
-                     default file set is linted (no per-line escape —
-                     fix the scenario).
+  upward-import      no module under ``ops/``, ``parallel/`` or
+                     ``layers/`` imports a subsystem package
+                     (``serving``, ``store``, ``vocab``, ``fleet``,
+                     ``schedule``, ``faults``, ``analysis``), nor from
+                     ``obs/`` anything but ``obs.stages`` (the scope
+                     names the step is traced under) — what a cell runs
+                     is read from the step down, never through a
+                     package above it. Standing exceptions are named
+                     debts, listed in ``UPWARD_EXCEPTIONS`` by file,
+                     function and module.
 
 Escapes: append ``# lint: allow(<rule>)`` to the offending line (or the
 line directly above). Escapes are themselves greppable, which is the
@@ -85,6 +85,20 @@ METRIC_MODULES = (
     "distributed_embeddings_tpu.utils.metrics",   # the re-export
 )
 METRIC_ALLOWED_DIR = "obs"
+
+# the hot path's packages, and what they may not import
+LOW_DIRS = ("ops", "parallel", "layers")
+SUBSYSTEMS = ("serving", "store", "vocab", "fleet", "schedule", "faults",
+              "analysis")
+OBS_ALLOWED = "obs.stages"
+# (file, enclosing function, imported module): each a debt ROADMAP names
+UPWARD_EXCEPTIONS = (
+    # ROADMAP D5, the host-apply ladder: the quantized arm of
+    # host_bucket_apply counts its rows in the default registry
+    # (layers/dist_model_parallel.py:3647 today)
+    (os.path.join("layers", "dist_model_parallel.py"),
+     "_host_quantized_touched_apply", "obs.registry"),
+)
 
 _ALLOW_RE = re.compile(
     r'#.*?lint:\s*allow\(([\w-]+(?:\s*,\s*[\w-]+)*)\)')
@@ -131,6 +145,39 @@ def _rel(path: str) -> str:
     return os.path.relpath(path, REPO_ROOT)
 
 
+def _imports_in_scope(node: ast.AST, fn: Optional[str] = None):
+    """(import node, innermost enclosing function's name or None)."""
+    for child in ast.iter_child_nodes(node):
+        if isinstance(child, (ast.Import, ast.ImportFrom)):
+            yield child, fn
+        elif isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            yield from _imports_in_scope(child, child.name)
+        else:
+            yield from _imports_in_scope(child, fn)
+
+
+def _package_modules(node: ast.AST, pkg_rel: str) -> List[str]:
+    """What an import statement of a package module names, each relative
+    to the package root ('obs.registry.default_registry', 'store');
+    imports from outside the package give nothing."""
+    if isinstance(node, ast.Import):
+        full = [a.name for a in node.names]
+    else:
+        base = node.module or ""
+        if node.level:
+            here = [PACKAGE] + pkg_rel.split(os.sep)[:-1]
+            base = ".".join(here[:len(here) - node.level + 1]
+                            + ([base] if base else []))
+        full = [f"{base}.{a.name}" for a in node.names]
+    return [m[len(PACKAGE) + 1:] for m in full
+            if m.startswith(PACKAGE + ".")]
+
+
+def _under(mod: str, prefix: str) -> bool:
+    """`mod` is the module `prefix` or something inside it."""
+    return mod == prefix or mod.startswith(prefix + ".")
+
+
 def lint_file(path: str, rel: Optional[str] = None) -> List[Finding]:
     """Lint one file. ``rel`` overrides the repo-relative path the
     path-scoped rules key on (fixture tests lint tmp files AS IF they
@@ -157,6 +204,21 @@ def lint_file(path: str, rel: Optional[str] = None) -> List[Finding]:
     check_clock = in_package and pkg_rel.split(os.sep)[0] in \
         JIT_MODULE_DIRS
     check_metric = pkg_rel.split(os.sep)[0] != METRIC_ALLOWED_DIR
+
+    if in_package and pkg_rel.split(os.sep)[0] in LOW_DIRS:
+        for node, fn in _imports_in_scope(tree):
+            for mod in _package_modules(node, pkg_rel):
+                top = mod.split(".")[0]
+                upward = top in SUBSYSTEMS or (
+                    top == "obs" and not _under(mod, OBS_ALLOWED))
+                listed = any(
+                    pkg_rel == exc_path and fn == func and _under(mod, m)
+                    for exc_path, func, m in UPWARD_EXCEPTIONS)
+                if upward and not listed:
+                    emit("upward-import", node,
+                         f"{pkg_rel} imports {mod} — ops/, parallel/ and "
+                         "layers/ import nothing above themselves "
+                         f"(of obs/ only {OBS_ALLOWED})")
 
     # ---- import tracking, so from-imports and aliases cannot evade the
     # rules: `from jax.lax import all_to_all`, `import jax.lax as jl`,
@@ -262,47 +324,6 @@ def lint_file(path: str, rel: Optional[str] = None) -> List[Finding]:
     return findings
 
 
-def lint_scenario_knobs(scenario_dir: Optional[str] = None
-                        ) -> List[Finding]:
-    """Validate every scenario file's ``"knobs"`` overrides against the
-    tune registry (ISSUE 18). A scenario naming an unknown env var or an
-    out-of-domain value would refuse at `bench.load_soak_scenario` —
-    this rule surfaces it in CI instead. An unparsable scenario file is
-    itself a finding (the soak host would hit the same wall)."""
-    if scenario_dir is None:
-        scenario_dir = os.path.join(REPO_ROOT, "tools", "soak_scenarios")
-    sys.path.insert(0, REPO_ROOT)
-    from distributed_embeddings_tpu.tune import registry as tune_registry
-    findings: List[Finding] = []
-    if not os.path.isdir(scenario_dir):
-        return findings
-    for name in sorted(os.listdir(scenario_dir)):
-        if not name.endswith(".json"):
-            continue
-        path = os.path.join(scenario_dir, name)
-        rel = _rel(path)
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except ValueError as e:
-            findings.append(Finding("scenario-knobs", rel, 1,
-                                    f"unparsable scenario JSON: {e}"))
-            continue
-        knobs = doc.get("knobs")
-        if knobs is None:
-            continue
-        if not isinstance(knobs, dict):
-            findings.append(Finding(
-                "scenario-knobs", rel, 1,
-                "'knobs' must be an env -> value object"))
-            continue
-        for env, value in knobs.items():
-            err = tune_registry.validate_override(env, value)
-            if err is not None:
-                findings.append(Finding("scenario-knobs", rel, 1, err))
-    return findings
-
-
 def default_files() -> List[str]:
     out = []
     for dirpath, dirnames, filenames in os.walk(
@@ -324,10 +345,6 @@ def main(argv=None) -> int:
     findings: List[Finding] = []
     for path in files:
         findings.extend(lint_file(path))
-    if not args.paths:
-        # the JSON scenario rule rides the default sweep (explicit
-        # paths mean "lint exactly these python files")
-        findings.extend(lint_scenario_knobs())
     if args.json:
         print(json.dumps([f.to_dict() for f in findings], indent=1))
     else:

@@ -157,8 +157,8 @@ def main():
         return out, pm
     timed_chain(step7, (rows, perm), label="permute 720k x 16 rows")
 
-    # 8. fused sparse-adagrad row update (the bench's per-bucket backward
-    # cost; decides DET_SPARSE_DENSE_MAX), both dedup strategies
+    # 8. fused sparse-adagrad row update (the per-bucket backward cost;
+    # either side of sparse_update.DENSE_ELEMS_MAX), both dedup strategies
     from distributed_embeddings_tpu.ops import sparse_update as su
     tbl = jnp.zeros((v, 16), jnp.float32)
     acc = jnp.full((v, 16), 0.1, jnp.float32)

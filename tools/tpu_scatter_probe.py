@@ -187,9 +187,7 @@ def main():
     acc = jnp.full((v, 16), 0.1, jnp.float32)
     sids = jnp.asarray(rng.integers(0, v, n).astype(np.int32))
     contribs = jnp.asarray(rng.standard_normal((n, 16), dtype=np.float32))
-    for strat, dedup in (("sort", "sort"), ("sort", "cumsum"),
-                         ("dense", "sort")):
-        os.environ["DET_DEDUP_IMPL"] = dedup
+    for strat in ("sort", "dense"):
 
         def step8(s, strat=strat):
             t, a, i = s
@@ -197,9 +195,7 @@ def main():
                                        0.01, strategy=strat)
             return t2, a2, (i * 1103515245 + 12345) % v
         timed_chain(step8, (tbl, acc, sids), iters=6,
-                    label=f"sparse_adagrad[{strat}|{dedup}]+flags "
-                          "n=720k V=25M")
-    os.environ.pop("DET_DEDUP_IMPL", None)
+                    label=f"sparse_adagrad[{strat}]+flags n=720k V=25M")
 
     # Pallas RMW scatter kernel vs the flagged XLA scatter (width-128 f32
     # rows only: see pallas_lookup.check_row_dma)
