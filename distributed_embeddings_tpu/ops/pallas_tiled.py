@@ -30,9 +30,15 @@ per-row DMA, no semaphores:
 
     HBM traffic is block-sequential (the access pattern of a blocked
     matmul), so the cost model is bytes/bandwidth, not descriptors/row:
-    ~visited tiles * tile bytes * 2(read+write) * arrays. (Projected, not
-    measured; note that XLA stores a narrow table column-major, so a
-    [tile, 16] block of it is not the sequential read this model assumes.)
+    ~visited tiles * tile bytes * 2(read+write) * arrays. XLA stores a
+    table narrower than 128 lanes column-major, so the update walk of
+    such a table runs over its transpose, rows on the lanes (see "update
+    kernels"). Read on one v5e chip (PERF.md section 6, PR 33):
+    `tiled_adagrad_rows` over Tiny V3's width-16 bucket (70.2M rows, its
+    accumulator, 2.88M deduped slots) takes 42-48 ms a call with its
+    walk (the kernel itself 35-40 ms in the cell: 18 GB at 450-510
+    GB/s), where the two XLA scatter-adds and the re-read it stands in
+    for take 609-673.
 
 This is the TPU-native analogue of the reference backward kernel's
 sort -> unique -> segment-reduce pipeline (reference:
@@ -53,11 +59,15 @@ Status: interpret-mode tested on CPU (tests/test_pallas_tiled.py,
 tests/test_pallas_fused.py); every entry point compiles for the chip at
 widths 16 and 128 (tests/test_chip_compile.py) and ran compiled against its
 XLA formulation at widths 8, 16 and 128 on a v5e chip (chip_smoke.py,
-PR 22). No step time has been measured. Dispatch lives in sparse_update
-behind DET_SCATTER_IMPL=tiled (raw-stream kernels, f32-tolerance parity) and
-DET_SCATTER_IMPL=pallas (the ISSUE 12 fused strategy: deduped-row
-appliers + the weighted gather->combine forward, bit-exact vs the XLA
-sort path — see the fused section below).
+PR 22; the compiled checks again in PR 33, after the update walk was
+re-oriented). One entry point is on a default path and timed in a cell:
+`tiled_adagrad_rows`, which `sparse_update.sparse_adagrad` takes on a TPU
+for a narrow table's sort branch (ISSUE 33; both Tiny V3 cells). The rest
+of the dispatch lives in sparse_update behind DET_SCATTER_IMPL=tiled
+(raw-stream kernels, f32-tolerance parity) and DET_SCATTER_IMPL=pallas (the
+ISSUE 12 fused strategy: deduped-row appliers + the weighted
+gather->combine forward, bit-exact vs the XLA sort path — see the fused
+section below); none of those has a step time.
 """
 
 import functools
@@ -104,6 +114,31 @@ def _interpret_default(interpret: Optional[bool]) -> bool:
 # slab and the MXU contraction depth.
 _TILE = 1024     # table rows per tile (multiple of 8)
 _CHUNK = 512     # sorted ids per chunk (multiple of 128)
+# the rows-on-lanes update walk (see "update kernels"). Its two scalar-
+# prefetch arrays hold one int32 a pair each and share the chip's 1 MiB of
+# scalar memory (described-chip compiles, ISSUE 33: 79,819 pairs fit,
+# 159,638 were refused). Its one-hots hold at most rows * chunk + n * tile
+# elements a call (n_tiles + n_chunks pairs of tile * chunk), and a larger
+# tile means fewer grid steps and block transfers: read on the chip at
+# Tiny V3's bucket (70.2M rows, 2.88M ids, chunk 256; PERF.md section 6,
+# PR 33) tile 1024 took 79 ms, 4096 47, 8192 46 and 16384 57.
+_PAIRS_MAX = 130_000
+_LANE_CHUNK = 256
+_LANE_TILES = (1024, 2048, 4096, 8192)
+
+
+def lane_blocks(rows: int, n: int):
+    """(chunk, tile) of the rows-on-lanes update walk over a [rows, w]
+    table and a stream of n ids: the largest tile of the ladder at which
+    the stream's share of the one-hots (n * tile) stays under the table's
+    (rows * chunk), and at least the smallest whose n_tiles + n_chunks
+    pairs fit the scalar memory. None where no tile fits it."""
+    n_chunks = -(-n // _LANE_CHUNK)
+    fits = [t for t in _LANE_TILES if -(-rows // t) + n_chunks <= _PAIRS_MAX]
+    if not fits:
+        return None
+    even = [t for t in fits if n * t <= rows * _LANE_CHUNK]
+    return _LANE_CHUNK, (even[-1] if even else fits[0])
 
 
 def _sort_ids(ids: jax.Array, contribs: Optional[jax.Array], vocab: int):
@@ -160,21 +195,28 @@ def _chunk_spec(chunk: int) -> pl.BlockSpec:
                         memory_space=pltpu.VMEM)
 
 
-def _tile_major_pairs(chunk_first, chunk_last, n_tiles: int, n_chunks: int):
-    """Static-size (tile, chunk) pair walk, TILE-major: for each tile, the
-    chunks overlapping it (>=1 per tile — empty tiles get one zero-
-    contribution dummy so every output tile block is visited and written).
-    Pairs are monotone in tile, so each tile's pairs are consecutive and
-    the out block revisit/flush pattern is exact.
+def _tile_major_pairs(sid, vocab: int, n_tiles: int, n_chunks: int,
+                      chunk: int, tile: int):
+    """Static-size (tile, chunk) pair walk, TILE-major, from where each
+    tile's ids sit in the sorted stream `sid` (whole chunks, fillers
+    >= vocab at the end): tile t holds slots [pos[t], pos[t+1]), so it
+    pairs with the chunks pos[t] // chunk .. (pos[t+1] - 1) // chunk and
+    with no other — a chunk whose ids jump over a tile is not paired with
+    it. A tile with no id gets one pair with the FILLER chunk (index
+    n_chunks), so every output tile block is still visited and written,
+    and the kernels read `cof == n_chunks` as "nothing to place". Pairs
+    are monotone in tile, so each tile's pairs are consecutive and the
+    out block revisit/flush pattern is exact.
 
-    Returns (tof [G], cof [G]) int32 with G = n_tiles + n_chunks static;
+    Returns (tof [G], cof [G]) int32 with G = n_tiles + n_chunks static
+    (a tile's span is one more than the chunk edges inside its ids);
     padded trailing pairs map to (last tile, filler chunk)."""
     g_count = n_tiles + n_chunks
-    t_iota = lax.iota(jnp.int32, n_tiles)
-    lo = jnp.searchsorted(chunk_last, t_iota, side="left").astype(jnp.int32)
-    hi = (jnp.searchsorted(chunk_first, t_iota, side="right").astype(
-        jnp.int32) - 1)
-    span = jnp.maximum(1, hi - lo + 1)
+    edges = jnp.minimum(lax.iota(jnp.int32, n_tiles + 1) * tile, vocab)
+    pos = jnp.searchsorted(sid, edges, side="left").astype(jnp.int32)
+    lo = pos[:-1] // chunk
+    some = pos[1:] > pos[:-1]
+    span = jnp.where(some, (pos[1:] - 1) // chunk - lo + 1, 1)
     pstart = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(span)[:-1].astype(jnp.int32)])
     total = pstart[-1] + span[-1]
@@ -184,7 +226,8 @@ def _tile_major_pairs(chunk_first, chunk_last, n_tiles: int, n_chunks: int):
         0, n_tiles - 1)
     cof = jnp.clip(jnp.take(lo, tof) + (g_iota - jnp.take(pstart, tof)),
                    0, n_chunks - 1)
-    cof = jnp.where(g_iota < total, cof, jnp.int32(n_chunks))
+    cof = jnp.where((g_iota < total) & jnp.take(some, tof), cof,
+                    jnp.int32(n_chunks))
     tof = jnp.where(g_iota < total, tof, jnp.int32(n_tiles - 1))
     return tof, cof
 
@@ -230,7 +273,19 @@ def _onehot(ids_row: jax.Array, tile_base, tile: int) -> jax.Array:
 
 # --------------------------------------------------------------------------
 # update kernels (tile-major walk)
+#
+# Two orientations of one walk. A table of 128 lanes and more is stored
+# row-major and its blocks are [tile, width]. A narrower one the chip
+# stores COLUMN-major (`f32[V,16]{0,1:T(8,128)}`, which is byte for byte
+# `f32[16,V]{1,0:T(8,128)}`): a [tile, 16] block of it makes the compiler
+# copy the whole table to a row-major one with its 16 lanes padded to 128
+# (36 GB for Tiny V3's 70.2M-row bucket: ISSUE 33), so the walk runs over
+# `table.T` with blocks [width, tile], rows on the lanes, and the
+# transposes on either side of the call are bitcasts.
 # --------------------------------------------------------------------------
+ROW_MAJOR_WIDTH = 128   # narrower tables are stored, and walked, rows on lanes
+
+
 def _flags(tof_ref, g, g_count):
     t = tof_ref[g]
     prev_t = tof_ref[jnp.maximum(g - 1, 0)]
@@ -240,23 +295,70 @@ def _flags(tof_ref, g, g_count):
     return t, first, last
 
 
-def _sgd_kernel(tof_ref, cof_ref, ids_ref, grads_ref, hp_ref, table_ref,
-                out_ref, acc_ref, *, tile: int, g_count: int):
+def _top16(x: jax.Array) -> jax.Array:
+    """x with the low 16 bits of its f32 pattern cleared: a bfloat16 value
+    held in f32, and ``x - _top16(x)`` is exact."""
+    bits = lax.bitcast_convert_type(x, jnp.uint32) & jnp.uint32(0xFFFF0000)
+    return lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _placed(ids_ref, grads_ref, base, tile: int, lanes: bool) -> jax.Array:
+    """One pair's contribution to its tile, in the tile's orientation:
+    [tile, width], or [width, tile] with `lanes`.
+
+    Rows on lanes, the one-hot [tile, chunk] is the large operand (chunk x
+    tile a pair, ~10^10 elements a Tiny V3 step) and HIGHEST on f32 would
+    pass it through the matrix unit six times. It is exact in bfloat16, so
+    the gradient rows are cut into three bfloat16 pieces instead (8 + 8 +
+    8 bits of mantissa: all of an f32), stacked as 3 * width rows of ONE
+    bfloat16 matmul with f32 accumulation, and the three slabs added. Over
+    a unique id stream each output lane receives one non-zero product a
+    slab, so the total is the f32 value bit for bit: placement, not
+    arithmetic. (A non-finite gradient element spreads over its tile here
+    as in the row-major form: 0 * inf.)"""
+    ids = ids_ref[0, :]
+    if not lanes:
+        return lax.dot_general(_onehot(ids, base, tile),
+                               grads_ref[:].astype(jnp.float32),
+                               (((1,), (0,)), ((), ())),
+                               precision=lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+    x = grads_ref[:].astype(jnp.float32)                 # [width, chunk]
+    width = x.shape[0]
+    hi = _top16(x)
+    mid = _top16(x - hi)
+    pieces = jnp.concatenate([hi, mid, (x - hi) - mid],
+                             axis=0).astype(jnp.bfloat16)
+    slabs = lax.dot_general(pieces,
+                            _onehot(ids, base, tile).astype(jnp.bfloat16),
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    return (slabs[:width] + slabs[width:2 * width]) + slabs[2 * width:]
+
+
+def _tile_total(tof_ref, cof_ref, ids_ref, grads_ref, acc_ref, *, tile: int,
+                g_count: int, n_chunks: int, lanes: bool):
+    """Add this pair's placed rows to the tile's running total in
+    `acc_ref`; a pair with the filler chunk places nothing and skips the
+    matmul. Returns whether this is the tile's last pair."""
     g = pl.program_id(0)
     t, first, last = _flags(tof_ref, g, g_count)
-    oh = _onehot(ids_ref[0, :], t * tile, tile)
-    part = lax.dot_general(oh, grads_ref[:].astype(jnp.float32),
-                           (((1,), (0,)), ((), ())),
-                           precision=lax.Precision.HIGHEST,
-                           preferred_element_type=jnp.float32)
 
     @pl.when(first)
     def _():
-        acc_ref[:] = part
+        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when(jnp.logical_not(first))
+    @pl.when(cof_ref[g] != n_chunks)
     def _():
-        acc_ref[:] = acc_ref[:] + part
+        acc_ref[:] = acc_ref[:] + _placed(ids_ref, grads_ref, t * tile, tile,
+                                          lanes)
+
+    return last
+
+
+def _sgd_kernel(tof_ref, cof_ref, ids_ref, grads_ref, hp_ref, table_ref,
+                out_ref, acc_ref, **walk):
+    last = _tile_total(tof_ref, cof_ref, ids_ref, grads_ref, acc_ref, **walk)
 
     @pl.when(last)
     def _():
@@ -268,23 +370,9 @@ def _sgd_kernel(tof_ref, cof_ref, ids_ref, grads_ref, hp_ref, table_ref,
 
 
 def _adagrad_kernel(tof_ref, cof_ref, ids_ref, grads_ref, hp_ref, table_ref,
-                    accum_ref, out_t_ref, out_a_ref, acc_ref, *, tile: int,
-                    g_count: int, eps: float):
-    g = pl.program_id(0)
-    t, first, last = _flags(tof_ref, g, g_count)
-    oh = _onehot(ids_ref[0, :], t * tile, tile)
-    part = lax.dot_general(oh, grads_ref[:].astype(jnp.float32),
-                           (((1,), (0,)), ((), ())),
-                           precision=lax.Precision.HIGHEST,
-                           preferred_element_type=jnp.float32)
-
-    @pl.when(first)
-    def _():
-        acc_ref[:] = part
-
-    @pl.when(jnp.logical_not(first))
-    def _():
-        acc_ref[:] = acc_ref[:] + part
+                    accum_ref, out_t_ref, out_a_ref, acc_ref, *, eps: float,
+                    **walk):
+    last = _tile_total(tof_ref, cof_ref, ids_ref, grads_ref, acc_ref, **walk)
 
     @pl.when(last)
     def _():
@@ -293,65 +381,88 @@ def _adagrad_kernel(tof_ref, cof_ref, ids_ref, grads_ref, hp_ref, table_ref,
         gs = acc_ref[:]
         a_new = accum_ref[:].astype(jnp.float32) + fp_round(gs * gs, zero)
         out_a_ref[:] = a_new.astype(out_a_ref.dtype)
-        # untouched rows: gs == 0 -> delta == 0, accumulator unchanged
+        # untouched rows: gs == 0 -> the accumulator as read, and a zero
+        # delta whatever rsqrt makes of an accumulator of zero
+        delta = jnp.where(gs != 0.0, fp_round(lr * gs * lax.rsqrt(a_new + eps),
+                                              zero), 0.0)
         out_t_ref[:] = (table_ref[:].astype(jnp.float32)
-                        - fp_round(lr * gs * lax.rsqrt(a_new + eps),
-                                   zero)).astype(out_t_ref.dtype)
+                        - delta).astype(out_t_ref.dtype)
 
 
 def _update_call(kernel, n_out, table, extra_tables, sid, rows, hp,
-                 chunk: int, tile: int, interpret, extra_scratch=()):
+                 chunk: Optional[int], tile: Optional[int], interpret,
+                 extra_scratch=(), lanes: Optional[bool] = None):
     """Shared pallas_call builder for the tile-major update kernels.
     extra_tables: additional [V, w] state arrays (adagrad accumulator,
     adam moments); extra_scratch: VMEM scratch beyond the grad
-    accumulator (adam's touched-count column)."""
+    accumulator (adam's touched-count column). `lanes`: walk the state
+    arrays and the stream transposed, rows on the lanes (see above; None:
+    wherever the chip stores the table so). chunk / tile of None: the
+    orientation's own (`_walk_blocks`)."""
     vocab, width = table.shape
-    kids, pad_rows, c_first, c_last, n_chunks = _chunk_layout(
-        sid, vocab, chunk, tile)
+    n = sid.shape[0]
+    if lanes is None:
+        lanes = width < ROW_MAJOR_WIDTH
+    chunk, tile = _walk_blocks(vocab, n, chunk, tile, lanes)
+    n_chunks = -(-n // chunk)
+    # whole chunks plus one all-filler chunk: pairs that place nothing
+    # point there
+    pad = (n_chunks + 1) * chunk - n
+    sid = jnp.concatenate([sid, jnp.full((pad,), vocab, jnp.int32)])
+    # 3-D so a one-chunk block's trailing dims EQUAL the array's, the only
+    # tiling-legal form of a single-sublane block on the chip
+    kids = jnp.where(sid < vocab, sid, -1).reshape(n_chunks + 1, 1, chunk)
     rows = jnp.concatenate(
-        [rows.astype(jnp.float32),
-         jnp.zeros((pad_rows - rows.shape[0], width), jnp.float32)])
+        [rows.astype(jnp.float32), jnp.zeros((pad, width), jnp.float32)])
     n_tiles = -(-vocab // tile)
-    tof, cof = _tile_major_pairs(c_first, c_last, n_tiles, n_chunks)
+    tof, cof = _tile_major_pairs(sid, vocab, n_tiles, n_chunks, chunk, tile)
     g_count = n_tiles + n_chunks
     tables = [table, *extra_tables]
-    n_tab = len(tables)
+    if lanes:
+        rows = rows.T
+        tables = [t.T for t in tables]
+
+    def block(size: int, walk: int) -> pl.BlockSpec:
+        """`size` rows of a [rows, width] array (its transpose with
+        `lanes`), chosen by scalar-prefetch array `walk` (0 tof, 1 cof)."""
+        if lanes:
+            return pl.BlockSpec((width, size),
+                                lambda g, *of: (0, of[walk][g]),
+                                memory_space=pltpu.VMEM)
+        return pl.BlockSpec((size, width), lambda g, *of: (of[walk][g], 0),
+                            memory_space=pltpu.VMEM)
+
+    out_specs = [block(tile, 0) for _ in range(n_out)]
+    out_shape = [jax.ShapeDtypeStruct(t.shape, t.dtype)
+                 for t in tables[:n_out]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(g_count,),
         in_specs=[
             _chunk_spec(chunk),
-            pl.BlockSpec((chunk, width), lambda g, tof, cof: (cof[g], 0),
-                         memory_space=pltpu.VMEM),
+            block(chunk, 1),
             pl.BlockSpec(hp.shape, lambda g, tof, cof: (0, 0),
                          memory_space=pltpu.SMEM),
-        ] + [
-            pl.BlockSpec((tile, width), lambda g, tof, cof: (tof[g], 0),
-                         memory_space=pltpu.VMEM)
-            for _ in range(n_tab)
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, width), lambda g, tof, cof: (tof[g], 0),
-                         memory_space=pltpu.VMEM)
-            for _ in range(n_tab)
-        ][:n_out] if n_out > 1 else pl.BlockSpec(
-            (tile, width), lambda g, tof, cof: (tof[g], 0),
-            memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((tile, width), jnp.float32),
-                        *extra_scratch],
+        ] + [block(tile, 0) for _ in tables],
+        out_specs=out_specs if n_out > 1 else out_specs[0],
+        scratch_shapes=[
+            pltpu.VMEM((width, tile) if lanes else (tile, width),
+                       jnp.float32), *extra_scratch],
     )
-    out_shape = [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tables]
-    out_shape = out_shape[:n_out] if n_out > 1 else out_shape[0]
     # operand indices include the 2 prefetch args: ids=2, rows=3, hp=4,
     # tables start at 5
     aliases = {5 + i: i for i in range(n_out)}
-    return pl.pallas_call(
-        functools.partial(kernel, tile=tile, g_count=g_count),
+    out = pl.pallas_call(
+        functools.partial(kernel, tile=tile, g_count=g_count,
+                          n_chunks=n_chunks, lanes=lanes),
         grid_spec=grid_spec,
-        out_shape=out_shape,
+        out_shape=out_shape if n_out > 1 else out_shape[0],
         input_output_aliases=aliases,
         interpret=_interpret_default(interpret),
     )(tof, cof, kids, rows, hp, *tables)
+    if not lanes:
+        return out
+    return [o.T for o in out] if n_out > 1 else out.T
 
 
 def _shrink(vocab: int, n: int, chunk: int, tile: int):
@@ -359,6 +470,18 @@ def _shrink(vocab: int, n: int, chunk: int, tile: int):
     tile = min(tile, max(8, -(-vocab // 8) * 8))
     chunk = min(chunk, max(128, -(-n // 128) * 128))
     return chunk, tile
+
+
+def _walk_blocks(vocab: int, n: int, chunk: Optional[int],
+                 tile: Optional[int], lanes: bool):
+    """(chunk, tile) of an update walk: the caller's where given, else the
+    orientation's defaults, clamped for small problems. Rows on lanes the
+    tile is the block's lane extent: a multiple of 128."""
+    if not lanes:
+        return _shrink(vocab, n, chunk or _CHUNK, tile or _TILE)
+    auto = lane_blocks(vocab, n) or (_LANE_CHUNK, _LANE_TILES[-1])
+    return (min(chunk or auto[0], -(-n // 128) * 128),
+            min(tile or auto[1], -(-vocab // 128) * 128))
 
 
 def _hp_with_pin(ids, lr, *extra):
@@ -388,7 +511,7 @@ def _sorted_stream(ids, contribs, vocab: int, presorted):
 
 
 def tiled_sgd(table: jax.Array, ids: jax.Array, contribs: jax.Array, lr,
-              chunk: int = _CHUNK, tile: int = _TILE,
+              chunk: Optional[int] = None, tile: Optional[int] = None,
               interpret: Optional[bool] = None,
               presorted=None) -> jax.Array:
     """table[ids] -= lr * contribs with duplicate aggregation in-kernel.
@@ -396,7 +519,6 @@ def tiled_sgd(table: jax.Array, ids: jax.Array, contribs: jax.Array, lr,
     carry this id stream's (sid, perm) from a prior `_sort_ids`."""
     if ids.shape[0] == 0:
         return table
-    chunk, tile = _shrink(table.shape[0], ids.shape[0], chunk, tile)
     sid, rows = _sorted_stream(ids, contribs, table.shape[0], presorted)
     hp = _hp_with_pin(sid, lr)
     return _update_call(_sgd_kernel, 1, table, [], sid, rows, hp,
@@ -405,7 +527,7 @@ def tiled_sgd(table: jax.Array, ids: jax.Array, contribs: jax.Array, lr,
 
 def tiled_adagrad(table: jax.Array, accum: jax.Array, ids: jax.Array,
                   contribs: jax.Array, lr, eps: float = 1e-10,
-                  chunk: int = _CHUNK, tile: int = _TILE,
+                  chunk: Optional[int] = None, tile: Optional[int] = None,
                   interpret: Optional[bool] = None, presorted=None):
     """Fused row-wise adagrad with in-kernel duplicate aggregation:
         total[r]  = sum of contribs rows for r
@@ -414,7 +536,6 @@ def tiled_adagrad(table: jax.Array, accum: jax.Array, ids: jax.Array,
     tolerance. lr may be traced; eps is static."""
     if ids.shape[0] == 0:
         return table, accum
-    chunk, tile = _shrink(table.shape[0], ids.shape[0], chunk, tile)
     sid, rows = _sorted_stream(ids, contribs, table.shape[0], presorted)
     hp = _hp_with_pin(sid, lr)
     out = _update_call(functools.partial(_adagrad_kernel, eps=eps), 2,
@@ -424,13 +545,15 @@ def tiled_adagrad(table: jax.Array, accum: jax.Array, ids: jax.Array,
 
 def _adam_kernel(tof_ref, cof_ref, ids_ref, grads_ref, hp_ref, table_ref,
                  mu_ref, nu_ref, out_t_ref, out_mu_ref, out_nu_ref, acc_ref,
-                 cnt_ref, *, tile: int, g_count: int, b1: float, b2: float,
-                 eps: float):
+                 cnt_ref, *, tile: int, g_count: int, n_chunks: int,
+                 lanes: bool, b1: float, b2: float, eps: float):
     """Lazy row-wise adam (sparse_update.sparse_adam semantics): moments
     decay ONLY on touched rows, so the kernel also accumulates a per-row
     id count (one extra all-ones matmul column) to build the touched mask
     — a zero gradient SUM on a touched row must still decay its moments,
-    so `sum != 0` is not a usable mask."""
+    so `sum != 0` is not a usable mask. Row-major at every width (the
+    count is a column of the tile): no cell runs adam."""
+    del n_chunks, lanes     # a filler pair's one-hot is zero: no skip here
     g = pl.program_id(0)
     t, first, last = _flags(tof_ref, g, g_count)
     oh = _onehot(ids_ref[0, :], t * tile, tile)
@@ -475,10 +598,21 @@ def _adam_kernel(tof_ref, cof_ref, ids_ref, grads_ref, hp_ref, table_ref,
                         + delta).astype(out_t_ref.dtype)
 
 
+def _adam_call(table, mu, nu, sid, rows, hp, chunk, tile, interpret, **hyper):
+    """The adam walk: row-major at every width, so the block sizes are
+    fixed here, where the count column's scratch takes the tile's."""
+    chunk, tile = _walk_blocks(table.shape[0], sid.shape[0], chunk, tile,
+                               lanes=False)
+    return _update_call(
+        functools.partial(_adam_kernel, **hyper), 3, table, [mu, nu], sid,
+        rows, hp, chunk, tile, interpret, lanes=False,
+        extra_scratch=[pltpu.VMEM((tile, 1), jnp.float32)])
+
+
 def tiled_adam(table: jax.Array, mu: jax.Array, nu: jax.Array, count,
                ids: jax.Array, contribs: jax.Array, lr, b1: float = 0.9,
-               b2: float = 0.999, eps: float = 1e-8, chunk: int = _CHUNK,
-               tile: int = _TILE, interpret: Optional[bool] = None,
+               b2: float = 0.999, eps: float = 1e-8, chunk: Optional[int] = None,
+               tile: Optional[int] = None, interpret: Optional[bool] = None,
                presorted=None):
     """Fused lazy row-wise adam with in-kernel duplicate aggregation;
     matches sparse_update.sparse_adam (touched rows decay, bias correction
@@ -491,13 +625,10 @@ def tiled_adam(table: jax.Array, mu: jax.Array, nu: jax.Array, count,
     cf = count.astype(jnp.float32)
     c1 = 1.0 - lax.pow(jnp.float32(b1), cf)
     c2 = 1.0 - lax.pow(jnp.float32(b2), cf)
-    chunk, tile = _shrink(table.shape[0], ids.shape[0], chunk, tile)
     sid, rows = _sorted_stream(ids, contribs, table.shape[0], presorted)
     hp = _hp_with_pin(sid, lr, c1, c2)
-    out = _update_call(
-        functools.partial(_adam_kernel, b1=b1, b2=b2, eps=eps), 3,
-        table, [mu, nu], sid, rows, hp, chunk, tile, interpret,
-        extra_scratch=[pltpu.VMEM((tile, 1), jnp.float32)])
+    out = _adam_call(table, mu, nu, sid, rows, hp, chunk, tile, interpret,
+                     b1=b1, b2=b2, eps=eps)
     return out[0], out[1], out[2], count
 
 
@@ -732,15 +863,12 @@ _tiled_lookup.defvjp(_tiled_lookup_fwd, _tiled_lookup_bwd)
 # rep stream is canonical-sorted by dedup_sum's contract, so no sort
 # happens here: the forward's folded GroupSort is the only sort in the
 # step. Dispatch + gates live in sparse_update behind
-# DET_SCATTER_IMPL=pallas.
+# DET_SCATTER_IMPL=pallas; `tiled_adagrad_rows` alone is also what
+# sparse_adagrad's sort branch takes, unasked, for a narrow table on a
+# TPU (`sparse_update._tile_stream`).
 # --------------------------------------------------------------------------
-def _rows_prep(table, rep, sums, chunk: int, tile: int):
-    chunk, tile = _shrink(table.shape[0], rep.shape[0], chunk, tile)
-    return rep.astype(jnp.int32), sums, chunk, tile
-
-
 def tiled_sgd_rows(table: jax.Array, rep: jax.Array, sums: jax.Array, lr,
-                   chunk: int = _CHUNK, tile: int = _TILE,
+                   chunk: Optional[int] = None, tile: Optional[int] = None,
                    interpret: Optional[bool] = None) -> jax.Array:
     """table[rep] -= lr * sums for a canonical-sorted UNIQUE `rep` stream
     (dedup_sum 'sort' output; fillers >= table rows are dropped).
@@ -749,7 +877,7 @@ def tiled_sgd_rows(table: jax.Array, rep: jax.Array, sums: jax.Array, lr,
     (SMEM scalar)."""
     if rep.shape[0] == 0:
         return table
-    rep, sums, chunk, tile = _rows_prep(table, rep, sums, chunk, tile)
+    rep = rep.astype(jnp.int32)
     hp = _hp_with_pin(rep, lr)
     return _update_call(_sgd_kernel, 1, table, [], rep, sums, hp,
                         chunk, tile, interpret)
@@ -757,7 +885,7 @@ def tiled_sgd_rows(table: jax.Array, rep: jax.Array, sums: jax.Array, lr,
 
 def tiled_adagrad_rows(table: jax.Array, accum: jax.Array, rep: jax.Array,
                        sums: jax.Array, lr, eps: float = 1e-10,
-                       chunk: int = _CHUNK, tile: int = _TILE,
+                       chunk: Optional[int] = None, tile: Optional[int] = None,
                        interpret: Optional[bool] = None):
     """Fused adagrad over deduped rows — one RMW stream reads and writes
     each touched table+accumulator tile once:
@@ -767,7 +895,7 @@ def tiled_adagrad_rows(table: jax.Array, accum: jax.Array, rep: jax.Array,
     placement, same expression grouping). Returns (table', accum')."""
     if rep.shape[0] == 0:
         return table, accum
-    rep, sums, chunk, tile = _rows_prep(table, rep, sums, chunk, tile)
+    rep = rep.astype(jnp.int32)
     hp = _hp_with_pin(rep, lr)
     out = _update_call(functools.partial(_adagrad_kernel, eps=eps), 2,
                        table, [accum], rep, sums, hp, chunk, tile,
@@ -778,7 +906,7 @@ def tiled_adagrad_rows(table: jax.Array, accum: jax.Array, rep: jax.Array,
 def tiled_adam_rows(table: jax.Array, mu: jax.Array, nu: jax.Array, count,
                     rep: jax.Array, sums: jax.Array, lr, b1: float = 0.9,
                     b2: float = 0.999, eps: float = 1e-8,
-                    chunk: int = _CHUNK, tile: int = _TILE,
+                    chunk: Optional[int] = None, tile: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """Fused lazy adam over deduped rows (sparse_update.sparse_adam's
     touched-row semantics, bit-identical to its 'sort' path): the
@@ -791,12 +919,10 @@ def tiled_adam_rows(table: jax.Array, mu: jax.Array, nu: jax.Array, count,
     # exact expression twin of sparse_adam's bias correction
     c1 = 1.0 - b1 ** cf
     c2 = 1.0 - b2 ** cf
-    rep, sums, chunk, tile = _rows_prep(table, rep, sums, chunk, tile)
+    rep = rep.astype(jnp.int32)
     hp = _hp_with_pin(rep, lr, c1, c2)
-    out = _update_call(
-        functools.partial(_adam_kernel, b1=b1, b2=b2, eps=eps), 3,
-        table, [mu, nu], rep, sums, hp, chunk, tile, interpret,
-        extra_scratch=[pltpu.VMEM((tile, 1), jnp.float32)])
+    out = _adam_call(table, mu, nu, rep, sums, hp, chunk, tile, interpret,
+                     b1=b1, b2=b2, eps=eps)
     return out[0], out[1], out[2], count
 
 

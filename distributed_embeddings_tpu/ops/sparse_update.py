@@ -89,14 +89,17 @@ DEDUP_FLAGS = {"unique_indices": True, "indices_are_sorted": True}
 
 
 # ------------- kernel dispatch + the compiled check
-# A kernel family is selected by request alone (DET_SCATTER_IMPL /
+# A kernel family is selected by request (DET_SCATTER_IMPL /
 # DET_LOOKUP_PATH / an explicit strategy=): what was asked for runs, and a
 # kernel the chip's compiler refuses stops the program with the compiler's
-# own error. Nothing here falls back to another path. On a TPU backend the
-# step/layer factories also run each requested family ONCE per width
-# class, eagerly and compiled, against its XLA formulation
-# (`prevalidate_active_impl`) and raise on a mismatch; off-TPU the kernels
-# run in interpret mode and the test suite is that check.
+# own error. Nothing here falls back to another path. With no request,
+# one kernel runs by what the code sees: on a TPU, adagrad's sort branch
+# over a table the chip stores column-major hands `dedup_sum`'s output to
+# the fused family's tile stream (`_tile_stream`, ISSUE 33). On a TPU
+# backend the step/layer factories also run each family they will
+# dispatch to ONCE per width class, eagerly and compiled, against its XLA
+# formulation (`prevalidate_active_impl`) and raise on a mismatch; off-TPU
+# the kernels run in interpret mode and the test suite is that check.
 def _width_class(width: int) -> int:
     """Pow2 lane-width shape-class for the compiled checks: the compiled
     form of a BlockSpec kernel depends on the lane padding of its width,
@@ -150,10 +153,7 @@ def _validate_tiled(width: int) -> bool:
     t2, a2 = ptl.tiled_adagrad(table, acc, ids, delta, 0.05,
                                interpret=False)
     rep, sums = dedup_sum(ids, delta, sentinel=v)
-    a_want = acc.at[rep].add(sums * sums, mode="drop", **DEDUP_FLAGS)
-    d_want = -0.05 * sums * lax.rsqrt(
-        jnp.take(a_want, jnp.minimum(rep, v - 1), axis=0) + 1e-10)
-    t_want = table.at[rep].add(d_want, mode="drop", **DEDUP_FLAGS)
+    t_want, a_want = _adagrad_rows_xla(table, acc, rep, sums, 0.05, 1e-10)
     ok = ok and _close(a2, a_want, 1e-3) and _close(t2, t_want, 1e-3)
     g3 = ptl.tiled_gather(table, ids, interpret=False)
     ok = ok and _close(g3, jnp.take(table, ids, axis=0), 1e-4)
@@ -212,10 +212,7 @@ def _validate_pallas_fused(width: int) -> bool:
     acc = jnp.full((v, w), 0.1, jnp.float32)
     t2, a2 = ptl.tiled_adagrad_rows(table, acc, rep, sums, 0.05,
                                     interpret=False)
-    a_want = acc.at[rep].add(sums * sums, mode="drop", **DEDUP_FLAGS)
-    d_want = -0.05 * sums * lax.rsqrt(
-        jnp.take(a_want, jnp.minimum(rep, v - 1), axis=0) + 1e-10)
-    t_want = table.at[rep].add(d_want, mode="drop", **DEDUP_FLAGS)
+    t_want, a_want = _adagrad_rows_xla(table, acc, rep, sums, 0.05, 1e-10)
     ok = ok and _close(a2, a_want, 1e-4) and _close(t2, t_want, 1e-4)
     mu = jnp.zeros((v, w), jnp.float32)
     nu = jnp.zeros((v, w), jnp.float32)
@@ -236,6 +233,32 @@ def _validate_pallas_fused(width: int) -> bool:
     return ok and _close(got_f, want_f, 1e-3)
 
 
+def _validate_tile_stream(width: int) -> bool:
+    """Compiled correctness of the one kernel a default path runs:
+    `pallas_tiled.tiled_adagrad_rows`, rows on the lanes, against the XLA
+    lines it stands in for in `sparse_adagrad`, over several tiles and as
+    one program (one compile, kept by the persistent cache)."""
+    import numpy as np
+    from distributed_embeddings_tpu.ops import pallas_tiled as ptl
+    rng = np.random.RandomState(0)
+    v, n = 20_000, 4096
+
+    @jax.jit
+    def gaps(table, acc, ids, delta):
+        rep, sums = dedup_sum(ids, delta, sentinel=v)
+        got = ptl.tiled_adagrad_rows(table, acc, rep, sums, 0.05,
+                                     interpret=False)
+        want = _adagrad_rows_xla(table, acc, rep, sums, 0.05, 1e-10)
+        return [jnp.max(jnp.abs(g - w)) for g, w in zip(got, want)]
+
+    table_gap, acc_gap = gaps(
+        jnp.asarray(rng.randn(v, width).astype(np.float32)),
+        jnp.full((v, width), 0.1, jnp.float32),
+        jnp.asarray(rng.randint(0, v, n).astype(np.int32)),
+        jnp.asarray(rng.randn(n, width).astype(np.float32)))
+    return bool(table_gap < 1e-5) and bool(acc_gap < 1e-5)
+
+
 # 'pallas' names the fused deduped-row tile-walk strategy (ISSUE 12); the
 # per-row DMA RMW kernels (ops/pallas_scatter.py) are 'pallas-dma'
 _TILED_CHECK = _KernelCheck(_validate_tiled, "DET_SCATTER_IMPL=tiled")
@@ -243,6 +266,9 @@ _PALLAS_DMA_CHECK = _KernelCheck(_validate_pallas_scatter,
                                  "DET_SCATTER_IMPL=pallas-dma")
 _PALLAS_FUSED_CHECK = _KernelCheck(_validate_pallas_fused,
                                    "DET_SCATTER_IMPL=pallas")
+# the fused family's one member on a default path (see `_tile_stream`)
+_TILE_STREAM_CHECK = _KernelCheck(_validate_tile_stream,
+                                  "sparse_adagrad's tile stream")
 
 
 def prevalidate_tiled(width: int = 16) -> bool:
@@ -285,29 +311,64 @@ def _scatter_route(strategy: str) -> str:
     return "xla"
 
 
+def _lane_width(width: int) -> bool:
+    """A width the chip stores column-major (`f32[V,w]{0,1:T(8,128)}`, so
+    that `table.T` is a bitcast): under 128 lanes, whole sublanes."""
+    from distributed_embeddings_tpu.ops import pallas_tiled as ptl
+    return width < ptl.ROW_MAJOR_WIDTH and width % 8 == 0
+
+
+def _tile_stream(strategy: str, rows: int, width: int, n: int) -> bool:
+    """Does `sparse_adagrad` hand `dedup_sum`'s output over n id slots to
+    the Pallas tile stream (`pallas_tiled.tiled_adagrad_rows`, rows on
+    the lanes) and not to its XLA scatter lines? By what the code sees,
+    never by a request: a TPU, a table that takes the sort branch and is
+    stored column-major there (a row scatter into it costs ~100 ns a row,
+    the one thing the CPU and a row-major wide table do well), a pair
+    walk that fits the chip's scalar memory. An explicit strategy="sort"
+    keeps the XLA lines: it is the reference the kernels are held to."""
+    if strategy != "auto" or _scatter_route(strategy) != "xla":
+        return False
+    if jax.default_backend() != "tpu" or not _lane_width(width):
+        return False
+    if _pick(strategy, rows, width) != "sort":
+        return False
+    from distributed_embeddings_tpu.ops import pallas_tiled as ptl
+    return ptl.lane_blocks(rows, n) is not None
+
+
 def gate_verdicts() -> dict:
     """{impl: verdict} for the ``kernels/gate_verdict{impl=}`` obs gauge:
     1 = the family's compiled check ran and passed in this process,
-    -1 = it never ran (off-TPU interpret mode, or never requested). A
-    failed check raises, so no process lives to report one."""
+    -1 = it never ran (off-TPU interpret mode, or nothing dispatches to
+    it). A failed check raises, so no process lives to report one."""
     return {"tiled": 1 if _TILED_CHECK.validated else -1,
             "pallas-dma": 1 if _PALLAS_DMA_CHECK.validated else -1,
-            "pallas": 1 if _PALLAS_FUSED_CHECK.validated else -1}
+            "pallas": 1 if (_PALLAS_FUSED_CHECK.validated
+                            or _TILE_STREAM_CHECK.validated) else -1}
 
 
-def active_scatter_impl(strategy: str = "auto") -> str:
-    """Which update family a step traced now dispatches to — the obs
-    label for the per-strategy update-phase span."""
+def active_scatter_impl(strategy: str = "auto", kind: Optional[str] = None,
+                        rows: int = 0, width: int = 0, n: int = 0) -> str:
+    """Which update family a step traced now dispatches to for a
+    [rows, width] table under optimizer `kind` and a stream of n id
+    slots — the obs label for the per-strategy update-phase span. With
+    no shape it answers for the request alone."""
+    if kind == "adagrad" and _tile_stream(strategy, rows, width, n):
+        return "pallas"
     return _scatter_route(strategy)
 
 
 def prevalidate_active_impl(strategy: Optional[str] = None,
-                            widths=None) -> None:
+                            widths=None, kind: Optional[str] = None) -> None:
     """Eagerly run the compiled check of whichever kernel family the env
-    knobs (or an explicit strategy= argument) select, once per width
-    class, before a train step is traced. A no-op for the XLA default and
-    off-TPU. Wired into make_sparse_train_step and DistributedEmbedding
-    construction, so user code need not call it.
+    knobs (or an explicit strategy= argument) select, and of the tile
+    stream adagrad's default path takes at the widths the chip stores
+    column-major (`kind`: the sparse optimizer about to be built), once
+    per width class, before a train step is traced. A no-op off-TPU and
+    where nothing dispatches to a kernel. Wired into
+    make_sparse_train_step and DistributedEmbedding construction, so user
+    code need not call it.
 
     `widths`: the table lane widths the caller will dispatch at (the
     layer/step factories pass their plan's bucket+row widths); None
@@ -334,6 +395,16 @@ def prevalidate_active_impl(strategy: Optional[str] = None,
     for check in checks:
         for w in sorted({_width_class(w) for w in widths}):
             check.prevalidate(w)
+    # the default path's own kernel: `_tile_stream` asks the same of the
+    # request; rows and id counts are the trace's to know. It runs where
+    # a chip is attached to run it on: a step compiled for a described
+    # chip (tests/test_chip_compile.py, benchmark.tools.describe_chip)
+    # has the backend answered for it and no device
+    if (kind == "adagrad" and strategy in (None, "auto")
+            and _scatter_route("auto") == "xla"
+            and jax.devices()[0].platform == "tpu"):
+        for w in sorted({_width_class(w) for w in widths if _lane_width(w)}):
+            _TILE_STREAM_CHECK.prevalidate(w)
 
 
 def _static_float(x):
@@ -658,6 +729,13 @@ def sparse_adagrad(table: jax.Array, accum: jax.Array, grad: SparseRowGrad,
         return t_new, acc_new
     rep, sums = dedup_sum(grad.ids, grad.contribs, sentinel=rows,
                           presorted=ps)
+    if _tile_stream(strategy, rows, table.shape[-1], rep.shape[0]):
+        # one in-place stream over the table and its accumulator as the
+        # chip stores them, rows on the lanes: each tile read and written
+        # once (ISSUE 33; 47 ms at Tiny V3's bucket where the lines below
+        # take 673: PERF.md section 6, PR 33)
+        from distributed_embeddings_tpu.ops import pallas_tiled as ptl
+        return ptl.tiled_adagrad_rows(table, accum, rep, sums, lr, eps=eps)
     lr_static = _static_float(lr)
     if _scatter_env("pallas-dma") and lr_static is not None:
         # fused RMW stream: one pass reads+updates table and accumulator
@@ -667,6 +745,14 @@ def sparse_adagrad(table: jax.Array, accum: jax.Array, grad: SparseRowGrad,
         from distributed_embeddings_tpu.ops import pallas_scatter as ps
         return ps.adagrad_rows_sorted_unique(table, accum, rep, sums,
                                              lr_static, eps)
+    return _adagrad_rows_xla(table, accum, rep, sums, lr, eps)
+
+
+def _adagrad_rows_xla(table, accum, rep, sums, lr, eps):
+    """Adagrad over `dedup_sum`'s output as two row scatter-adds and the
+    accumulator's re-read between them: the CPU's path, a wide table's,
+    and what every kernel that takes (rep, sums) is held to."""
+    rows = table.shape[0]
     # rep is strictly increasing (dedup_sum contract) => both scatter
     # promises hold; without them XLA's duplicate-safe lowering costs
     # ~100-280 ns/row on TPU (round-3 prims measurement)

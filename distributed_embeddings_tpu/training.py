@@ -202,10 +202,10 @@ def _sparse_optimizer_setup(optimizer: str, lr, strategy: str,
     # model)
     sparse_hp = {"adagrad": {"eps": 1e-7}, "adam": {}, "sgd": {}}[optimizer]
     scheduled = callable(lr)
-    # eagerly validate any DET_SCATTER_IMPL kernel choice on the attached
-    # chip now — inside the traced step only the cached verdict is
-    # consulted, so without this call the env knob would be silently inert
-    prevalidate_active_impl(strategy=strategy, widths=widths)
+    # eagerly check, compiled on the attached chip, every kernel the step
+    # will dispatch to: a DET_SCATTER_IMPL choice, and the tile stream
+    # adagrad takes by default at the widths the chip stores column-major
+    prevalidate_active_impl(strategy=strategy, widths=widths, kind=optimizer)
     sopt = make_sparse_optimizer(optimizer, 0.0 if scheduled else lr,
                                  strategy=strategy, **sparse_hp)
     if dense_optimizer is None:
@@ -711,14 +711,19 @@ def fit(model, params, data, steps: int, optimizer: str = "adagrad",
     # per-strategy update-phase attribution (ISSUE 12): the step span
     # gains a nested span whose PATH names the sparse-update kernel
     # family the traced step dispatches to (xla/tiled/pallas — resolved
-    # once, from the env knobs + cached gate verdicts), so snapshots and
-    # the soak harness can see WHICH path actually ran. Like train/step
-    # itself this times the host-side dispatch; the count/label is the
-    # signal, not the duration.
+    # once, from the env knobs and from what the dispatch sees of each
+    # bucket: optimizer, rows, width), so snapshots and the soak harness
+    # can see WHICH path actually ran: a kernel's label where any bucket
+    # takes one. Like train/step itself this times the host-side dispatch;
+    # the count/label is the signal, not the duration.
     if sparse:
         from distributed_embeddings_tpu.ops.sparse_update import (
             active_scatter_impl)
-        update_impl = active_scatter_impl()
+        impls = [active_scatter_impl(kind=optimizer, rows=b.rows_max,
+                                     width=b.width)
+                 for b in model.embedding.plan.tp_buckets]
+        update_impl = next((i for i in impls if i != "xla"),
+                           active_scatter_impl())
     else:
         update_impl = "dense"
     import time as _time

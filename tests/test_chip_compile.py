@@ -195,6 +195,9 @@ def _tiny_v3_adagrad_step(one_chip, monkeypatch):
         on_chip(params), on_chip(opt_state),
         S((BATCH, cfg.num_numerical_features), F32),
         [S((BATCH, h), I32) for h in hotness], S((BATCH, 1), F32)).compile()
+    # the width-16 bucket's apply is the Pallas tile stream (ISSUE 33): no
+    # other kernel is on this step's path
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
     m = compiled.memory_analysis()
     live = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
@@ -207,13 +210,63 @@ def _tiny_v3_adagrad_step(one_chip, monkeypatch):
         f"Tiny V3 step needs {live / 2**30:.2f} GiB of a 16 GB chip")
 
 
+# Tiny V3's width-16 bucket as a chip holds it (PERF.md section 4): the one
+# shape a cell runs a Pallas kernel at
+BUCKET_ROWS, BUCKET_IDS = 70_200_320, 2_883_584
+
+
+def _tiny_v3_bucket_stream(one_chip):
+    """`tiled_adagrad_rows` at the bucket's real shape, donated as the step
+    donates it: it compiles (row-major blocks made the compiler ask for a
+    36 GB copy of the table, its 16 lanes padded to 128: ISSUE 33), in
+    place, and what it keeps beside its arguments is the padded stream."""
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = S((BUCKET_ROWS, 16), F32)
+    compiled = jax.jit(
+        lambda t, a, r, s: pallas_tiled.tiled_adagrad_rows(
+            t, a, r, s, 0.01, eps=1e-7, interpret=False),
+        donate_argnums=(0, 1)).lower(
+            state, state, S((BUCKET_IDS,), I32),
+            S((BUCKET_IDS, 16), F32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 0.5 * 2 ** 30
+    assert m.alias_size_in_bytes >= 2 * BUCKET_ROWS * 16 * 4
+
+
+@pytest.mark.parametrize("backend,rows,width,n,want", [
+    ("tpu", BUCKET_ROWS, 16, BUCKET_IDS, "pallas"),
+    ("tpu", BUCKET_ROWS, 128, BUCKET_IDS, "xla"),       # a row-major table
+    ("cpu", BUCKET_ROWS, 16, BUCKET_IDS, "xla"),
+    # 33,203,125 chunks of ids alone pass the scalar memory's 130,000 pairs
+    ("tpu", BUCKET_ROWS, 16, 8_500_000_000, "xla"),
+    ("tpu", 2 ** 31 - 10 ** 6, 16, BUCKET_IDS, "xla"),  # 262,023 tiles of 8192
+])
+def test_tile_stream_selection(backend, rows, width, n, want, monkeypatch):
+    """The rule that hands adagrad's sort branch to the tile stream, by
+    what the code sees: a TPU, a table stored column-major, a pair walk
+    that fits the chip's scalar memory. Needs no chip described; it is
+    here because the bound it asserts is the one the case above compiles
+    under."""
+    from distributed_embeddings_tpu.ops import sparse_update
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert sparse_update.active_scatter_impl(
+        "auto", kind="adagrad", rows=rows, width=width, n=n) == want
+
+
 @pytest.mark.parametrize(
     "kernel,width",
-    [(k, w) for k in KERNELS for w in (16, 128)] + [("tiny_v3_step", None)],
+    [(k, w) for k in KERNELS for w in (16, 128)]
+    + [("tiny_v3_step", None), ("tiny_v3_bucket_stream", 16)],
     ids=lambda v: str(v))
 def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
     if kernel == "tiny_v3_step":
         _tiny_v3_adagrad_step(one_chip, monkeypatch)
+        return
+    if kernel == "tiny_v3_bucket_stream":
+        _tiny_v3_bucket_stream(one_chip)
         return
 
     def S(shape, dtype):

@@ -84,6 +84,74 @@ def test_rows_appliers_exact_placement():
     np.testing.assert_array_equal(np.asarray(placed), np.asarray(want))
 
 
+# the rows-on-lanes tile stream (ISSUE 33): name -> (width, rows, raw ids).
+# tile = chunk = 128 below, so that these small tables span several tiles
+# and their streams several chunks
+def _lane_cases():
+    rng = np.random.RandomState(11)
+    zipf = (rng.zipf(1.3, 700) - 1) % 1000
+    return {
+        "w16-rows-not-a-tile-multiple": (16, 1000, rng.choice(1000, 300,
+                                                              False)),
+        "w8": (8, 1000, rng.randint(0, 1000, 300)),
+        "first-and-last-row": (16, 1000, np.array([0, 999, 0, 999])),
+        "both-sides-of-a-tile-edge": (16, 640, np.array(
+            [126, 127, 128, 129, 255, 256, 383, 384, 639])),
+        "a-chunk-of-fillers-only": (16, 512, np.repeat([3, 130, 400], 128)),
+        "no-row-in-bounds": (16, 512, np.full(256, -1)),
+        "zipf-like": (16, 1000, zipf),
+        "all-distinct": (8, 2000, rng.permutation(2000)[:1024]),
+        "default-blocks": (16, 3000, rng.randint(0, 3000, 2000)),
+    }
+
+
+@pytest.mark.parametrize("case", list(_lane_cases()))
+def test_lane_stream_matches_xla_lines(case):
+    """`tiled_adagrad_rows` over a table the chip stores column-major
+    (width under 128: blocks [width, tile], rows on the lanes, the
+    gradient cut into three bfloat16 pieces for one matmul) against the
+    XLA lines of `sparse_adagrad(strategy="sort")` on the same
+    (rep, sums): table and accumulator equal bit for bit, two steps in a
+    row, and rows no id names bit-identical to what went in. (No fused
+    producer stands in front of the delta here, so not even rsqrt's
+    rounding parts the two: see `_assert_adagrad_tables_match`.)"""
+    width, rows, ids = _lane_cases()[case]
+    rng = np.random.RandomState(5)
+    ids = jnp.asarray(ids.astype(np.int32))
+    contribs = jnp.asarray(rng.randn(ids.shape[0], width).astype(np.float32))
+    table = jnp.asarray(rng.randn(rows, width).astype(np.float32))
+    accum = jnp.asarray(0.1 + rng.rand(rows, width).astype(np.float32))
+    blocks = {} if case == "default-blocks" else {"tile": 128, "chunk": 128}
+
+    @jax.jit
+    def both(table, accum, ids, contribs):
+        rep, sums = su.dedup_sum(ids, contribs, sentinel=rows)
+        return (pt.tiled_adagrad_rows(table, accum, rep, sums, 0.05,
+                                      eps=1e-7, interpret=True, **blocks),
+                su._adagrad_rows_xla(table, accum, rep, sums, 0.05, 1e-7))
+
+    got, want = both(table, accum, ids, contribs)
+    got2, want2 = both(*got, ids, contribs * 3.0)
+    for a, b in zip(got + got2, want + want2):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    named = np.zeros(rows, bool)
+    valid = np.asarray(ids)[(np.asarray(ids) >= 0) & (np.asarray(ids) < rows)]
+    named[valid] = True
+    if named.any():
+        assert (np.asarray(got[0])[named] != np.asarray(table)[named]).any()
+    for new, old in zip(got, (table, accum)):
+        np.testing.assert_array_equal(np.asarray(new)[~named],
+                                      np.asarray(old)[~named])
+
+
+def test_lane_stream_empty_stream():
+    """A stream of no slots at all returns what it was given."""
+    table = jnp.ones((300, 16), jnp.float32)
+    t, a = pt.tiled_adagrad_rows(table, table, jnp.zeros((0,), jnp.int32),
+                                 jnp.zeros((0, 16), jnp.float32), 0.05)
+    assert t is table and a is table
+
+
 def test_fused_lookup_matches_reference():
     """fused_lookup_combine == the XLA gather+einsum formulation (sum and
     mean, weighted and not) to f32 tolerance, with exact grads in params
@@ -433,6 +501,63 @@ def test_pallas_requested_env_inert_off_tpu(monkeypatch):
     assert su._scatter_route("pallas") == "pallas"
     assert su.active_scatter_impl("auto") == "xla"
     assert su.active_scatter_impl("pallas") == "pallas"
+
+
+TINY_BUCKET = dict(rows=70_200_000, width=16, n=2_883_584)
+
+
+@pytest.mark.parametrize("backend,kind,shape,want", [
+    ("tpu", "adagrad", TINY_BUCKET, "pallas"),      # Tiny V3's width-16 bucket
+    ("tpu", "adagrad", dict(rows=60_160, width=8, n=2_700_000), "xla"),  # dense
+    ("tpu", "sgd", dict(rows=11_849_058, width=128, n=106_496), "xla"),  # DLRM
+    ("tpu", "adagrad", dict(rows=11_849_058, width=128, n=106_496), "xla"),
+    ("tpu", "adagrad", dict(rows=10 ** 6, width=12, n=65_536), "xla"),
+    ("tpu", "sgd", TINY_BUCKET, "xla"),
+    ("tpu", "adam", TINY_BUCKET, "xla"),
+    ("cpu", "adagrad", TINY_BUCKET, "xla"),
+], ids=lambda v: v if isinstance(v, str) else f"w{v['width']}")
+def test_active_scatter_impl_answers_for_the_shape(backend, kind, shape,
+                                                   want, monkeypatch):
+    """`active_scatter_impl` returns what the dispatch will do for an
+    optimizer and a table's shape, not what was requested: the fused
+    family's label for the one bucket whose sort branch takes the tile
+    stream on a TPU, `xla` for a small (dense branch), a wide or an odd
+    table, for sgd and adam, and on the CPU."""
+    monkeypatch.setattr(su.jax, "default_backend", lambda: backend)
+    assert su.active_scatter_impl("auto", kind=kind, **shape) == want
+    # an explicit strategy is the request and nothing else
+    assert su.active_scatter_impl("sort", kind=kind, **shape) == "xla"
+    assert su.active_scatter_impl("pallas", kind=kind, **shape) == "pallas"
+
+
+def test_tile_stream_check_runs_where_a_chip_is_attached(monkeypatch):
+    """adagrad's default path is checked compiled before a step is traced,
+    with no variable asking for it, at the lane widths and only there; a
+    backend answered "tpu" over CPU devices (a described chip) runs
+    nothing; `gate_verdicts` shows the fused family's check passed."""
+    ran = []
+    check = su._KernelCheck(lambda cls: ran.append(cls) or True, "test")
+    monkeypatch.setattr(su, "_TILE_STREAM_CHECK", check)
+    monkeypatch.setattr(su, "_PALLAS_FUSED_CHECK",
+                        su._KernelCheck(lambda cls: True, "test"))
+    monkeypatch.setattr(su.jax, "default_backend", lambda: "tpu")
+    su.prevalidate_active_impl(strategy="auto", widths=(8, 16, 128),
+                               kind="adagrad")
+    assert ran == [] and su.gate_verdicts()["pallas"] == -1
+
+    class Chip:
+        platform = "tpu"
+
+    monkeypatch.setattr(su.jax, "devices", lambda: [Chip()])
+    su.prevalidate_active_impl(strategy="auto", widths=(8, 16, 128),
+                               kind="sgd")
+    su.prevalidate_active_impl(strategy="sort", widths=(8, 16, 128),
+                               kind="adagrad")
+    assert ran == []
+    su.prevalidate_active_impl(strategy="auto", widths=(8, 16, 128),
+                               kind="adagrad")
+    su.prevalidate_active_impl(widths=(16,), kind="adagrad")
+    assert ran == [8, 16] and su.gate_verdicts()["pallas"] == 1
 
 
 def test_gate_verdicts_shape():
