@@ -1,17 +1,16 @@
 """Least time one training step could take on a chip, from shapes and the
 chip's published peaks.
 
-The arithmetic is ``bench.py``'s (``chip_peaks``, ``roofline_step_s``,
-``dlrm_roofline_bytes_flops``), copied so that no later PR can move the
-yardstick. The embedding path is bound by HBM bandwidth: every looked-up row
-crosses HBM a fixed number of times per step, which depends on the
-optimizer:
+The arithmetic is kept here, with the benchmark, so that no later PR can
+move the yardstick. The embedding path is bound by HBM bandwidth: every
+looked-up row crosses HBM a fixed number of times per step, which depends
+on the optimizer:
 
 * sgd, 3 transfers: the forward reads the row, the update reads it and
   writes it;
 * adagrad, 7 transfers: the forward reads the row, the backward's
   scatter-add reads and writes it, and the update reads and writes both the
-  row and its accumulator (``bench.py``'s count);
+  row and its accumulator;
 * adam, 9 transfers: the forward reads the row, the backward's scatter-add
   reads and writes it, and the lazy row-wise update reads and writes the
   row and both of its moments (the same count with one more state array).

@@ -9,6 +9,11 @@ refuses, shows here at no chip time. It prints ``memory_analysis()`` per
 device and counts the collectives, sorts, scatters and gathers of the compiled
 program. A compile that passes is not a chip run and gives no time.
 
+The batch is the cell's own: the shapes and dtypes of the first batch its
+generator makes (numerical features and ``[B, 1]`` f32 clicks, or document
+boundaries and ``[T]`` int32 next ids; no data is kept), lowered through
+``step_fn.lower``, the handle of the very ``jax.jit`` a run dispatches to.
+
 The library asks ``jax.default_backend()`` where a TPU takes another branch
 than the CPU, and here it would see the CPU: this script answers "tpu" for
 it, as ``tests/test_chip_compile.py`` does. The topology is described inside
@@ -69,7 +74,6 @@ def main(argv=None) -> int:
     os.environ["JAX_PLATFORMS"] = "cpu"
 
     import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                               SingleDeviceSharding)
@@ -112,16 +116,21 @@ def main(argv=None) -> int:
                 else NamedSharding(mesh, P())),
             jax.eval_shape(init_fn, params))
 
-    def S(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=batch_sharding)
-
-    batch = built.global_batch
-    cats = [S((batch,) if built.ids_1d and h == 1 else (batch, h), jnp.int32)
-            for h in built.hotness]
+    # one batch of the cell's generator, for its shapes and dtypes alone
+    inputs, cats, labels = spec.plugin(
+        "generators", cell.traffic["generator"]).generate(
+            dict(cell.traffic, num_batches=1),
+            [(built.tables[t][0], h)
+             for t, h in zip(built.table_map, built.hotness)],
+            built.global_batch, built.num_numerical, built.numerical_scale,
+            0)[0]
+    inputs, cats, labels = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=batch_sharding),
+        (inputs, built.shape_ids(cats), labels))
     with mesh or Mesh(devices[:1], ("one",)):
-        compiled = jax.jit(step_fn, donate_argnums=(0, 1)).lower(
-            params, opt_state, S((batch, built.num_numerical), jnp.float32),
-            cats, S((batch, 1), jnp.float32)).compile()
+        compiled = step_fn.lower(params, opt_state, inputs, cats,
+                                 labels).compile()
     m = compiled.memory_analysis()
     text = compiled.as_text()
     counts = {name: len(re.findall(

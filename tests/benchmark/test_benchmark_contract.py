@@ -19,6 +19,19 @@ NAME = re.compile(r"[A-Za-z0-9][A-Za-z0-9_.\-]{0,63}$")
 LAYER = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 PATH = re.compile(r"[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+# `reduced` never names a width (model-configs, section 4; the driver's
+# contract): a hidden, intermediate, latent, state, head or projection size,
+# a window, an expansion factor, the experts per token, a key that ends in
+# `_dim` or `_rank`. A count (layers, experts held, rows of a vocabulary or
+# a table, the batch) may be the chip's share
+WIDTH = re.compile(
+    r"(_dim|_rank)$|width|window|expan|experts_per_tok"
+    r"|(hidden|intermediate|latent|state|head|proj\w*)_size")
+# reduced keys whose published value stands elsewhere in today's files, which
+# this test does not edit: dlrm-mlperf's `exchange_peers` (1 or 4) is cut
+# from `deployment.chips` (16), and `test_dlrm_share_follows_its_rule` holds
+# the two together
+PUBLISHED_ELSEWHERE = {"exchange_peers": ("deployment", "chips")}
 
 
 def _under_paths(path):
@@ -60,9 +73,15 @@ def test_configs_and_cells():
         assert _under_paths(c["file"])
         held = spec.load_json(c["file"])
         assert held["source"] == c["source"]
-        assert held["reduced"] == c["reduced"]
-        assert not any(re.search(r"(_dim|_rank|hidden|width)", k)
-                       for k in c["reduced"])
+        assert held["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
+        for key in c["reduced"]:
+            assert NAME.match(key) and not WIDTH.search(key), key
+            # what the guide asks of a cut: the published value beside it
+            if key in PUBLISHED_ELSEWHERE:
+                group, inner = PUBLISHED_ELSEWHERE[key]
+                assert inner in held[group], (c["name"], key)
+            elif key in held:
+                assert key + "_published" in held, (c["name"], key)
     cells = BENCH["workloads"]
     assert 2 <= len(cells) <= 24
     assert {w["config"] for w in cells} == {c["name"] for c in BENCH["configs"]}
@@ -77,6 +96,18 @@ def test_configs_and_cells():
         assert "setup_s" in reported and len(reported) >= 2
         assert cell.per_layer
         assert all(m["moves"] in reported for m in cell.per_layer)
+
+
+@pytest.mark.parametrize("key, is_a_width", [
+    ("hidden_size", True), ("head_dim", True), ("kv_lora_rank", True),
+    ("moe_intermediate_size", True), ("sliding_window", True),
+    ("embedding_dim", True), ("table_width", True),
+    ("num_experts_per_tok", True), ("ssm_state_size", True),
+    ("num_hidden_layers", False), ("n_routed_experts", False),
+    ("vocab_size", False), ("table_rows", False), ("global_batch", False),
+    ("exchange_peers", False)])
+def test_reduced_may_name_a_count_and_never_a_width(key, is_a_width):
+    assert bool(WIDTH.search(key)) is is_a_width
 
 
 def test_metrics():

@@ -1,8 +1,10 @@
 """A later PR adds a cell and a metric as files and entries, and edits
 nothing that is there: a scratch configuration, traffic mix, reader and
 layer metric dropped into a copy of the benchmark are found by name. So are
-a model that is no click model (a next-token model under adam), its plain
-reference, its loss and its generator."""
+a model that is no click model (a next-token model under adam with its depth
+and its vocabulary cut), its plain reference, its loss and its generator:
+by the harness, by the copy's own generic tests (what the driver runs over
+every cell) and by `describe_chip`."""
 
 import json
 import os
@@ -37,18 +39,21 @@ def read(ctx, params):
 '''
 
 # ---- a next-token model small enough for the CPU: one [T] one-hot input
-# into a DistributedEmbedding table, one dense layer, an untied head over
-# the table's rows, softmax cross-entropy against the next id, counted
-# where the next id belongs to the same document
+# into a DistributedEmbedding table, `num_hidden_layers` dense layers, an
+# untied head over the table's rows, softmax cross-entropy against the next
+# id, counted where the next id belongs to the same document. Its depth and
+# its vocabulary are cut, as a language model's are for one chip
+# (model-configs, section 4), with the published counts beside them
 LM_CONFIG = {
     "name": "scratch-lm", "source": "https://example.org/scratch-lm",
     "builder": "scratch_lm", "sample_unit": "token",
-    "vocab_size": 512, "hidden_size": 16, "intermediate_size": 32,
-    "tokens_per_step": 256,
+    "vocab_size": 512, "vocab_size_published": 4096,
+    "num_hidden_layers": 2, "num_hidden_layers_published": 8,
+    "hidden_size": 16, "intermediate_size": 32, "tokens_per_step": 256,
     "optimizer": {"kind": "adam", "lr": 0.001, "b1": 0.9, "b2": 0.999,
                   "eps": 1e-08},
     "matmul_precision": "highest", "sync_every": 2, "trace_steps": 4,
-    "reduced": [], "rehearse": {}}
+    "reduced": ["num_hidden_layers", "vocab_size"], "rehearse": {}}
 LM_TRAFFIC = {"generator": "scratch_tokens", "num_batches": 3, "skew": 2.0,
               "mean_document": 32}
 LM_BUILDER = '''"""Scratch: configuration file -> a next-token model on the repo's public
@@ -61,29 +66,32 @@ from benchmark.harness.built import Built, mlp_train_flops
 
 
 class NextToken:
-    def __init__(self, vocab, width, hidden, mesh):
+    def __init__(self, vocab, width, hidden, layers, mesh):
         from distributed_embeddings_tpu.layers.dist_model_parallel import (
             DistributedEmbedding)
         from distributed_embeddings_tpu.layers.embedding import Embedding
 
-        self.sizes = vocab, width, hidden
+        self.sizes = vocab, width, hidden, layers
         self.embedding = DistributedEmbedding([Embedding(vocab, width)],
                                               mesh=mesh)
 
     def init(self, key):
-        vocab, width, hidden = self.sizes
-        ke, kd, kh = jax.random.split(key, 3)
+        vocab, width, hidden, layers = self.sizes
+        ke, kh, *kd = jax.random.split(key, 2 + layers)
+        dims = [width] + [hidden] * layers
         return {"embedding": self.embedding.init(ke),
-                "dense": {"w": jax.random.normal(kd, (width, hidden))
-                          / width ** 0.5, "b": jnp.zeros(hidden)},
+                "dense": [{"w": jax.random.normal(k, (a, b)) / a ** 0.5,
+                           "b": jnp.zeros(b)}
+                          for k, a, b in zip(kd, dims[:-1], dims[1:])],
                 "head": jax.random.normal(kh, (hidden, vocab)) / hidden ** 0.5}
 
     def loss_fn(self, params, same_document, cats, next_ids, taps=None,
                 return_residuals=False):
         (x,), res = self.embedding(params["embedding"], list(cats), taps=taps,
                                    return_residuals=True)
-        dense = params["dense"]
-        logits = jnp.maximum(x @ dense["w"] + dense["b"], 0.0) @ params["head"]
+        for layer in params["dense"]:
+            x = jnp.maximum(x @ layer["w"] + layer["b"], 0.0)
+        logits = x @ params["head"]
         nll = (jax.nn.logsumexp(logits, axis=1)
                - jnp.take_along_axis(logits, next_ids[:, None], axis=1)[:, 0])
         loss = jnp.sum(nll * same_document) / jnp.sum(same_document)
@@ -93,9 +101,10 @@ class NextToken:
 def build(config, mesh, rehearse):
     from distributed_embeddings_tpu.training import make_sparse_train_step
 
-    vocab, width, hidden = (config["vocab_size"], config["hidden_size"],
-                            config["intermediate_size"])
-    model = NextToken(vocab, width, hidden, mesh)
+    vocab, width, hidden, layers = (
+        config["vocab_size"], config["hidden_size"],
+        config["intermediate_size"], config["num_hidden_layers"])
+    model = NextToken(vocab, width, hidden, layers, mesh)
     opt = config["optimizer"]
     return Built(
         model=model,
@@ -106,7 +115,8 @@ def build(config, mesh, rehearse):
         global_batch=config["tokens_per_step"], optimizer=opt,
         reference="scratch_lm",
         dense_params=lambda p: {"dense": p["dense"], "head": p["head"]},
-        mlp_flops_per_sample=mlp_train_flops([width, hidden, vocab]),
+        mlp_flops_per_sample=mlp_train_flops(
+            [width] + [hidden] * layers + [vocab]),
         ids_1d=True, mesh=mesh)
 '''
 LM_REFERENCE = '''"""Scratch: the next-token model's forward and loss, plainly. ``inputs``
@@ -115,13 +125,14 @@ is ``[T]`` int32, the next ids."""
 
 import jax.numpy as jnp
 
+from benchmark.reference import mlp
+
 LABEL_OFFSET = 0
 
 
 def loss(dense, embs, inputs, labels):
     (x,) = embs
-    hidden = jnp.maximum(x @ dense["dense"]["w"] + dense["dense"]["b"], 0.0)
-    logits = hidden @ dense["head"]
+    logits = mlp(dense["dense"], x, final_activation=True) @ dense["head"]
     top = jnp.max(logits, axis=1)
     log_sum = top + jnp.log(jnp.sum(jnp.exp(logits - top[:, None]), axis=1))
     labels = jnp.roll(labels, LABEL_OFFSET)
@@ -238,32 +249,49 @@ def test_the_four_chip_cell_comes_back_by_entries_alone(tmp_path):
     assert all(p.read_bytes() == data for p, data in before.items())
 
 
-@pytest.mark.parametrize("label_offset, outcome", [(0, None), (1, "(b)")])
-def test_a_next_token_model_under_adam_by_new_files_alone(
-        tmp_path, label_offset, outcome):
-    """Builder, plain reference (forward and softmax cross-entropy over the
-    vocabulary), generator (int32 [T] labels), configuration
-    (`sample_unit: token`, adam: so three held steps), traffic and two entries:
-    the check holds the system to the reference's loss and adam rule. With
-    the reference's labels one position off, the loss (b) fails."""
-    before = _copy_of_the_benchmark(tmp_path)
+def _add_the_token_cell(tmp_path, label_offset=0):
+    """The next-token model's files into the copy (none may be there), and
+    BENCHMARK.json with its three entries: the configuration with its depth
+    and vocabulary cut, the cell, a per-layer metric listed for it alone."""
     for path, text in (
             ("builders/scratch_lm.py", LM_BUILDER),
             ("references/scratch_lm.py", LM_REFERENCE.replace(
                 "LABEL_OFFSET = 0", f"LABEL_OFFSET = {label_offset}")),
             ("generators/scratch_tokens.py", LM_GENERATOR),
             ("configs/scratch-lm.json", json.dumps(LM_CONFIG)),
-            ("traffic/scratch-docs.json", json.dumps(LM_TRAFFIC))):
+            ("traffic/scratch-docs.json", json.dumps(LM_TRAFFIC)),
+            ("readers/window_steps.py", READER),
+            ("layer_metrics/scratch.steps_x10.json",
+             json.dumps({"reader": "window_steps", "times": 10}))):
         assert not (tmp_path / "benchmark" / path).exists()
         (tmp_path / "benchmark" / path).write_text(text)
     bench = spec.load_json("BENCHMARK.json")
     bench["configs"].append({
         "name": "scratch-lm", "source": LM_CONFIG["source"],
-        "file": "benchmark/configs/scratch-lm.json", "reduced": [],
-        "why": "scratch"})
+        "file": "benchmark/configs/scratch-lm.json",
+        "reduced": LM_CONFIG["reduced"], "why": "scratch"})
     bench["workloads"].append({
         "name": "scratch-lm.docs", "config": "scratch-lm",
         "traffic": "scratch-docs", "chips": 1, "why": "scratch"})
+    bench["per_layer"].append({
+        "name": "scratch.steps_x10", "unit": "steps", "better": "higher",
+        "source": "program_counter", "layer": "train_step",
+        "moves": "samples_per_s", "workloads": ["scratch-lm.docs"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    return bench
+
+
+@pytest.mark.parametrize("label_offset, outcome", [(0, None), (1, "(b)")])
+def test_a_next_token_model_under_adam_by_new_files_alone(
+        tmp_path, label_offset, outcome):
+    """Builder, plain reference (forward and softmax cross-entropy over the
+    vocabulary), generator (int32 [T] labels), configuration
+    (`sample_unit: token`, adam: so three held steps; `num_hidden_layers`
+    and `vocab_size` reduced), traffic and three entries: the check holds
+    the system to the reference's loss and adam rule. With the reference's
+    labels one position off, the loss (b) fails."""
+    before = _copy_of_the_benchmark(tmp_path)
+    bench = _add_the_token_cell(tmp_path, label_offset)
     lines = _rehearse(tmp_path, bench, "scratch-lm.docs", 0)
     check = _line(lines, "REFERENCE_CHECK")
     if outcome is None:
@@ -277,4 +305,94 @@ def test_a_next_token_model_under_adam_by_new_files_alone(
                 > check["compared"]["loss0_off"][1])
     last = json.loads(lines[-1])
     assert last["failed"] == 0 and last["attempted"] > 0
+    assert all(p.read_bytes() == data for p, data in before.items())
+
+
+def _copy_of_a_checkout(tmp_path):
+    """The benchmark's two directories copied, what else a checkout holds
+    for them (the system under test, the example the DLRM share is held to)
+    linked -> the copied files' bytes."""
+    before = _copy_of_the_benchmark(tmp_path)
+    shutil.copytree(os.path.join(spec.ROOT, "tests", "benchmark"),
+                    tmp_path / "tests" / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before.update({p: p.read_bytes()
+                   for p in (tmp_path / "tests").rglob("*") if p.is_file()})
+    for name in ("distributed_embeddings_tpu", "examples"):
+        os.symlink(os.path.join(spec.ROOT, name), tmp_path / name)
+    return before
+
+
+def _in_the_copy(tmp_path, *argv, timeout=900):
+    env = {k: v for k, v in os.environ.items() if not k.startswith("DET_")}
+    env.update(PYTHONPATH=str(tmp_path), JAX_PLATFORMS="cpu")
+    return subprocess.run([sys.executable, *argv], cwd=tmp_path, env=env,
+                          capture_output=True, text=True, timeout=timeout)
+
+
+def test_the_copys_own_tests_hold_the_token_cell(tmp_path):
+    """What the driver runs on every PR: the copy's own `tests/benchmark`
+    over the cell it gained. The contract's tests take a configuration whose
+    `reduced` names `num_hidden_layers` (a count, not a width) beside its
+    published value, and the rehearsal's case of the new cell expects the
+    losses the check holds under adam: `loss0_off`, `loss1_off`,
+    `loss2_off`. PRs 29 and 30 each lost a cell that ran `correct` on the
+    chip to these two tests (PERF.md section 6, PR 34)."""
+    before = _copy_of_a_checkout(tmp_path)
+    _add_the_token_cell(tmp_path)
+    done = _in_the_copy(
+        tmp_path, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+        "tests/benchmark/test_benchmark_contract.py",
+        "tests/benchmark/test_benchmark_rehearse.py",
+        "-k", "test_benchmark_contract or scratch-lm.docs")
+    assert done.returncode == 0, done.stdout[-6000:] + done.stderr[-2000:]
+    # the contract's file whole and the one rehearsal: none skipped or lost
+    ran = done.stdout.strip().splitlines()[-1]
+    assert " passed" in ran and "skipped" not in ran, ran
+    assert all(p.read_bytes() == data for p, data in before.items())
+
+
+# ---- `describe_chip` describes the cell's own batch (PR 34). A topology
+# may be described in one test file alone (`tests/test_chip_compile.py`:
+# one process at a time holds the TPU's library), so here the local CPU
+# devices stand in for the described ones: the shapes the tool lowers, the
+# handle it lowers through and what it prints are the same code
+STAND_IN = (
+    "import sys, types\n"
+    "import jax\n"
+    "from jax.experimental import topologies\n"
+    "topologies.get_topology_desc = lambda **kw: types.SimpleNamespace(\n"
+    "    devices=jax.devices('cpu'))\n"
+    "from benchmark.tools import describe_chip\n"
+    "sys.exit(describe_chip.main(['--workload', sys.argv[1]]))\n")
+
+
+def _described(tmp_path, workload):
+    done = _in_the_copy(tmp_path, "-c", STAND_IN, workload)
+    assert done.returncode == 0, done.stderr[-2000:]
+    said = json.loads(done.stdout.strip().splitlines()[-1])
+    assert said["workload"] == workload and said["devices"] == 1
+    return said["per_device_GiB"]
+
+
+def test_describe_chip_lowers_the_cells_own_batch(tmp_path):
+    """The token cell's `[T]` f32 boundaries and `[T]` int32 next ids, with
+    no edit to the tool; and `dlrm-mlperf.zipf` as before: these three are
+    what the parent's tool prints under the same stand-in (my CPU run,
+    PR 34; on the described v5e both print arguments 5.6596, temporaries
+    5.7809, live 11.4405 GiB)."""
+    before = _copy_of_a_checkout(tmp_path)
+    _add_the_token_cell(tmp_path)
+    token = _described(tmp_path, "scratch-lm.docs")
+    assert set(token) == {"arguments", "outputs", "aliased", "temporaries",
+                          "live"}
+    # table, two dense layers and head, with adam's two moments of each;
+    # 256 ids, next ids and boundaries of 4 bytes
+    floats = 3 * (512 * 16 + 16 * 32 + 32 + 32 * 32 + 32 + 32 * 512)
+    assert floats * 4 + 3 * 256 * 4 <= token["arguments"] * 2 ** 30 \
+        <= floats * 4 * 1.05
+    assert 0 < token["aliased"] <= token["outputs"]
+    dlrm = _described(tmp_path, "dlrm-mlperf.zipf")
+    assert (dlrm["arguments"], dlrm["outputs"], dlrm["aliased"]) == (
+        5.65950633212924, 5.658896133303642, 5.65889598056674)
     assert all(p.read_bytes() == data for p, data in before.items())

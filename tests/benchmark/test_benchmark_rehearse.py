@@ -7,6 +7,7 @@ import json
 import pytest
 
 from benchmark import run
+from benchmark.harness import check as held
 from benchmark.harness import spec
 
 CELLS = [w["name"] for w in spec.load_json("BENCHMARK.json")["workloads"]]
@@ -38,10 +39,13 @@ def test_cell_rehearses_and_agrees_with_the_reference(workload, capsys):
         "REFERENCE_CHECK ")).split(" ", 1)[1])
     assert check["ok"] is True, check
     assert "reference_peak_bytes" in check     # None: the CPU keeps no count
-    # every number the check compared, beside its limit
-    assert {"emb_err_beyond_rtol_over_largest", "loss0_off", "loss1_off",
-            "row_err_over_tolerance", "untouched_rows_changed"} == set(
-                check["compared"])
+    # every number the check compared, beside its limit: a loss for each
+    # step the check holds under the cell's optimizer (three under adam)
+    steps = held.check_steps(spec.load_cell(workload).config["optimizer"])
+    assert {"emb_err_beyond_rtol_over_largest", "row_err_over_tolerance",
+            "untouched_rows_changed"} | {
+                f"loss{i}_off" for i in range(steps)} == set(check["compared"])
+    assert len(check["loss"]) == steps
     assert all(number <= limit for number, limit in check["compared"].values())
     assert check["touched_rows"] > 0 and check["untouched_rows"] > 0
     assert check["touched_rows_moved"] > 0

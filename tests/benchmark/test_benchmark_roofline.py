@@ -1,5 +1,5 @@
-"""The roofline arithmetic copied from bench.py: byte counts for both
-configurations, the optimizer's row-transfer count, and the table of peaks."""
+"""The roofline arithmetic: byte counts for both configurations, the
+optimizer's row-transfer count, and the table of peaks."""
 
 import pytest
 
