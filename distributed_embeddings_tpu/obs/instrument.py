@@ -15,6 +15,7 @@ from typing import Optional
 from distributed_embeddings_tpu.obs.registry import MetricRegistry
 
 __all__ = ["export_exchange_gauges", "export_kernel_gauges",
+           "export_moe_gauges",
            "EXCHANGE_GAUGE_FIELDS", "EXCHANGE_GROUP_GAUGE_FIELDS"]
 
 
@@ -31,6 +32,24 @@ def export_kernel_gauges(registry: MetricRegistry) -> dict:
     for impl, verdict in verdicts.items():
         registry.gauge("kernels/gate_verdict", impl=impl).set(verdict)
     return verdicts
+
+def export_moe_gauges(registry: MetricRegistry, stats: dict) -> dict:
+    """Set ``moe/held_pairs_share{layer=}`` and
+    ``moe/max_expert_load_share{layer=}`` from one batch's
+    `routing_stats` (`models.mellum.Mellum.routing_stats`, jitted and
+    forward only: ``{name: [layers]}``). The first says how far this
+    chip's load is from an even router's ``held / total`` (the sorted
+    pair stream's usual rows hold twice that: `ExpertLayer.fast_rows`),
+    the second how uneven the held experts are among themselves. A host
+    read of a device result: call it beside a loss fetch, not every
+    step. Returns ``{name: [floats]}``."""
+    out = {}
+    for name, per_layer in stats.items():
+        out[name] = [float(v) for v in per_layer]
+        for layer, value in enumerate(out[name]):
+            registry.gauge(f"moe/{name}", layer=layer).set(value)
+    return out
+
 
 # top-level report fields exported as exchange/<field> gauges
 EXCHANGE_GAUGE_FIELDS = (

@@ -27,26 +27,39 @@ by primitive matches ``/gather:``, ``/sort:`` at a path's end).
   dedup      the canonical id sort and the duplicate sum (sort, permutation
              gather, prefix, segment-sum, representatives)
   apply      the row update itself: scatter-adds, state re-reads, the delta
+
+A model's own blocks nest inside ``model`` and win there, as the embedding's
+do. They are declared here too, in `MODEL_STAGES`, apart from the engine's
+eight: a step over a model without such a block holds none of them.
+
+  attn       a transformer block's attention: projections, rotary, scores
+  router     an expert layer's scores over all experts and the top-k choice
+  experts    the held experts' part: pair sort, gathers, grouped products,
+             the weighted combine
+  head       final norm, logits over the vocabulary's slice, the loss
 """
 
 import functools
 
 import jax
 
-__all__ = ["PREFIX", "STAGES", "STEP_NAME", "stage", "staged"]
+__all__ = ["MODEL_STAGES", "PREFIX", "STAGES", "STEP_NAME", "stage", "staged"]
 
 PREFIX = "det."
 STAGES = ("ids", "lookup", "acts", "model", "dense_opt", "contrib", "dedup",
           "apply")
+MODEL_STAGES = ("attn", "router", "experts", "head")
 # the jitted train steps' function name: traces say jit(det_train_step) and
 # the compiled module is jit_det_train_step
 STEP_NAME = "det_train_step"
 
 
 def stage(name: str):
-    """``jax.named_scope("det." + name)`` for a name of `STAGES`."""
-    if name not in STAGES:
-        raise ValueError(f"unknown stage {name!r}; the stages are {STAGES}")
+    """``jax.named_scope("det." + name)`` for a name of `STAGES` or
+    `MODEL_STAGES`."""
+    if name not in STAGES + MODEL_STAGES:
+        raise ValueError(f"unknown stage {name!r}; the stages are "
+                         f"{STAGES + MODEL_STAGES}")
     return jax.named_scope(PREFIX + name)
 
 
