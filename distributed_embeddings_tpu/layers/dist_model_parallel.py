@@ -4064,6 +4064,25 @@ class DistributedEmbedding:
         return {k: np.unique(np.concatenate(chunks))
                 for k, chunks in per.items()}
 
+    def duplicate_shares(self, params: dict, inputs: Sequence,
+                         sort_spec=("adagrad", "auto")) -> dict:
+        """{bucket: 1 - distinct rows / valid slots} of one batch's
+        exchanged id stream (`sparse_update.dup_share`), for every
+        table-parallel bucket whose sparse update takes the forward's
+        folded sort under `sort_spec` = (optimizer kind, strategy), as
+        `residual_sort_scope` reads it: how much of the stream the update's
+        duplicate sum aggregates. Forward only and jittable: it reads the
+        sort's `seg_start` and gathers no row's gradient. The values are
+        device scalars; `obs.instrument.export_update_gauges` fetches
+        them."""
+        _, res = self.apply(params, inputs, return_residuals=True,
+                            residual_sort=sort_spec)
+        groups, _ = self._exchange_groups_for_key(res.key)
+        return {grp.bucket: sparse_update_ops.dup_share(
+                    sort_g, max(self.plan.tp_buckets[grp.bucket].rows_max, 1))
+                for grp, sort_g in zip(groups, res.tp_sort)
+                if sort_g is not None}
+
     def hot_resident_rows(self, params) -> dict:
         """{bucket: (sorted valid int64 keys [n], rows [n, w])} — the
         AUTHORITATIVE hot-resident rows per hot bucket. This is the ONE

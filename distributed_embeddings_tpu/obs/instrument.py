@@ -15,7 +15,7 @@ from typing import Optional
 from distributed_embeddings_tpu.obs.registry import MetricRegistry
 
 __all__ = ["export_exchange_gauges", "export_kernel_gauges",
-           "export_moe_gauges",
+           "export_moe_gauges", "export_update_gauges",
            "EXCHANGE_GAUGE_FIELDS", "EXCHANGE_GROUP_GAUGE_FIELDS"]
 
 
@@ -48,6 +48,20 @@ def export_moe_gauges(registry: MetricRegistry, stats: dict) -> dict:
         out[name] = [float(v) for v in per_layer]
         for layer, value in enumerate(out[name]):
             registry.gauge(f"moe/{name}", layer=layer).set(value)
+    return out
+
+
+def export_update_gauges(registry: MetricRegistry, shares: dict) -> dict:
+    """Set ``update/dup_share{bucket=}`` from one batch's
+    `DistributedEmbedding.duplicate_shares` (jitted and forward only:
+    ``{bucket: share}``): 1 - distinct rows / valid slots of the id stream
+    a bucket's sparse update receives, the part of it that the duplicate
+    sum (`dedup_sum`'s scan, or the tile stream's one-hot product) folds
+    away. A host read of a device result: call it beside a loss fetch,
+    not every step. Returns ``{bucket: float}``."""
+    out = {bucket: float(share) for bucket, share in shares.items()}
+    for bucket, value in out.items():
+        registry.gauge("update/dup_share", bucket=bucket).set(value)
     return out
 
 

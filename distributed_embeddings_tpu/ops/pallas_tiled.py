@@ -38,7 +38,8 @@ per-row DMA, no semaphores:
     accumulator, 2.88M deduped slots) takes 42-48 ms a call with its
     walk (the kernel itself 35-40 ms in the cell: 18 GB at 450-510
     GB/s), where the two XLA scatter-adds and the re-read it stands in
-    for take 609-673.
+    for take 609-673. `tiled_adagrad` over the same 2.88M slots with
+    their duplicates is the same walk: PERF.md section 6, PR 37.
 
 This is the TPU-native analogue of the reference backward kernel's
 sort -> unique -> segment-reduce pipeline (reference:
@@ -61,13 +62,16 @@ widths 16 and 128 (tests/test_chip_compile.py) and ran compiled against its
 XLA formulation at widths 8, 16 and 128 on a v5e chip (chip_smoke.py,
 PR 22; the compiled checks again in PR 33, after the update walk was
 re-oriented). One entry point is on a default path and timed in a cell:
-`tiled_adagrad_rows`, which `sparse_update.sparse_adagrad` takes on a TPU
-for a narrow table's sort branch (ISSUE 33; both Tiny V3 cells). The rest
-of the dispatch lives in sparse_update behind DET_SCATTER_IMPL=tiled
-(raw-stream kernels, f32-tolerance parity) and DET_SCATTER_IMPL=pallas (the
-ISSUE 12 fused strategy: deduped-row appliers + the weighted
-gather->combine forward, bit-exact vs the XLA sort path — see the fused
-section below); none of those has a step time.
+`tiled_adagrad`, rows on the lanes, which `sparse_update.sparse_adagrad`
+takes on a TPU for a narrow table's sort branch and hands the sorted
+stream with its duplicates (ISSUE 37; both Tiny V3 cells. From PR 33 to
+PR 36 the cells ran `tiled_adagrad_rows` behind `dedup_sum`: the same
+kernel over the same number of slots). The rest of the dispatch lives in
+sparse_update behind DET_SCATTER_IMPL=tiled (the other raw-stream kernels,
+f32-tolerance parity) and DET_SCATTER_IMPL=pallas (the ISSUE 12 fused
+strategy: deduped-row appliers + the weighted gather->combine forward,
+bit-exact vs the XLA sort path — see the fused section below); none of
+those has a step time in a cell.
 """
 
 import functools
@@ -79,6 +83,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_embeddings_tpu.obs.stages import staged
 # shared rounding pin (see its docstring): the in-kernel optimizer
 # arithmetic must round at exactly the seams the XLA sort path rounds at
 # (scatter-operand materialization / the pinned adam products), or
@@ -312,9 +317,14 @@ def _placed(ids_ref, grads_ref, base, tile: int, lanes: bool) -> jax.Array:
     the gradient rows are cut into three bfloat16 pieces instead (8 + 8 +
     8 bits of mantissa: all of an f32), stacked as 3 * width rows of ONE
     bfloat16 matmul with f32 accumulation, and the three slabs added. Over
-    a unique id stream each output lane receives one non-zero product a
-    slab, so the total is the f32 value bit for bit: placement, not
-    arithmetic. (A non-finite gradient element spreads over its tile here
+    a unique id stream (`tiled_*_rows`) each output lane receives one
+    non-zero product a slab, so the total is the f32 value bit for bit:
+    placement, not arithmetic. Over duplicates (`tiled_adagrad` on the
+    sorted stream, what a cell runs) a lane's slab is the f32-accumulated
+    sum of its run's exact bfloat16 pieces within the chunk, and the
+    chunks of a run are added in f32 in `acc_ref`: an f32 sum of the
+    run's rows in another order than `dedup_sum`'s tree, no longer a
+    placement. (A non-finite gradient element spreads over its tile here
     as in the row-major form: 0 * inf.)"""
     ids = ids_ref[0, :]
     if not lanes:
@@ -497,12 +507,15 @@ def _hp_with_pin(ids, lr, *extra):
     return jnp.stack(vals).reshape(1, len(vals))
 
 
+@staged("dedup")
 def _sorted_stream(ids, contribs, vocab: int, presorted):
     """(sid, permuted contrib rows) for an update kernel: fresh sort, or a
     caller-provided (sid, perm) — e.g. the forward lookup's sort reused by
     the backward over the SAME id stream (saves ~2 ns/key sort + the key
     build; XLA CSE does not merge the fwd/bwd sorts on its own, measured
-    round 5 — see docs/perf_model.md 'Sort folding')."""
+    round 5 — see docs/perf_model.md 'Sort folding'). Stage `dedup`, as
+    `dedup_sum`'s sort and permutation gather are: the walk and the kernel
+    that follow stay the caller's `apply`."""
     if presorted is None:
         return _sort_ids(ids, contribs, vocab)[:2]
     sid, perm = presorted
@@ -863,9 +876,9 @@ _tiled_lookup.defvjp(_tiled_lookup_fwd, _tiled_lookup_bwd)
 # rep stream is canonical-sorted by dedup_sum's contract, so no sort
 # happens here: the forward's folded GroupSort is the only sort in the
 # step. Dispatch + gates live in sparse_update behind
-# DET_SCATTER_IMPL=pallas; `tiled_adagrad_rows` alone is also what
-# sparse_adagrad's sort branch takes, unasked, for a narrow table on a
-# TPU (`sparse_update._tile_stream`).
+# DET_SCATTER_IMPL=pallas. (What sparse_adagrad's sort branch takes,
+# unasked, for a narrow table on a TPU is the raw-stream `tiled_adagrad`
+# above: `sparse_update._tile_stream`.)
 # --------------------------------------------------------------------------
 def tiled_sgd_rows(table: jax.Array, rep: jax.Array, sums: jax.Array, lr,
                    chunk: Optional[int] = None, tile: Optional[int] = None,

@@ -94,12 +94,13 @@ DEDUP_FLAGS = {"unique_indices": True, "indices_are_sorted": True}
 # kernel the chip's compiler refuses stops the program with the compiler's
 # own error. Nothing here falls back to another path. With no request,
 # one kernel runs by what the code sees: on a TPU, adagrad's sort branch
-# over a table the chip stores column-major hands `dedup_sum`'s output to
-# the fused family's tile stream (`_tile_stream`, ISSUE 33). On a TPU
-# backend the step/layer factories also run each family they will
-# dispatch to ONCE per width class, eagerly and compiled, against its XLA
-# formulation (`prevalidate_active_impl`) and raise on a mismatch; off-TPU
-# the kernels run in interpret mode and the test suite is that check.
+# over a table the chip stores column-major hands the sorted stream,
+# duplicates and all, to the tile stream (`_tile_stream`, ISSUEs 33 and
+# 37). On a TPU backend the step/layer factories also run each family they
+# will dispatch to ONCE per width class, eagerly and compiled, against its
+# XLA formulation (`prevalidate_active_impl`) and raise on a mismatch;
+# off-TPU the kernels run in interpret mode and the test suite is that
+# check.
 def _width_class(width: int) -> int:
     """Pow2 lane-width shape-class for the compiled checks: the compiled
     form of a BlockSpec kernel depends on the lane padding of its width,
@@ -235,26 +236,36 @@ def _validate_pallas_fused(width: int) -> bool:
 
 def _validate_tile_stream(width: int) -> bool:
     """Compiled correctness of the one kernel a default path runs:
-    `pallas_tiled.tiled_adagrad_rows`, rows on the lanes, against the XLA
-    lines it stands in for in `sparse_adagrad`, over several tiles and as
-    one program (one compile, kept by the persistent cache)."""
+    `pallas_tiled.tiled_adagrad`, rows on the lanes, on the sorted stream
+    with its duplicates, against the XLA lines it stands in for in
+    `sparse_adagrad` (`dedup_sum` + `_adagrad_rows_xla`), as one program
+    (one compile, kept by the persistent cache). Duplicates are the rule:
+    three ids in four name one of 300 hot rows that straddle a tile edge,
+    the hottest some thousand times over several chunks, the rest a
+    uniform tail with a few slots out of range. The kernel sums a run in
+    another f32 order than the scan does, so a row is held to 1e-5 of its
+    largest element, not bit for bit."""
     import numpy as np
     from distributed_embeddings_tpu.ops import pallas_tiled as ptl
     rng = np.random.RandomState(0)
-    v, n = 20_000, 4096
+    v, n = 20_000, 16_384
+    hot = 900 + (rng.zipf(1.2, n) - 1) % 300     # tile edge at row 1,024
+    ids = np.where(rng.rand(n) < 0.75, hot, rng.randint(-8, v + 8, n))
 
     @jax.jit
     def gaps(table, acc, ids, delta):
+        got = ptl.tiled_adagrad(table, acc, ids, delta, 0.05,
+                                interpret=False)
         rep, sums = dedup_sum(ids, delta, sentinel=v)
-        got = ptl.tiled_adagrad_rows(table, acc, rep, sums, 0.05,
-                                     interpret=False)
         want = _adagrad_rows_xla(table, acc, rep, sums, 0.05, 1e-10)
-        return [jnp.max(jnp.abs(g - w)) for g, w in zip(got, want)]
+        return [jnp.max(jnp.abs(g - w)
+                        / jnp.max(jnp.abs(w), axis=1, keepdims=True))
+                for g, w in zip(got, want)]
 
     table_gap, acc_gap = gaps(
         jnp.asarray(rng.randn(v, width).astype(np.float32)),
         jnp.full((v, width), 0.1, jnp.float32),
-        jnp.asarray(rng.randint(0, v, n).astype(np.int32)),
+        jnp.asarray(ids.astype(np.int32)),
         jnp.asarray(rng.randn(n, width).astype(np.float32)))
     return bool(table_gap < 1e-5) and bool(acc_gap < 1e-5)
 
@@ -266,7 +277,7 @@ _PALLAS_DMA_CHECK = _KernelCheck(_validate_pallas_scatter,
                                  "DET_SCATTER_IMPL=pallas-dma")
 _PALLAS_FUSED_CHECK = _KernelCheck(_validate_pallas_fused,
                                    "DET_SCATTER_IMPL=pallas")
-# the fused family's one member on a default path (see `_tile_stream`)
+# the one kernel on a default path (see `_tile_stream`)
 _TILE_STREAM_CHECK = _KernelCheck(_validate_tile_stream,
                                   "sparse_adagrad's tile stream")
 
@@ -319,14 +330,17 @@ def _lane_width(width: int) -> bool:
 
 
 def _tile_stream(strategy: str, rows: int, width: int, n: int) -> bool:
-    """Does `sparse_adagrad` hand `dedup_sum`'s output over n id slots to
-    the Pallas tile stream (`pallas_tiled.tiled_adagrad_rows`, rows on
-    the lanes) and not to its XLA scatter lines? By what the code sees,
-    never by a request: a TPU, a table that takes the sort branch and is
-    stored column-major there (a row scatter into it costs ~100 ns a row,
-    the one thing the CPU and a row-major wide table do well), a pair
-    walk that fits the chip's scalar memory. An explicit strategy="sort"
-    keeps the XLA lines: it is the reference the kernels are held to."""
+    """Does `sparse_adagrad` hand its n id slots, sorted and with their
+    duplicates, to the Pallas tile stream (`pallas_tiled.tiled_adagrad`,
+    rows on the lanes: the entry point both Tiny V3 cells run), which
+    sums a run of duplicates in its one-hot product, and not to
+    `dedup_sum` and the XLA scatter lines? By what the code sees, never by
+    a request: a TPU, a table that takes the sort branch and is stored
+    column-major there (a row scatter into it costs ~100 ns a row, the
+    one thing the CPU and a row-major wide table do well), a pair walk
+    that fits the chip's scalar memory. An explicit strategy="sort"
+    keeps `dedup_sum` and the XLA lines: they are the reference the
+    kernels are held to."""
     if strategy != "auto" or _scatter_route(strategy) != "xla":
         return False
     if jax.default_backend() != "tpu" or not _lane_width(width):
@@ -645,6 +659,18 @@ def _usable_presorted(presorted, grad: SparseRowGrad, rows: int):
     return presorted
 
 
+def dup_share(sort, rows: int) -> jax.Array:
+    """1 - distinct rows / valid slots of a sorted id stream (a
+    `GroupSort` under `rows`' canonical key, any leading axes): the share
+    of the contributions that a duplicate sum folds into a row another
+    slot already names. What `dedup_sum`'s scan, or the tile stream's
+    one-hot product, aggregates; 0.0 for a stream of distinct ids, and
+    for one with no valid slot."""
+    valid = sort.sid < rows
+    distinct = jnp.sum(sort.seg_start & valid)
+    return 1.0 - distinct / jnp.maximum(jnp.sum(valid), 1)
+
+
 # ------------------------------------------------------------------ SGD
 @staged("apply")
 def sparse_sgd(table: jax.Array, grad: SparseRowGrad, lr,
@@ -700,10 +726,19 @@ def sparse_adagrad(table: jax.Array, accum: jax.Array, grad: SparseRowGrad,
     rows = table.shape[0]
     ps = _usable_presorted(presorted, grad, rows)
     route = _scatter_route(strategy)
-    if route == "tiled":
+    if route == "tiled" or _tile_stream(strategy, rows, table.shape[-1],
+                                        grad.ids.shape[0]):
         # tiled one-hot-matmul kernel: sort + in-kernel aggregation, no
         # dedup pass, no scatter (see ops/pallas_tiled.py). Explicit
-        # strategy="tiled" runs in interpret mode off-TPU (tests).
+        # strategy="tiled" runs in interpret mode off-TPU (tests). With
+        # no request it is what a narrow table's sort branch takes on a
+        # TPU (`_tile_stream`): one in-place stream over the table and
+        # its accumulator as the chip stores them, rows on the lanes,
+        # each tile read and written once (ISSUE 33; 47 ms at Tiny V3's
+        # bucket where the XLA lines below take 673: PERF.md section 6,
+        # PR 33), a run of duplicates summed in the tile's one-hot
+        # product, so that `dedup_sum`'s scan and compress levels are not
+        # in the step (ISSUE 37)
         from distributed_embeddings_tpu.ops import pallas_tiled as ptl
         return ptl.tiled_adagrad(table, accum, grad.ids, grad.contribs,
                                  lr, eps=eps,
@@ -729,13 +764,6 @@ def sparse_adagrad(table: jax.Array, accum: jax.Array, grad: SparseRowGrad,
         return t_new, acc_new
     rep, sums = dedup_sum(grad.ids, grad.contribs, sentinel=rows,
                           presorted=ps)
-    if _tile_stream(strategy, rows, table.shape[-1], rep.shape[0]):
-        # one in-place stream over the table and its accumulator as the
-        # chip stores them, rows on the lanes: each tile read and written
-        # once (ISSUE 33; 47 ms at Tiny V3's bucket where the lines below
-        # take 673: PERF.md section 6, PR 33)
-        from distributed_embeddings_tpu.ops import pallas_tiled as ptl
-        return ptl.tiled_adagrad_rows(table, accum, rep, sums, lr, eps=eps)
     lr_static = _static_float(lr)
     if _scatter_env("pallas-dma") and lr_static is not None:
         # fused RMW stream: one pass reads+updates table and accumulator

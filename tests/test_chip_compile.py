@@ -216,20 +216,22 @@ BUCKET_ROWS, BUCKET_IDS = 70_200_320, 2_883_584
 
 
 def _tiny_v3_bucket_stream(one_chip):
-    """`tiled_adagrad_rows` at the bucket's real shape, donated as the step
-    donates it: it compiles (row-major blocks made the compiler ask for a
-    36 GB copy of the table, its 16 lanes padded to 128: ISSUE 33), in
-    place, and what it keeps beside its arguments is the padded stream."""
+    """`tiled_adagrad` at the bucket's real shape, on the raw stream with
+    the folded forward sort (`presorted`) as `sparse_adagrad` calls it
+    (ISSUE 37), donated as the step donates it: it compiles (row-major
+    blocks made the compiler ask for a 36 GB copy of the table, its 16
+    lanes padded to 128: ISSUE 33), in place, and what it keeps beside its
+    arguments is the permuted and the padded stream."""
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     state = S((BUCKET_ROWS, 16), F32)
+    ids = S((BUCKET_IDS,), I32)
     compiled = jax.jit(
-        lambda t, a, r, s: pallas_tiled.tiled_adagrad_rows(
-            t, a, r, s, 0.01, eps=1e-7, interpret=False),
+        lambda t, a, i, c, s, p: pallas_tiled.tiled_adagrad(
+            t, a, i, c, 0.01, eps=1e-7, interpret=False, presorted=(s, p)),
         donate_argnums=(0, 1)).lower(
-            state, state, S((BUCKET_IDS,), I32),
-            S((BUCKET_IDS, 16), F32)).compile()
+            state, state, ids, S((BUCKET_IDS, 16), F32), ids, ids).compile()
     assert "tpu_custom_call" in compiled.as_text()
     m = compiled.memory_analysis()
     assert m.temp_size_in_bytes < 0.5 * 2 ** 30

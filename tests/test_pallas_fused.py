@@ -105,6 +105,14 @@ def _lane_cases():
     }
 
 
+def _named_rows(ids, rows):
+    """[rows] bool: the rows some in-range id of the stream names."""
+    ids = np.asarray(ids)
+    named = np.zeros(rows, bool)
+    named[ids[(ids >= 0) & (ids < rows)]] = True
+    return named
+
+
 @pytest.mark.parametrize("case", list(_lane_cases()))
 def test_lane_stream_matches_xla_lines(case):
     """`tiled_adagrad_rows` over a table the chip stores column-major
@@ -134,9 +142,7 @@ def test_lane_stream_matches_xla_lines(case):
     got2, want2 = both(*got, ids, contribs * 3.0)
     for a, b in zip(got + got2, want + want2):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    named = np.zeros(rows, bool)
-    valid = np.asarray(ids)[(np.asarray(ids) >= 0) & (np.asarray(ids) < rows)]
-    named[valid] = True
+    named = _named_rows(ids, rows)
     if named.any():
         assert (np.asarray(got[0])[named] != np.asarray(table)[named]).any()
     for new, old in zip(got, (table, accum)):
@@ -150,6 +156,175 @@ def test_lane_stream_empty_stream():
     t, a = pt.tiled_adagrad_rows(table, table, jnp.zeros((0,), jnp.int32),
                                  jnp.zeros((0, 16), jnp.float32), 0.05)
     assert t is table and a is table
+
+
+# `sparse_adagrad` under the tile-stream selection (ISSUE 37): the raw
+# sorted stream, duplicates and all, goes to `tiled_adagrad`; name ->
+# (rows, raw ids). Blocks are the walk's own: tile 1,024, chunk 256.
+def _duplicate_cases():
+    rng = np.random.RandomState(37)
+    zipf = (rng.zipf(1.05, 2000) - 1) % 3000
+    return {
+        # row 1,500 named 5,000 times: its run spans 20 and more chunks
+        "duplicate-heavy": (3000, rng.permutation(
+            np.concatenate([np.full(5000, 1500), zipf]))),
+        "all-distinct": (3000, rng.permutation(3000)[:1500]),
+        # rows 1,019-1,029 sixty times each: a run over the tile edge at
+        # 1,024 and runs over the chunk edges at slots 256 and 512
+        "run-across-a-chunk-and-a-tile-edge": (
+            2048, np.repeat(np.arange(1019, 1030), 60)),
+        "fillers-and-out-of-range": (3000, np.concatenate(
+            [rng.randint(-40, 3040, 700), np.full(300, 3000),
+             np.full(60, 7), np.full(60, -1)])),
+    }
+
+
+HOT_ROW = 1500
+
+
+def _ulp(x):
+    return np.spacing(np.abs(np.asarray(x, np.float32)))
+
+
+@pytest.mark.parametrize("width", [8, 16])
+@pytest.mark.parametrize("case", list(_duplicate_cases()))
+def test_tile_stream_sums_duplicates_in_its_product(case, width,
+                                                    monkeypatch):
+    """`sparse_adagrad` as a TPU dispatches it for a narrow table's sort
+    branch (interpret mode, rows on the lanes) against
+    `sparse_adagrad(strategy="sort")` on the same raw stream, two steps in
+    a row: touched rows within 1e-5 of the row's largest element (the
+    kernel sums a run in another f32 order than `dedup_sum`'s tree), rows
+    no id names bit-identical; the 5,000-fold row's total within 4 ulp
+    (of the row's largest total) of a float64 sum of its contributions."""
+    rows, ids = _duplicate_cases()[case]
+    rng = np.random.RandomState(5)
+    ids = jnp.asarray(ids.astype(np.int32))
+    contribs = rng.randn(ids.shape[0], width).astype(np.float32)
+    contribs[np.asarray(ids) == 7] = 0.0      # padded slots: a zero row
+    contribs = jnp.asarray(contribs)
+    table = jnp.asarray(rng.randn(rows, width).astype(np.float32))
+    accum = jnp.asarray(0.1 + rng.rand(rows, width).astype(np.float32))
+    # a table of this size takes the dense branch; the sort branch's rule
+    # is held at its real threshold by
+    # test_active_scatter_impl_answers_for_the_shape
+    monkeypatch.setattr(su, "DENSE_ELEMS_MAX", 0)
+    monkeypatch.setattr(pt, "_BACKEND_INTERPRET", True)
+
+    def step(strategy):
+        return jax.jit(lambda t, a, i, c: su.sparse_adagrad(
+            t, a, su.SparseRowGrad(i, c), 0.05, eps=1e-7,
+            strategy=strategy))
+
+    want = step("sort")(table, accum, ids, contribs)
+    want2 = step("sort")(*want, ids, contribs * 3.0)
+    monkeypatch.setattr(su.jax, "default_backend", lambda: "tpu")
+    assert su._tile_stream("auto", rows, width, ids.shape[0])
+    got = step("auto")(table, accum, ids, contribs)
+    got2 = step("auto")(*got, ids, contribs * 3.0)
+
+    named = _named_rows(ids, rows)
+    for g, w in zip(got + got2, want + want2):
+        g, w = np.asarray(g), np.asarray(w)
+        assert (np.abs(g - w) <= 1e-5 * np.abs(w).max(
+            axis=1, keepdims=True)).all()
+    assert (np.asarray(got[0])[named] != np.asarray(table)[named]).any()
+    for new, old in zip(got2, (table, accum)):
+        np.testing.assert_array_equal(np.asarray(new)[~named],
+                                      np.asarray(old)[~named])
+    if case == "duplicate-heavy":
+        total = np.asarray(contribs, np.float64)[
+            np.asarray(ids) == HOT_ROW].sum(axis=0)
+        acc64 = np.asarray(accum, np.float64)[HOT_ROW] + total * total
+        row64 = (np.asarray(table, np.float64)[HOT_ROW]
+                 - 0.05 * total / np.sqrt(acc64 + 1e-7))
+        # the sum is read through the accumulator, which squares it:
+        # d(total^2) = 2 |total| d(total), and two roundings of its own
+        off = 4 * _ulp(total).max()
+        assert (np.abs(np.asarray(got[1])[HOT_ROW] - acc64)
+                <= 2 * np.abs(total) * off + 2 * _ulp(acc64)).all()
+        assert (np.abs(np.asarray(got[0])[HOT_ROW] - row64)
+                <= 4 * _ulp(row64).max()).all()
+
+
+@pytest.mark.parametrize("backend,width,strategy,scans", [
+    ("tpu", 16, "auto", False),     # the tile stream: no scan in the step
+    ("tpu", 8, "auto", False),
+    ("cpu", 16, "auto", True),
+    ("tpu", 128, "auto", True),     # a row-major table keeps the XLA lines
+    ("tpu", 16, "sort", True),      # the reference, by request
+])
+def test_dedup_sum_is_traced_only_off_the_tile_stream(backend, width,
+                                                      strategy, scans,
+                                                      monkeypatch):
+    """With the tile stream selected, the traced `sparse_adagrad` holds no
+    `dedup_sum` (its 44 streamed levels at Tiny V3's bucket: ISSUE 37);
+    everywhere else it still does."""
+    class Scanned(Exception):
+        pass
+
+    def raising(*a, **k):
+        raise Scanned
+
+    monkeypatch.setattr(su, "dedup_sum", raising)
+    monkeypatch.setattr(su.jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(pt, "_BACKEND_INTERPRET", True)
+    S = jax.ShapeDtypeStruct
+    state = S((3_000_000, width), jnp.float32)
+    n = 65_536
+
+    def trace():
+        return jax.eval_shape(
+            lambda t, a, i, c: su.sparse_adagrad(
+                t, a, su.SparseRowGrad(i, c), 0.05, strategy=strategy),
+            state, state, S((n,), jnp.int32), S((n, width), jnp.float32))
+
+    if scans:
+        with pytest.raises(Scanned):
+            trace()
+    else:
+        assert [o.shape for o in trace()] == [state.shape] * 2
+
+
+@pytest.mark.parametrize("mesh_size", [1, 8])
+def test_dup_share_counts_what_the_duplicate_sum_folds(mesh_size):
+    """`update/dup_share{bucket=}` (ISSUE 37): 1 - distinct rows / valid
+    slots of the id stream a bucket's update receives, from the forward's
+    folded sort alone, jitted; exact against numpy on one chip's stream,
+    between the all-distinct and the one-row batch on a mesh; in the
+    registry and in the catalog."""
+    import os
+    from distributed_embeddings_tpu.obs.instrument import export_update_gauges
+    from distributed_embeddings_tpu.obs.registry import MetricRegistry
+    mesh = create_mesh(jax.devices()[:mesh_size]) if mesh_size > 1 else None
+    model = TinyModel([(96, 8, "sum")], mesh, input_max_hotness=[3])
+    emb = model.embedding
+    params = emb.init(jax.random.PRNGKey(0))
+    shares = jax.jit(lambda p, c: emb.duplicate_shares(
+        p, c, sort_spec=("adagrad", "sort")))
+    rng = np.random.RandomState(3)
+    distinct = jnp.asarray(rng.permutation(96)[:BATCH * 3].reshape(BATCH, 3)
+                           .astype(np.int32))
+    one_row = jnp.full((BATCH, 3), 5, jnp.int32)
+    skewed = jnp.asarray(rng.zipf(1.3, (BATCH, 3)).astype(np.int32) % 96)
+    registry = MetricRegistry()
+    got = {name: export_update_gauges(registry, shares(params, [c]))
+           for name, c in [("distinct", distinct), ("one_row", one_row),
+                           ("skewed", skewed)]}
+    assert all(set(g) == {0} for g in got.values())
+    assert got["distinct"][0] == 0.0
+    if mesh is None:
+        assert got["one_row"][0] == pytest.approx(1 - 1 / (BATCH * 3))
+        assert got["skewed"][0] == pytest.approx(
+            1 - len(np.unique(skewed)) / skewed.size)
+    assert got["distinct"][0] < got["skewed"][0] < got["one_row"][0] < 1
+    # no artifact, no gauge: the dense branch aggregates without a sort
+    assert emb.duplicate_shares(params, [skewed]) == {}
+    assert registry.snapshot()["gauges"]["update/dup_share{bucket=0}"] \
+        == pytest.approx(got["skewed"][0])
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "docs", "observability.md")) as f:
+        assert "`update/dup_share{bucket=}`" in f.read()
 
 
 def test_fused_lookup_matches_reference():
