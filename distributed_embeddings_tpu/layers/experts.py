@@ -119,6 +119,12 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array,
                           preferred_element_type=jnp.float32)
 
 
+ROUTERS = ("softmax", "sigmoid")
+# what a sigmoid router's renormalisation adds to the chosen scores' sum
+# (they are independent, and all of them can be next to nothing)
+SIGMOID_NORM_EPS = 1e-6
+
+
 class Routing(NamedTuple):
     """A batch's routing: per token its `top_k` experts, ascending by score
     rank, and their weights, renormalised to sum to 1."""
@@ -127,17 +133,26 @@ class Routing(NamedTuple):
 
 
 class ExpertLayer:
-    """SwiGLU experts behind a softmax router; static configuration only.
+    """SwiGLU experts behind a router; static configuration only.
 
     Args:
       hidden: the model's width. width: an expert's inner width.
       num_experts_total: the router's outputs, held here or not.
       held: the experts this chip holds, a contiguous ``range``.
       top_k: experts per token; their weights are renormalised to sum 1.
+      router: the rule that scores the experts, one of `ROUTERS`.
+        ``softmax``: a softmax over all experts, the `top_k` largest, their
+        probabilities divided by their sum. ``sigmoid``: each expert's score
+        is a sigmoid of its own logit, and the chosen scores are divided by
+        their sum plus `SIGMOID_NORM_EPS`. Its parameters hold ``bias``
+        ``[num_experts_total]``, which **selects and does not weigh**: the
+        `top_k` are taken of ``score + bias`` and weighted by the scores
+        alone. A buffer that balances the experts' load from outside the
+        loss: no gradient reaches it, and this layer never changes it.
     """
 
     def __init__(self, hidden: int, width: int, num_experts_total: int,
-                 held: Sequence[int], top_k: int):
+                 held: Sequence[int], top_k: int, router: str = "softmax"):
         given = list(held)
         held = range(given[0], given[0] + len(given)) if given else range(0)
         if not (given and given == list(held) and 0 <= held.start
@@ -146,34 +161,57 @@ class ExpertLayer:
                              f"{num_experts_total} experts")
         if not 0 < top_k <= num_experts_total:
             raise ValueError(f"top_k {top_k} of {num_experts_total} experts")
+        if router not in ROUTERS:
+            raise ValueError(f"router rule {router!r}; the rules are {ROUTERS}")
         self.hidden, self.width = hidden, width
         self.num_experts_total, self.held, self.top_k = (
             num_experts_total, held, top_k)
+        self.router = router
 
-    def init(self, key, std: float = 0.02, down_std: float = None) -> dict:
+    def init(self, key, std: float = 0.02, down_std: float = None,
+             bias_range: float = 0.0) -> dict:
         """Normal draws at `std`; the down projections, which write to the
-        residual stream, at `down_std` (default `std`)."""
+        residual stream, at `down_std` (default `std`); a sigmoid router's
+        selection bias uniform in ``+-bias_range``."""
         kr, kg, ku, kd = jax.random.split(key, 4)
         down_std = std if down_std is None else down_std
         n, h, f = len(self.held), self.hidden, self.width
-        return {
+        params = {
             "router": std * jax.random.normal(kr, (h, self.num_experts_total)),
             "gate": std * jax.random.normal(kg, (n, h, f)),
             "up": std * jax.random.normal(ku, (n, h, f)),
             "down": down_std * jax.random.normal(kd, (n, f, h))}
+        if self.router == "sigmoid":
+            params["bias"] = jax.random.uniform(
+                jax.random.fold_in(key, 4), (self.num_experts_total,),
+                minval=-bias_range, maxval=bias_range)
+        return params
 
-    def route(self, router: jax.Array, x: jax.Array) -> Routing:
-        """Softmax over all experts, the `top_k` largest, renormalised."""
+    def route(self, router: jax.Array, x: jax.Array,
+              bias: jax.Array = None) -> Routing:
+        """The router's rule over all experts (`ROUTERS`): the `top_k`
+        largest, of ``score + bias`` where a selection bias is given, and
+        their scores renormalised."""
         with stage("router"):
-            scores = jax.nn.softmax(x @ router, axis=-1)
-            top, experts = lax.top_k(scores, self.top_k)
-            return Routing(experts.astype(jnp.int32),
-                           top / jnp.sum(top, axis=-1, keepdims=True))
+            if self.router == "softmax":
+                scores = jax.nn.softmax(x @ router, axis=-1)
+                top, experts = lax.top_k(scores, self.top_k)
+                return Routing(experts.astype(jnp.int32),
+                               top / jnp.sum(top, axis=-1, keepdims=True))
+            scores = jax.nn.sigmoid(x @ router)
+            ranked = (scores if bias is None
+                      else scores + lax.stop_gradient(bias))
+            _, experts = lax.top_k(ranked, self.top_k)
+            top = jnp.take_along_axis(scores, experts, axis=-1)
+            return Routing(
+                experts.astype(jnp.int32),
+                top / (jnp.sum(top, axis=-1, keepdims=True)
+                       + SIGMOID_NORM_EPS))
 
     def __call__(self, params: dict, x: jax.Array) -> jax.Array:
         """``[T, hidden] -> [T, hidden]``: the held experts' part of the
         layer's output."""
-        routing = self.route(params["router"], x)
+        routing = self.route(params["router"], x, params.get("bias"))
         with stage("experts"):
             return self._held_part(params, x, routing)
 
@@ -228,14 +266,24 @@ class ExpertLayer:
         """Forward only, what a batch's routing asks of this chip:
         ``held_pairs_share``, the share of the ``T * top_k`` pairs that
         picked a held expert (``len(held) / num_experts_total`` under a
-        uniform router), and ``max_expert_load_share``, the busiest held
-        expert's share of the held pairs (``1 / len(held)`` when even)."""
-        local = self.route(params["router"], x).experts - self.held.start
+        uniform router), ``max_expert_load_share``, the busiest held
+        expert's share of the held pairs (``1 / len(held)`` when even),
+        and, under the ``sigmoid`` rule, ``bias_moved_share``: the share of
+        the tokens whose chosen set is another than their scores alone
+        would choose."""
+        chosen = self.route(params["router"], x, params.get("bias")).experts
+        local = chosen - self.held.start
         loads = jnp.sum(local[:, :, None] == jnp.arange(len(self.held)),
                         axis=(0, 1))
         held = jnp.sum(loads)
-        return {"held_pairs_share": held / local.size,
-                "max_expert_load_share": jnp.max(loads) / jnp.maximum(held, 1)}
+        stats = {"held_pairs_share": held / local.size,
+                 "max_expert_load_share": jnp.max(loads) / jnp.maximum(held, 1)}
+        if self.router == "sigmoid":
+            unbiased = self.route(params["router"], x).experts
+            stats["bias_moved_share"] = jnp.mean(jnp.any(
+                jnp.sort(chosen, axis=-1) != jnp.sort(unbiased, axis=-1),
+                axis=-1))
+        return stats
 
 
 # Every gather, mask and scan of `_products` is paid by the row, held or

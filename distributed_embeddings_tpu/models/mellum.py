@@ -125,6 +125,31 @@ def _rms_norm(x, weight, eps):
                         + eps) * weight
 
 
+def embed_tokens(embedding, params, cats, taps, return_residuals):
+    """(the tokens' rows ``[T, hidden]``, the lookup's residuals or None):
+    the one-table embedding as `make_sparse_train_step`'s ``loss_fn`` calls
+    it, tapped where the step asks."""
+    if taps is not None or return_residuals:
+        (x,), res = embedding(params, list(cats), taps=taps,
+                              return_residuals=True)
+        return x, res
+    (x,) = embedding(params, list(cats))
+    return x, None
+
+
+def head_loss(x, final_norm, head, positions, next_ids, eps):
+    """Stage ``head``: the final RMSNorm, logits over the head's slice of
+    the vocabulary, and the mean softmax cross-entropy over the tokens whose
+    successor is in the same document and sequence."""
+    with stage("head"):
+        _, follows = packed_mask_terms(positions)
+        logits = _rms_norm(x, final_norm, eps) @ head
+        nll = (jax.nn.logsumexp(logits, axis=-1)
+               - jnp.take_along_axis(logits, next_ids[:, None],
+                                     axis=-1)[:, 0])
+        return jnp.sum(jnp.where(follows, nll, 0.0)) / jnp.sum(follows)
+
+
 class Mellum:
     """Static configuration; ``init(key)`` returns the parameters and
     ``loss_fn`` is what `training.make_sparse_train_step` asks of a model.
@@ -241,21 +266,11 @@ class Mellum:
 
     def loss_fn(self, params, positions, cats, next_ids, taps=None,
                 return_residuals: bool = False):
-        res = None
-        if taps is not None or return_residuals:
-            (x,), res = self.embedding(params["embedding"], list(cats),
-                                       taps=taps, return_residuals=True)
-        else:
-            (x,) = self.embedding(params["embedding"], list(cats))
+        x, res = embed_tokens(self.embedding, params["embedding"], cats, taps,
+                              return_residuals)
         x = self.hidden_states(params, positions, x)
-        with stage("head"):
-            _, follows = packed_mask_terms(positions)
-            logits = _rms_norm(x, params["final_norm"],
-                               self.rms_eps) @ params["head"]
-            nll = (jax.nn.logsumexp(logits, axis=-1)
-                   - jnp.take_along_axis(logits, next_ids[:, None],
-                                         axis=-1)[:, 0])
-            loss = jnp.sum(jnp.where(follows, nll, 0.0)) / jnp.sum(follows)
+        loss = head_loss(x, params["final_norm"], params["head"], positions,
+                         next_ids, self.rms_eps)
         return (loss, res) if return_residuals else loss
 
     def routing_stats(self, params, positions, cats) -> dict:

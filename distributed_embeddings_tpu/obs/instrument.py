@@ -34,15 +34,18 @@ def export_kernel_gauges(registry: MetricRegistry) -> dict:
     return verdicts
 
 def export_moe_gauges(registry: MetricRegistry, stats: dict) -> dict:
-    """Set ``moe/held_pairs_share{layer=}`` and
-    ``moe/max_expert_load_share{layer=}`` from one batch's
-    `routing_stats` (`models.mellum.Mellum.routing_stats`, jitted and
-    forward only: ``{name: [layers]}``). The first says how far this
-    chip's load is from an even router's ``held / total`` (the sorted
-    pair stream's usual rows hold twice that: `ExpertLayer.fast_rows`),
-    the second how uneven the held experts are among themselves. A host
-    read of a device result: call it beside a loss fetch, not every
-    step. Returns ``{name: [floats]}``."""
+    """Set ``moe/held_pairs_share{layer=}``,
+    ``moe/max_expert_load_share{layer=}`` and, of sigmoid routers,
+    ``moe/bias_moved_share{layer=}`` from one batch's
+    `routing_stats` (`models.mellum.Mellum.routing_stats` or
+    `models.lfm2.Lfm2.routing_stats`, jitted and forward only: ``{name:
+    [layers]}``). The first says how far this chip's load is from an
+    even router's ``held / total`` (the sorted pair stream's usual rows
+    hold twice that: `ExpertLayer.fast_rows`), the second how uneven the
+    held experts are among themselves, the third for how many tokens the
+    bias chose another set than the scores alone. A host read of a device
+    result: call it beside a loss fetch, not every step. Returns ``{name:
+    [floats]}``."""
     out = {}
     for name, per_layer in stats.items():
         out[name] = [float(v) for v in per_layer]

@@ -238,22 +238,26 @@ def _tiny_v3_bucket_stream(one_chip):
     assert m.alias_size_in_bytes >= 2 * BUCKET_ROWS * 16 * 4
 
 
-def _mellum_experts(one_chip, monkeypatch):
-    """The expert layer at the cell's size (`mellum2.packed-4k`: 16,384
-    tokens of width 2,304, 8 of 64 experts of width 896 held, top 8),
-    forward and gradient: the library's grouped-product kernels at this
-    repo's tilings, and both branches of the bounded rows."""
+def _cell_experts(one_chip, monkeypatch, cell):
+    """The expert layer at a cell's size (`mellum2.packed-4k`: 16,384
+    tokens of width 2,304, 8 of 64 experts of width 896 held, top 8, a
+    softmax router; `lfm2.packed-4k`: width 2,048, experts of 1,536, top 4,
+    a sigmoid router with its selection bias), forward and gradient: the
+    library's grouped-product kernels at this repo's tilings, and both
+    branches of the bounded rows."""
     from distributed_embeddings_tpu.layers.experts import ExpertLayer
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    layer = ExpertLayer(2304, 896, 64, range(8), 8)
+    layer = {"mellum_experts": ExpertLayer(2304, 896, 64, range(8), 8),
+             "lfm2_experts": ExpertLayer(2048, 1536, 64, range(8), 4,
+                                         router="sigmoid")}[cell]
 
     def place(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one_chip), tree)
 
     params = place(jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
-    x = jax.ShapeDtypeStruct((16384, 2304), F32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((16384, layer.hidden), F32, sharding=one_chip)
     compiled = jax.jit(jax.grad(
         lambda p, x: jnp.sum(layer(p, x)), argnums=(0, 1))).lower(
             params, x).compile()
@@ -287,7 +291,7 @@ def test_tile_stream_selection(backend, rows, width, n, want, monkeypatch):
     "kernel,width",
     [(k, w) for k in KERNELS for w in (16, 128)]
     + [("tiny_v3_step", None), ("tiny_v3_bucket_stream", 16),
-       ("mellum_experts", 2304)],
+       ("mellum_experts", 2304), ("lfm2_experts", 2048)],
     ids=lambda v: str(v))
 def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
     if kernel == "tiny_v3_step":
@@ -296,8 +300,8 @@ def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
     if kernel == "tiny_v3_bucket_stream":
         _tiny_v3_bucket_stream(one_chip)
         return
-    if kernel == "mellum_experts":
-        _mellum_experts(one_chip, monkeypatch)
+    if kernel in ("mellum_experts", "lfm2_experts"):
+        _cell_experts(one_chip, monkeypatch, kernel)
         return
 
     def S(shape, dtype):
