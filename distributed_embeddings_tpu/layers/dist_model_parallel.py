@@ -292,6 +292,13 @@ def _effective_weights(weights: Optional[jax.Array], k: int,
     return weights / denom, 1.0
 
 
+def _as_stream(x: jax.Array, feature_major: bool) -> jax.Array:
+    """An exchange group's per-slot array [B, f, k, ...] in the order its
+    stream is flattened in: [f, k, B, ...] feature-major (see
+    `sparse_update.feature_major_stream`), as it is batch-major."""
+    return jnp.moveaxis(x, 0, 2) if feature_major else x
+
+
 class DistributedEmbedding:
     """Distributed embedding wrapper: plans placement for a list of embedding
     tables and runs the hybrid-parallel lookup over a device mesh.
@@ -1285,6 +1292,29 @@ class DistributedEmbedding:
         # flatten path (no combiner at hotness > 1) has no sorted gather
         return bucket.combiner is not None or k == 1
 
+    def _feature_major(self, bucket, k: int, batch: int) -> bool:
+        """Is a group's id stream flattened (f, k, b)? The one answer the
+        lookup, the folded sort and the update's contributions share
+        (`sparse_update.feature_major_stream`: the bucket's width and the
+        batch decide). Batch-major stays where the stream's consumer is
+        batch-major by construction: an offloaded bucket's host lookup and
+        apply, and the sorted-gather kernels, which unpermute by the
+        folded sort."""
+        if bucket.offload and self._offload_enabled:
+            return False
+        if self._fwd_tiled_active(bucket, k):
+            return False
+        return sparse_update_ops.feature_major_stream(bucket.width, batch)
+
+    def _fold_sort(self, bucket, grp, ids: jax.Array,
+                   want_inv: bool) -> GroupSort:
+        """The folded canonical sort of one group's exchanged ids
+        [B, f, k], over the stream in the order `_group_contrib` builds
+        the contributions in."""
+        fm = self._feature_major(bucket, grp.k, ids.shape[0])
+        return canonical_id_sort(_as_stream(ids, fm),
+                                 max(bucket.rows_max, 1), want_inv=want_inv)
+
     def _sort_plan(self, groups, spec) -> List[Optional[str]]:
         """Per exchange group: None (no artifact), "plain" (sid/perm/
         seg_start for the sparse update) or "inv" (+ inverse permutation,
@@ -1347,8 +1377,14 @@ class DistributedEmbedding:
     def _group_lookup(self, table: jax.Array, ids: jax.Array,
                       weights: Optional[jax.Array],
                       combiner: Optional[str],
-                      presorted: Optional[GroupSort] = None) -> jax.Array:
+                      presorted: Optional[GroupSort] = None,
+                      feature_major: bool = False) -> jax.Array:
         """Local fused-bucket lookup + combine: ids [B, f, k] -> [B, f, wf].
+
+        `feature_major` (`_feature_major`'s answer for this group): the
+        XLA gather and combine run over the stream flattened (f, k, b),
+        `_lookup_feature_major`; the Pallas lookup kernels keep their
+        batch-major order, whichever the stream's.
 
         Path selection (overridable via DET_LOOKUP_PATH=auto|xla|pallas for
         hardware A/B): combined sum/mean groups route through the Pallas
@@ -1412,6 +1448,9 @@ class DistributedEmbedding:
                        and combiner in ("sum", "mean")
                        and path != "xla"
                        and (k > 1 or path == "pallas"))
+        if feature_major and not (want_pallas and pallas_lookup.has_kernel(
+                table.shape[0], table.shape[1], table.dtype)):
+            return self._lookup_feature_major(table, ids, weights, combiner)
         if want_pallas:
             w = (weights if weights is not None
                  else jnp.ones((b_sz, f, k), jnp.float32))
@@ -1421,6 +1460,29 @@ class DistributedEmbedding:
             return self._cast(out.reshape(b_sz, f, out.shape[-1]))
         emb = self._cast(jnp.take(table, ids, axis=0))      # [B, f, k, w]
         return _combine(emb, weights, combiner)
+
+    def _lookup_feature_major(self, table: jax.Array, ids: jax.Array,
+                              weights: Optional[jax.Array],
+                              combiner: Optional[str]) -> jax.Array:
+        """`_group_lookup`'s XLA gather and combine for a narrow bucket:
+        ids [B, f, k] -> [B, f, wf], the same rows and the same products
+        as `_combine`'s, gathered in the stream's (f, k, b) order. The
+        chip stores such a table column-major, so the gather's result is
+        `[w, f, k, B]` there: the hotness sum is k adds of `[w, B]` slabs,
+        one input's output a contiguous lane range, and the logical
+        transpose back to [B, f, wf] a bitcast, the consumer wanting the
+        batch minor-most too."""
+        b_sz, f, k = ids.shape
+        emb = self._cast(jnp.take(table, _as_stream(ids, True), axis=0))
+        if combiner is None:                              # [f, k, B, w]
+            return jnp.moveaxis(emb, 2, 0).reshape(b_sz, f, -1)
+        eff_w, scale = _effective_weights(weights, k, combiner)
+        if eff_w is not None:
+            emb = emb * _as_stream(eff_w, True).astype(emb.dtype)[..., None]
+        out = jnp.sum(emb, axis=1)                        # [f, B, w]
+        if scale != 1.0:
+            out = out * jnp.asarray(scale, out.dtype)
+        return jnp.swapaxes(out, 0, 1)
 
     def _cast(self, x: jax.Array) -> jax.Array:
         """Cast a lookup result to compute_dtype (mixed precision no-op when
@@ -1575,8 +1637,8 @@ class DistributedEmbedding:
             if (want_res and sort_plan is not None and sort_plan[g]
                     and not offloaded):
                 with stage("dedup"):
-                    sort_g = canonical_id_sort(
-                        ids_x, max(bucket.rows_max, 1),
+                    sort_g = self._fold_sort(
+                        bucket, grp, ids_x,
                         want_inv=(sort_plan[g] == "inv"))
             if offloaded:
                 # id exchange happens on-device (above); the lookup itself
@@ -1599,9 +1661,11 @@ class DistributedEmbedding:
                 # update still drops those lanes outright.
                 with stage("lookup"):
                     ids_lu = jnp.minimum(ids_x, max(bucket.rows_max, 1) - 1)
-                    out = self._group_lookup(tp_params[grp.bucket][0],
-                                             ids_lu, w_x, "sum",
-                                             presorted=sort_g)
+                    out = self._group_lookup(
+                        tp_params[grp.bucket][0], ids_lu, w_x, "sum",
+                        presorted=sort_g,
+                        feature_major=self._feature_major(
+                            bucket, grp.k, ids_lu.shape[0]))
                     tap_g = None if taps is None else taps["tp"][g]
                     if tap_g is not None:
                         out = out + tap_g[0].astype(out.dtype)
@@ -1958,7 +2022,9 @@ class DistributedEmbedding:
             out = self._group_lookup(
                 tp_params[grp.bucket][0], ids_x, eff_w,
                 None if bucket.combiner is None else "sum",
-                presorted=presorted)
+                presorted=presorted,
+                feature_major=self._feature_major(bucket, grp.k,
+                                                  ids_x.shape[0]))
         if scale != 1.0:
             out = out * jnp.asarray(scale, out.dtype)
         if tap is not None:
@@ -2927,8 +2993,8 @@ class DistributedEmbedding:
                 sort_g = None
                 if return_residuals and sort_plan[g]:
                     with stage("dedup"):
-                        sort_g = canonical_id_sort(
-                            ids_l, max(bucket.rows_max, 1),
+                        sort_g = self._fold_sort(
+                            bucket, grp, ids_l,
                             want_inv=(sort_plan[g] == "inv"))
                 if g in offloaded_groups:
                     with stage("ids"):
@@ -3112,13 +3178,20 @@ class DistributedEmbedding:
         else:
             gk = gtap[..., None, :]
         eff = res_tp_w[g]
+        if eff is not None and not stacked:
+            eff = eff[0]
+        # the stream's order is the forward's (`_feature_major`): ids,
+        # weights and tap gradients are viewed [f, k, B] before they
+        # flatten, so that the folded sort's `perm` indexes these rows
+        fm = not stacked and self._feature_major(bucket, k, ids_x.shape[0])
+        ids_x, gk = _as_stream(ids_x, fm), _as_stream(gk, fm)
         if eff is None:
             _, scale = _effective_weights(None, k, bucket.combiner)
             contrib = jnp.broadcast_to(gk.astype(jnp.float32) * scale,
                                        ids_x.shape + (wf,))
         else:
-            eff = eff if stacked else eff[0]
-            contrib = gk.astype(jnp.float32) * eff[..., None]
+            contrib = (gk.astype(jnp.float32)
+                       * _as_stream(eff, fm)[..., None])
         if stacked:
             world = ids_x.shape[0]
             return SparseRowGrad(ids_x.reshape(world, -1),
@@ -4082,6 +4155,17 @@ class DistributedEmbedding:
                     sort_g, max(self.plan.tp_buckets[grp.bucket].rows_max, 1))
                 for grp, sort_g in zip(groups, res.tp_sort)
                 if sort_g is not None}
+
+    def stream_orders(self, batch: int) -> dict:
+        """{bucket: 1 | 0} for every table-parallel bucket: 1 where its
+        exchange groups' id streams run feature-major, (f, k, b), at this
+        global batch (what `_feature_major` answers the lookup, the folded
+        sort and the contributions), 0 where batch-major. Static: the
+        plan's widths and the batch decide, no device is read.
+        `obs.instrument.export_update_gauges` sets
+        ``lookup/stream_order{bucket=}`` from it."""
+        return {b: int(self._feature_major(bucket, 1, batch))
+                for b, bucket in enumerate(self.plan.tp_buckets)}
 
     def hot_resident_rows(self, params) -> dict:
         """{bucket: (sorted valid int64 keys [n], rows [n, w])} — the

@@ -54,17 +54,24 @@ def export_moe_gauges(registry: MetricRegistry, stats: dict) -> dict:
     return out
 
 
-def export_update_gauges(registry: MetricRegistry, shares: dict) -> dict:
+def export_update_gauges(registry: MetricRegistry, shares: dict,
+                         stream_orders: Optional[dict] = None) -> dict:
     """Set ``update/dup_share{bucket=}`` from one batch's
     `DistributedEmbedding.duplicate_shares` (jitted and forward only:
     ``{bucket: share}``): 1 - distinct rows / valid slots of the id stream
     a bucket's sparse update receives, the part of it that the duplicate
     sum (`dedup_sum`'s scan, or the tile stream's one-hot product) folds
     away. A host read of a device result: call it beside a loss fetch,
-    not every step. Returns ``{bucket: float}``."""
+    not every step. Returns ``{bucket: float}``.
+
+    `stream_orders` (`DistributedEmbedding.stream_orders(batch)`, static)
+    sets ``lookup/stream_order{bucket=}`` beside it: 1 where that stream
+    is flattened feature-major, (f, k, b), 0 where batch-major."""
     out = {bucket: float(share) for bucket, share in shares.items()}
     for bucket, value in out.items():
         registry.gauge("update/dup_share", bucket=bucket).set(value)
+    for bucket, order in (stream_orders or {}).items():
+        registry.gauge("lookup/stream_order", bucket=bucket).set(int(order))
     return out
 
 

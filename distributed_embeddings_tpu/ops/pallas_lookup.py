@@ -289,6 +289,14 @@ def check_lookup_kernel(vocab: int, width: int, dtype) -> None:
                       "(pallas_lookup._dma_gather_lookup)", width, dtype)
 
 
+def has_kernel(vocab: int, width: int, dtype) -> bool:
+    """Does `fused_embedding_lookup` serve a [vocab, width] table with a
+    Pallas kernel (the one-hot product or the row DMA), or fall through to
+    XLA's gather? The layer asks before it hands a narrow bucket's group
+    over in the kernels' batch-major order."""
+    return vocab <= _onehot_max_vocab() or row_dma_ok(width, dtype)
+
+
 def _fused_impl(params, ids, weights, interpret):
     vocab, width = params.shape
     if vocab <= _onehot_max_vocab():
@@ -296,7 +304,9 @@ def _fused_impl(params, ids, weights, interpret):
     if row_dma_ok(width, params.dtype):
         return _dma_gather_lookup(params, ids, weights, interpret=interpret)
     # a table the row DMA cannot address (chosen from its shape, not from a
-    # failure): XLA gather + weighted reduce, still fused by XLA
+    # failure): XLA gather + weighted reduce, still fused by XLA. A narrow
+    # bucket's group does not come this far on the layer's default path:
+    # `DistributedEmbedding._group_lookup` gathers it feature-major
     embs = jnp.take(params, ids, axis=0)
     return jnp.einsum("bk,bkw->bw", weights.astype(embs.dtype),
                       embs).astype(jnp.float32)

@@ -329,6 +329,22 @@ def _lane_width(width: int) -> bool:
     return width < ptl.ROW_MAJOR_WIDTH and width % 8 == 0
 
 
+def feature_major_stream(width: int, batch: int) -> bool:
+    """Is an exchange group's `[batch, f, k]` block of id slots flattened
+    into its stream as (f, k, b), and not as (b, f, k)? By what the code
+    sees, never by a request: where the chip stores the bucket
+    column-major (`_lane_width`) a gather of n slots comes back as
+    `[width, n]`, the slot on the lanes, and with the batch a whole number
+    of 128-lane vectors that is `[width, f, k, batch]`: the batch stays on
+    the lanes from the gather to the model and back, which wants it there,
+    and no pass re-tiles the rows in between. A row-major (wide) bucket
+    gains nothing and keeps (b, f, k). The lookup, the folded sort and the
+    update's contributions all ask here (through
+    `DistributedEmbedding._feature_major`), so that a stream and its
+    contributions cannot be flattened differently."""
+    return _lane_width(width) and batch > 0 and batch % 128 == 0
+
+
 def _tile_stream(strategy: str, rows: int, width: int, n: int) -> bool:
     """Does `sparse_adagrad` hand its n id slots, sorted and with their
     duplicates, to the Pallas tile stream (`pallas_tiled.tiled_adagrad`,

@@ -20,6 +20,8 @@ file may load it. Nothing here touches the topology at import, in a skipif
 or in a parametrize argument.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -165,19 +167,33 @@ KERNELS = {f.__name__.lstrip("_"): f for f in (
 REFUSED = {("dma_gather", 16), ("dma_scatter", 16), ("dma_adagrad", 16)}
 
 
-def _tiny_v3_adagrad_step(one_chip, monkeypatch):
-    """The program `chip_smoke.py` trains: Tiny V3 as published, sparse
-    Adagrad, batch 65,536, donated. The library asks `jax.default_backend()`
-    where a TPU takes another branch than the CPU (lookup dispatch, kernel
-    interpret mode), and here it would see the CPU: steer it, in the test,
-    so that what compiles is what the chip is given."""
+def _lane_picks(text):
+    """The fusions that pick one lane of 128 out of every vector of a padded
+    activation array, to put the batch back on the lanes."""
+    return re.findall(
+        r"^\s*%?slice_reduce_fusion[.\d]* = \(?(?:bf16|f32)\[", text, re.M)
+
+
+def _pathless_row_loops(text):
+    """The compiler's own `while` loops (no source path) that re-tile a
+    group's `[1, w, n]` rows."""
+    return [line for line in text.splitlines()
+            if re.search(r" while\(", line) and "op_name" not in line
+            and re.search(r"f32\[1,(8|16),\d+\]", line)]
+
+
+def _compile_adagrad_step(cfg, batch, one_chip, monkeypatch):
+    """A synthetic model's sparse Adagrad step, donated, compiled for the
+    described chip. The library asks `jax.default_backend()` where a TPU
+    takes another branch than the CPU (lookup dispatch, kernel interpret
+    mode), and here it would see the CPU: steer it, in the test, so that
+    what compiles is what the chip is given."""
     from distributed_embeddings_tpu.models.synthetic import (
-        SYNTHETIC_MODELS, SyntheticModel, expand_embedding_configs)
+        SyntheticModel, expand_embedding_configs)
     from distributed_embeddings_tpu.training import make_sparse_train_step
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(pallas_tiled, "_BACKEND_INTERPRET", None)
-    cfg = SYNTHETIC_MODELS["tiny"]
     model = SyntheticModel(cfg, mesh=None, distributed=True,
                            strategy="memory_balanced")
     init_fn, step_fn = make_sparse_train_step(model, "adagrad", lr=0.01)
@@ -191,13 +207,27 @@ def _tiny_v3_adagrad_step(one_chip, monkeypatch):
         return jax.tree.map(lambda s: S(s.shape, s.dtype), tree)
 
     _, _, hotness = expand_embedding_configs(cfg)
-    compiled = jax.jit(step_fn, donate_argnums=(0, 1)).lower(
+    return jax.jit(step_fn, donate_argnums=(0, 1)).lower(
         on_chip(params), on_chip(opt_state),
-        S((BATCH, cfg.num_numerical_features), F32),
-        [S((BATCH, h), I32) for h in hotness], S((BATCH, 1), F32)).compile()
+        S((batch, cfg.num_numerical_features), F32),
+        [S((batch, h), I32) for h in hotness], S((batch, 1), F32)).compile()
+
+
+def _tiny_v3_adagrad_step(one_chip, monkeypatch):
+    """The program `chip_smoke.py` trains: Tiny V3 as published, sparse
+    Adagrad, batch 65,536, donated."""
+    from distributed_embeddings_tpu.models.synthetic import SYNTHETIC_MODELS
+
+    cfg = SYNTHETIC_MODELS["tiny"]
+    compiled = _compile_adagrad_step(cfg, BATCH, one_chip, monkeypatch)
     # the width-16 bucket's apply is the Pallas tile stream (ISSUE 33): no
     # other kernel is on this step's path
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # both narrow buckets' streams run feature-major (ISSUE 39): the parent
+    # held 15 lane-picking fusions and 8 such loops here
+    assert not _lane_picks(text)
+    assert not _pathless_row_loops(text)
     m = compiled.memory_analysis()
     live = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
@@ -208,6 +238,41 @@ def _tiny_v3_adagrad_step(one_chip, monkeypatch):
     assert m.alias_size_in_bytes >= 2 * tables
     assert live < HBM_BYTES, (
         f"Tiny V3 step needs {live / 2**30:.2f} GiB of a 16 GB chip")
+
+
+def _narrow_bucket_step(one_chip, monkeypatch):
+    """A narrow bucket's step, two exchange groups (hotness 1 and 10),
+    batch 1,024: with the id stream feature-major (ISSUE 39) the batch
+    stays on the lanes from the gather to the model and back. Batch-major,
+    the compiler put the slot on the lanes: it padded a group's outputs to
+    `[1, B, f, 16]` with the 24 features on 128 lanes, picked each input
+    out of it with a `slice_reduce_fusion` a lane at a time, and (at Tiny
+    V3's size) re-tiled every group's `[1, w, n]` rows in `while` loops of
+    its own, which carry no source path. The timing cannot be asked about
+    on a CPU; the compiled text can. Forced batch-major the same step
+    shows the fusions, so the count below is known to see them."""
+    from distributed_embeddings_tpu.models.synthetic import (
+        EmbeddingConfig, ModelConfig)
+    from distributed_embeddings_tpu.ops import sparse_update
+
+    batch = 1024
+    cfg = ModelConfig(
+        "narrow", [EmbeddingConfig(2, [1, 10], 200000, 16, True),
+                   EmbeddingConfig(22, [1], 100000, 16, False)],
+        [256, 128], 10, None)
+
+    def compiled_text():
+        return _compile_adagrad_step(cfg, batch, one_chip,
+                                     monkeypatch).as_text()
+
+    assert sparse_update.feature_major_stream(16, batch)
+    text = compiled_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not _lane_picks(text)
+    assert not _pathless_row_loops(text)
+    monkeypatch.setattr(sparse_update, "feature_major_stream",
+                        lambda width, batch: False)
+    assert _lane_picks(compiled_text())
 
 
 # Tiny V3's width-16 bucket as a chip holds it (PERF.md section 4): the one
@@ -291,6 +356,7 @@ def test_tile_stream_selection(backend, rows, width, n, want, monkeypatch):
     "kernel,width",
     [(k, w) for k in KERNELS for w in (16, 128)]
     + [("tiny_v3_step", None), ("tiny_v3_bucket_stream", 16),
+       ("narrow_bucket_step", 16),
        ("mellum_experts", 2304), ("lfm2_experts", 2048)],
     ids=lambda v: str(v))
 def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
@@ -299,6 +365,9 @@ def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
         return
     if kernel == "tiny_v3_bucket_stream":
         _tiny_v3_bucket_stream(one_chip)
+        return
+    if kernel == "narrow_bucket_step":
+        _narrow_bucket_step(one_chip, monkeypatch)
         return
     if kernel in ("mellum_experts", "lfm2_experts"):
         _cell_experts(one_chip, monkeypatch, kernel)
