@@ -47,6 +47,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_embeddings_tpu import compat
+from distributed_embeddings_tpu.obs.spans import spanned
 from distributed_embeddings_tpu.obs.stages import stage, staged
 from distributed_embeddings_tpu.ops import embedding_ops, pallas_lookup
 from distributed_embeddings_tpu.ops import sparse_update as sparse_update_ops
@@ -720,6 +721,7 @@ class DistributedEmbedding:
         return [self._empty_hot_entry(b) if b in self._hot_buckets else None
                 for b in range(len(self.plan.tp_buckets))]
 
+    @spanned("embedding/init")
     def init(self, key) -> dict:
         """Create the parameter pytree:
           {'dp': [replicated [V,w]...],
@@ -4533,6 +4535,7 @@ class DistributedEmbedding:
                 multihost_utils.process_allgather(piece, tiled=True))
         return out
 
+    @spanned("embedding/get_weights")
     def get_weights(self, params, all_ranks: bool = False) -> List[np.ndarray]:
         """Reassemble global per-table weights in original table order
         (reference get_weights :1139-1162), reading device shards one at a

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from distributed_embeddings_tpu.layers.embedding import Embedding
 from distributed_embeddings_tpu.layers.dist_model_parallel import (
     DistributedEmbedding)
+from distributed_embeddings_tpu.obs.spans import spanned
 
 
 def dlrm_initializer():
@@ -125,6 +126,7 @@ class DLRM:
                            if compute_dtype != jnp.float32 else None))
         self.mesh = mesh
 
+    @spanned("model/init")
     def init(self, key) -> dict:
         ke, kb, kt = jax.random.split(key, 3)
         n_feats = len(self.table_sizes) + 1

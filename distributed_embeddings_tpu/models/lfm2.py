@@ -35,6 +35,7 @@ from distributed_embeddings_tpu.layers.experts import ExpertLayer
 from distributed_embeddings_tpu.models.mellum import (
     INIT_STD, _attend_blocks, _normal_init, _rms_norm, _rotate, _table_init,
     embed_tokens, head_loss, packed_mask_terms, rotary_frequencies)
+from distributed_embeddings_tpu.obs.spans import spanned
 from distributed_embeddings_tpu.obs.stages import stage
 
 __all__ = ["Lfm2", "short_conv"]
@@ -150,6 +151,7 @@ class Lfm2:
                 kf, INIT_STD, self.residual_std, bias_range=self.bias_range)
         return layer
 
+    @spanned("model/init")
     def init(self, key) -> dict:
         ke, kh, *kl = jax.random.split(key, 2 + len(self.layers))
         return {"embedding": self.embedding.init(ke),

@@ -34,6 +34,7 @@ from distributed_embeddings_tpu.layers.dist_model_parallel import (
     DistributedEmbedding)
 from distributed_embeddings_tpu.layers.embedding import Embedding
 from distributed_embeddings_tpu.layers.experts import ExpertLayer
+from distributed_embeddings_tpu.obs.spans import spanned
 from distributed_embeddings_tpu.obs.stages import stage
 
 __all__ = ["Mellum", "rotary_frequencies", "packed_mask_terms"]
@@ -203,6 +204,7 @@ class Mellum:
         self.mesh = mesh
 
     # ------------------------------------------------------------ parameters
+    @spanned("model/init")
     def init(self, key) -> dict:
         ke, kh, *kl = jax.random.split(key, 2 + len(self.layer_types))
         h, d = self.hidden, self.head_dim

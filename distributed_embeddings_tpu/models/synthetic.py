@@ -23,6 +23,7 @@ from distributed_embeddings_tpu.layers.embedding import Embedding
 from distributed_embeddings_tpu.layers.dist_model_parallel import (
     DistributedEmbedding)
 from distributed_embeddings_tpu.models.dlrm import _mlp_apply, _mlp_init
+from distributed_embeddings_tpu.obs.spans import spanned
 
 
 class EmbeddingConfig(NamedTuple):
@@ -310,6 +311,7 @@ class SyntheticModel:
         self.mlp_in = emb_out_width + model_config.num_numerical_features
         self.mlp_sizes = list(model_config.mlp_sizes) + [1]
 
+    @spanned("model/init")
     def init(self, key) -> dict:
         ke, km = jax.random.split(key)
         if self.distributed:

@@ -8,9 +8,8 @@ the lookahead engine its compile counts — and nothing could read,
 export, or gate any of it in one place. `MetricRegistry` is that place:
 named counters, gauges, and histograms with labeled families
 (``table=``, ``group=``, ``stage=``), a point-in-time ``snapshot()``
-dict every driver can embed (``fit`` history, the tier-1 smoke), JSONL
-append export for soak runs, and a Prometheus-style text dump for
-scraping.
+dict every driver can embed (``fit`` history, the tier-1 smoke) and a
+JSONL append export for soak runs.
 
 `LatencyHistogram` — the geometric-bucket histogram `serving` and the
 ingest pipeline always used — moved here and IS the registry's
@@ -29,9 +28,9 @@ drives). Instruments are plain Python objects updated from host-side
 driver code only — nothing here may run under a jit trace.
 """
 
+import bisect
 import json
 import os
-import re
 import threading
 import time
 from typing import Dict, Optional, Tuple
@@ -106,16 +105,22 @@ class LatencyHistogram:
         self._ratio = 10.0 ** (1.0 / bins_per_decade)
         # edges[i] = lo * ratio^i; bucket i holds (edges[i-1], edges[i]]
         self._edges = lo * self._ratio ** np.arange(self.bins)
-        self._counts = np.zeros((self.bins + 1,), np.int64)  # +overflow
+        # the same edges as a list: `record` bisects it, which costs a
+        # tenth of `np.searchsorted` on a scalar and finds the same bucket
+        self._edge_list = self._edges.tolist()
+        # a list, not an array: `record` adds one to one slot
+        self._counts = [0] * (self.bins + 1)                 # +overflow
         self._total = 0.0
         self._max = 0.0
 
     def record(self, seconds: float) -> None:
-        s = max(float(seconds), 0.0)
-        idx = int(np.searchsorted(self._edges, s, side="left"))
-        self._counts[min(idx, self.bins)] += 1
+        s = float(seconds)
+        if not s > 0.0:             # a negative reading, or not a number
+            s = 0.0
+        self._counts[bisect.bisect_left(self._edge_list, s)] += 1
         self._total += s
-        self._max = max(self._max, s)
+        if s > self._max:
+            self._max = s
 
     def merge(self, other: "LatencyHistogram") -> "LatencyHistogram":
         """Fold another histogram's counts into this one (in place;
@@ -132,14 +137,14 @@ class LatencyHistogram:
                 f"layouts: (lo={self.lo}, bins={self.bins}, "
                 f"ratio={self._ratio}) vs (lo={other.lo}, "
                 f"bins={other.bins}, ratio={other._ratio})")
-        self._counts += other._counts
+        self._counts = [a + b for a, b in zip(self._counts, other._counts)]
         self._total += other._total
         self._max = max(self._max, other._max)
         return self
 
     @property
     def count(self) -> int:
-        return int(self._counts.sum())
+        return sum(self._counts)
 
     def percentile(self, p: float) -> float:
         """The p-th percentile (0..100) in seconds; 0.0 when empty."""
@@ -194,6 +199,7 @@ class MetricRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._metrics: Dict[Tuple[str, str, _LabelKey], object] = {}
+        self._span_histograms: Dict[str, LatencyHistogram] = {}
 
     def _resolve(self, kind: str, name: str, labels: dict, factory):
         key = (kind, name, tuple(sorted(labels.items())))
@@ -234,6 +240,17 @@ class MetricRegistry:
                 f"histogram {metric_key(name, labels)!r} exists with a "
                 "different bucket layout (first creation wins; merging "
                 "layouts would misfile counts)")
+        return h
+
+    def span_histogram(self, path: str) -> LatencyHistogram:
+        """``histogram("span_seconds", span=path)``, resolved once per
+        path: `obs.span` asks on every entry, and the labelled lookup
+        (a sorted key, the lock, the layout check) cost more than the
+        region most spans time."""
+        h = self._span_histograms.get(path)
+        if h is None:
+            h = self._span_histograms[path] = self.histogram(
+                "span_seconds", span=path)
         return h
 
     # ------------------------------------------------------------ views
@@ -279,53 +296,6 @@ class MetricRegistry:
             if fsync:
                 os.fsync(f.fileno())
         return line
-
-    def to_prometheus(self) -> str:
-        """Prometheus text exposition of the registry: counters as
-        ``*_total``, gauges verbatim, histograms as summaries
-        (quantile series + ``_count``/``_sum``). Metric names sanitize
-        ``/`` and other non-identifier characters to ``_``; label
-        VALUES escape per the text-format spec (backslash, double
-        quote, newline) — degraded reasons and quarantine paths put
-        arbitrary filesystem strings into labels, and one unescaped
-        quote makes the whole exposition unparseable."""
-        def sane(name: str) -> str:
-            return re.sub(r"[^a-zA-Z0-9_:]", "_", name)
-
-        def esc(value: object) -> str:
-            # the exposition-format escape set, in spec order:
-            # backslash first (or the others' escapes double-escape)
-            return (str(value).replace("\\", "\\\\")
-                    .replace('"', '\\"').replace("\n", "\\n"))
-
-        def fmt_labels(labels: dict, extra: Optional[dict] = None) -> str:
-            merged = {**labels, **(extra or {})}
-            if not merged:
-                return ""
-            inner = ",".join(f'{sane(str(k))}="{esc(merged[k])}"'
-                             for k in sorted(merged))
-            return "{" + inner + "}"
-
-        out = []
-        for name, kl, m in self._by_kind("counter"):
-            mn = sane(name) + "_total"
-            out.append(f"# TYPE {mn} counter")
-            out.append(f"{mn}{fmt_labels(dict(kl))} {m.value}")
-        for name, kl, m in self._by_kind("gauge"):
-            mn = sane(name)
-            out.append(f"# TYPE {mn} gauge")
-            out.append(f"{mn}{fmt_labels(dict(kl))} {m.value}")
-        for name, kl, m in self._by_kind("histogram"):
-            mn = sane(name)
-            labels = dict(kl)
-            out.append(f"# TYPE {mn} summary")
-            for q in (0.5, 0.95, 0.99):
-                v = m.percentile(q * 100)
-                out.append(f"{mn}{fmt_labels(labels, {'quantile': q})} "
-                           f"{v:.9f}")
-            out.append(f"{mn}_count{fmt_labels(labels)} {m.count}")
-            out.append(f"{mn}_sum{fmt_labels(labels)} {m._total:.9f}")
-        return "\n".join(out) + ("\n" if out else "")
 
 
 _default_lock = threading.Lock()

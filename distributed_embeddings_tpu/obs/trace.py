@@ -6,20 +6,27 @@ histogram and an XPlane `TraceAnnotation` — but both are lossy in the
 direction a postmortem needs: the histogram keeps only the
 distribution, and the XPlane trace exists only while a profiler session
 is running (and never on CI or a serving replica). The
-`FlightRecorder` is the third output: a BOUNDED ring of begin/end/
+`FlightRecorder` is the third output: a BOUNDED ring of span/begin/end/
 instant events that is always on (a flight recorder that must be
 switched on before the incident is a black box that records nothing),
 cheap enough to feed from every span (one lock + deque append per
-edge), and exportable at any moment as Chrome-trace-format JSON that
+span), and exportable at any moment as Chrome-trace-format JSON that
 loads directly in Perfetto (ui.perfetto.dev) or chrome://tracing.
 
 Event kinds (Chrome trace `ph` phases on export):
 
-  * ``begin``/``end`` (B/E) — span edges, appended by `obs.span` on
-    entry/exit with the composed span path, so the exported timeline
-    reproduces the nesting `span_seconds{span=}` paths describe,
-    per thread (publisher loop, pipeline workers, consumer pollers
-    each get their own track).
+  * ``span`` (X in the ring, a B/E pair on export) — ONE entry per
+    `obs.span`, written when the span ends (ISSUE 40): its path, start
+    and end (`time.perf_counter_ns`), the span that was open on the
+    thread when it began (its parent) and the thread's step ordinal
+    as the span ended (a span around a dispatch carries that
+    dispatch's ordinal).
+    `spans()` gives them as `SpanRecord`s; the export puts the edges
+    back in time order, so the timeline reproduces the nesting
+    `span_seconds{span=}` paths describe, per thread (publisher loop,
+    pipeline workers, consumer pollers each get their own track).
+  * ``begin``/``end`` (B/E) — edges of a region opened by hand
+    (`begin(name)` .. `end(name)`).
   * ``instant`` (i) — point annotations (degraded-entry, SLO breach,
     fault injection...).
   * ``lineage`` (b/n/e nestable-async, ``cat="version"``) — a store
@@ -54,16 +61,26 @@ import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
-__all__ = ["FlightRecorder", "default_recorder", "reset_default_recorder",
-           "dump_postmortem", "DEFAULT_CAPACITY"]
+__all__ = ["FlightRecorder", "SpanRecord", "default_recorder",
+           "reset_default_recorder", "dump_postmortem", "DEFAULT_CAPACITY"]
 
 DEFAULT_CAPACITY = 16384
 
 # lineage phases in life order; "commit" opens the async track and
 # "serve" closes it (first occurrence only — see class docstring)
 LINEAGE_PHASES = ("commit", "publish", "scan", "apply", "serve")
+
+
+class SpanRecord(NamedTuple):
+    """One finished `obs.span`, as the ring holds it."""
+    name: str                    # the span's path
+    start_ns: int                # time.perf_counter_ns() at entry
+    end_ns: int                  # ... and at exit
+    parent: Optional[str]        # the path open on the thread at entry
+    step: Optional[int]          # the thread's step ordinal at EXIT
+    tid: int
 
 
 class FlightRecorder:
@@ -94,8 +111,10 @@ class FlightRecorder:
         # export, and by being integers — a few bytes per version)
         self._lineage: Dict[int, str] = {}
         # perf_counter at construction: export timestamps are relative
-        # to this origin (Chrome trace ts is an arbitrary-epoch us)
-        self._t0 = time.perf_counter()
+        # to this origin (Chrome trace ts is an arbitrary-epoch us).
+        # perf_counter and perf_counter_ns read one clock
+        self._t0_ns = time.perf_counter_ns()
+        self._t0 = self._t0_ns * 1e-9
         # wall-clock twin of _t0 so exported args can carry absolute time
         self._wall0 = time.time()
 
@@ -122,12 +141,24 @@ class FlightRecorder:
         with self._lock:
             self._append_locked(ph, name, ts, tid, cat, eid, args)
 
+    def span(self, name: str, start_ns: int, end_ns: int,
+             parent: Optional[str] = None,
+             step: Optional[int] = None) -> None:
+        """One finished span, as ONE ring entry (`obs.span` calls this
+        at exit; the times are `time.perf_counter_ns()` readings)."""
+        tid = threading.get_ident()
+        # ring layout of a span: the slots `_append_locked` names
+        # cat / eid / args hold parent / step / end_ns
+        with self._lock:
+            self._append_locked("X", name, start_ns, tid, parent, step,
+                                end_ns)
+
     def begin(self, name: str) -> None:
-        """Open a region (span entry). Paired with `end(name)`."""
+        """Open a region by hand. Paired with `end(name)`."""
         self._append("B", name)
 
     def end(self, name: str) -> None:
-        """Close a region (span exit)."""
+        """Close a region opened by `begin`."""
         self._append("E", name)
 
     def instant(self, name: str, **args) -> None:
@@ -177,9 +208,24 @@ class FlightRecorder:
     # ------------------------------------------------------------- views
     def events(self) -> List[tuple]:
         """The current ring contents, oldest first (tuples of
-        (ph, name, ts_seconds, tid, cat, id, args))."""
+        (ph, name, ts_seconds, tid, cat, id, args); a span's entry is
+        ("X", path, start_ns, tid, parent, step, end_ns): `spans()`)."""
         with self._lock:
             return list(self._events)
+
+    def spans(self, name: Optional[str] = None) -> List[SpanRecord]:
+        """The finished spans the ring holds, in the order they ended;
+        those of path `name` alone where one is given."""
+        return [SpanRecord(n, start, end, parent, step, tid)
+                for ph, n, start, tid, parent, step, end in self.events()
+                if ph == "X" and (name is None or n == name)]
+
+    def instants(self, name: str) -> List[tuple]:
+        """The ring's instants called `name`, oldest first, as
+        (``time.perf_counter_ns()`` reading, args dict)."""
+        return [(self._t0_ns + int(ts * 1e9), args or {})
+                for ph, n, ts, _tid, _cat, _eid, args in self.events()
+                if ph == "i" and n == name]
 
     @property
     def dropped(self) -> int:
@@ -212,18 +258,21 @@ class FlightRecorder:
         """The ring as a Chrome-trace-format dict (`traceEvents` JSON
         object form — what Perfetto and chrome://tracing load).
 
-        Balanced by construction: per-thread `E` events whose `B` was
-        evicted from the ring are dropped, still-open `B` events get a
-        synthetic close at the export timestamp, and lineage tracks
-        likewise (an evicted async begin is re-synthesized at the
-        track's first surviving event; an open track closes at export).
-        Span timestamps are microseconds relative to the recorder's
-        construction.
+        Balanced by construction: a span is one entry and exports as
+        its own B/E pair, placed in time order among the other events
+        (a span carries its step ordinal as ``args.step``); per-thread
+        `E` events whose `B` was evicted from the ring are dropped,
+        still-open `B` events get a synthetic close at the export
+        timestamp, and lineage tracks likewise (an evicted async begin
+        is re-synthesized at the track's first surviving event; an open
+        track closes at export). Span timestamps are microseconds
+        relative to the recorder's construction.
         """
         with self._lock:
             events = list(self._events)
             thread_names = dict(self._thread_names)
             wall0 = self._wall0
+        events = _span_edges(events, self._t0_ns)
         pid = os.getpid()
         now_us = (time.perf_counter() - self._t0) * 1e6
         out: List[dict] = [{
@@ -302,6 +351,28 @@ class FlightRecorder:
         with open(path, "w") as f:
             json.dump(doc, f)
         return doc
+
+
+def _span_edges(events: List[tuple], t0_ns: int) -> List[tuple]:
+    """The ring with every span entry replaced by its B and E edges, all
+    in time order. A span is written when it ENDS, so the ring holds a
+    child before its parent; on equal timestamps a parent still opens
+    before its child and closes after it, a sibling closes before the
+    next opens, and an empty span's B precedes its own E."""
+    keyed = []
+    for seq, ev in enumerate(events):
+        if ev[0] != "X":
+            keyed.append(((ev[2], 1, 0.0, seq), ev))
+            continue
+        _, name, start_ns, tid, _parent, step, end_ns = ev
+        start, end = (start_ns - t0_ns) * 1e-9, (end_ns - t0_ns) * 1e-9
+        args = None if step is None else {"step": step}
+        keyed.append(((start, 1, -end, -seq),
+                      ("B", name, start, tid, None, None, args)))
+        keyed.append(((end, 0 if end > start else 2, -start, seq),
+                      ("E", name, end, tid, None, None, None)))
+    keyed.sort(key=lambda k: k[0])
+    return [ev for _, ev in keyed]
 
 
 _default_lock = threading.Lock()

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from distributed_embeddings_tpu.layers.dist_model_parallel import (
     broadcast_variables)
+from distributed_embeddings_tpu.obs import spans as host_spans
 from distributed_embeddings_tpu.obs.stages import stage
 from distributed_embeddings_tpu.ops.sparse_update import (
     drain_sparse_apply, make_sparse_optimizer, prevalidate_active_impl)
@@ -157,6 +158,7 @@ def make_train_step(loss_fn: Callable, optimizer,
             params = apply_updates(params, updates)
         return params, opt_state, loss
 
+    host_spans.install_gc_hook()
     if donate is None:
         donate = default_donate()
     donate_argnums = (0, 1) if donate else ()
@@ -266,6 +268,11 @@ def make_sparse_train_step(model, optimizer: str = "adagrad", lr=0.01,
       init_fn(params) -> opt_state
       step_fn(params, opt_state, numerical, cats, labels)
         -> (params, opt_state, loss);  jit with donated params/opt_state.
+      A call is the host span ``train/dispatch`` (`obs.spans`: one
+      recorder entry with the step's ordinal, a ``det:train/dispatch``
+      annotation on a profiler's host plane, about 3 us); the first
+      call's span holds the step's trace, lowering and compile or cache
+      load.
       `step_fn.name` is the jitted function's name (`obs.stages.STEP_NAME`:
       traces say ``jit(det_train_step)``) and `step_fn.lower` the `lower` of
       the very `jax.jit` object a call dispatches to: arrays or
@@ -335,9 +342,19 @@ def make_sparse_train_step(model, optimizer: str = "adagrad", lr=0.01,
     if donate is None:
         donate = default_donate()
 
+    host_spans.install_gc_hook()
+
     def with_handle(run, core):
-        run.name, run.lower = det_train_step.__name__, core.lower
-        return init_fn, run
+        """`run` as the step function: under the host span
+        ``train/dispatch`` with the process's next step ordinal (the
+        flattening of the arguments' leaves, the dispatch and, on an
+        offloaded bucket, the host apply)."""
+        def step_fn(params, opt_state, numerical, cats, labels):
+            with host_spans.span("train/dispatch", rooted=True,
+                                 step=host_spans.next_step()):
+                return run(params, opt_state, numerical, cats, labels)
+        step_fn.name, step_fn.lower = det_train_step.__name__, core.lower
+        return init_fn, step_fn
 
     if not off_buckets:
         core = jax.jit(det_train_step,
@@ -708,14 +725,12 @@ def fit(model, params, data, steps: int, optimizer: str = "adagrad",
 
     next_batch = None
     examples_total = 0
-    # per-strategy update-phase attribution (ISSUE 12): the step span
-    # gains a nested span whose PATH names the sparse-update kernel
-    # family the traced step dispatches to (xla/tiled/pallas — resolved
-    # once, from the env knobs and from what the dispatch sees of each
-    # bucket: optimizer, rows, width), so snapshots and the soak harness
-    # can see WHICH path actually ran: a kernel's label where any bucket
-    # takes one. Like train/step itself this times the host-side dispatch;
-    # the count/label is the signal, not the duration.
+    # per-strategy update-phase attribution (ISSUE 12): the gauge
+    # update/impl{impl=} names the sparse-update kernel family the traced
+    # step dispatches to (xla/tiled/pallas — resolved once, from the env
+    # knobs and from what the dispatch sees of each bucket: optimizer,
+    # rows, width), so snapshots and the soak harness can see WHICH path
+    # actually ran: a kernel's label where any bucket takes one.
     if sparse:
         from distributed_embeddings_tpu.ops.sparse_update import (
             active_scatter_impl)
@@ -726,6 +741,7 @@ def fit(model, params, data, steps: int, optimizer: str = "adagrad",
                            active_scatter_impl())
     else:
         update_impl = "dense"
+    reg.gauge("update/impl", impl=update_impl).set(1)
     import time as _time
     t_run0 = _time.perf_counter()
     try:
@@ -772,8 +788,7 @@ def fit(model, params, data, steps: int, optimizer: str = "adagrad",
             # work the engine does); device time hides behind async
             # dispatch except at sync boundaries — the honest host-side
             # reading, same clock the reference's fit loop shows
-            with span("train/step", reg), \
-                    span(f"update/{update_impl}", reg):
+            with span("train/step", reg):
                 if la_engine is not None:
                     params, opt_state, loss = la_engine.step(
                         params, opt_state, batch, next_batch)

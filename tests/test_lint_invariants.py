@@ -268,6 +268,7 @@ def test_upward_import_tree_clean_but_for_listed_debts(tmp_path,
     and what the rule allows stays allowed."""
     ok = ("from distributed_embeddings_tpu.obs.stages import staged\n"
           "from distributed_embeddings_tpu.obs import stages\n"
+          "from distributed_embeddings_tpu.obs.spans import span\n"
           "from ..utils import profiling\n"
           "from . import wire\n"
           "import jax\n")

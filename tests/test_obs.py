@@ -80,21 +80,6 @@ def test_jsonl_export_appends_parseable_lines(tmp_path):
     assert all("ts" in ln for ln in lines)
 
 
-def test_prometheus_dump():
-    reg = obs.MetricRegistry()
-    reg.counter("train/steps").inc(7)
-    reg.gauge("vocab/occupancy", table=0).set(0.25)
-    h = reg.histogram("serve/request_seconds")
-    for _ in range(10):
-        h.record(0.002)
-    text = reg.to_prometheus()
-    assert "# TYPE train_steps_total counter" in text
-    assert "train_steps_total 7" in text
-    assert 'vocab_occupancy{table="0"} 0.25' in text
-    assert 'serve_request_seconds{quantile="0.99"}' in text
-    assert "serve_request_seconds_count 10" in text
-
-
 def test_default_registry_process_local():
     obs.reset_default_registry()
     try:

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src import config as jax_config
 from jax.sharding import NamedSharding
 
 from distributed_embeddings_tpu import training
@@ -274,7 +275,11 @@ def test_scopes_change_no_bit(name, monkeypatch):
     none = lambda name: contextlib.nullcontext()   # noqa: E731
     for module in (stages, training, dist_model_parallel):
         monkeypatch.setattr(module, "stage", none)
-    bare_losses, bare, bare_step = run()
+    # the compile cache's key leaves names out, so the bare step would be
+    # kept under the scoped step's key where it alone compiled long enough
+    # to be written, and the tests above would load a step without a stage
+    with jax_config.persistent_cache_min_compile_time_secs(float("inf")):
+        bare_losses, bare, bare_step = run()
     batch = _batch(*PROGRAMS[name][0]()[2:])
     assert "det." not in bare_step.lower(
         *jax.tree.map(jnp.asarray, bare), *batch).as_text()

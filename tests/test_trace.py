@@ -12,8 +12,7 @@ async lineage track — commit opens, publish/scan/apply ride,
 the first predict at >= V closes — version-monotonic across a real
 publish->poll->predict loop; (d) degraded-mode ENTRY dumps a postmortem
 artifact (ring + snapshot) when `DET_OBS_POSTMORTEM_DIR` is set; (e) the
-registry export satellites — per-line JSONL flush/fsync and Prometheus
-label escaping."""
+registry export satellite — per-line JSONL flush/fsync."""
 
 import json
 import os
@@ -288,26 +287,3 @@ def test_export_jsonl_flushes_per_line_and_fsyncs_final(tmp_path):
     reg.export_jsonl(path, extra={"source": "final"}, fsync=True)
     lines = [json.loads(ln) for ln in open(path)]
     assert len(lines) == 2 and lines[1]["source"] == "final"
-
-
-def test_prometheus_label_values_escaped():
-    """The exposition-format fixture (satellite): quarantine paths and
-    degraded reasons put quotes/backslashes/newlines into label values;
-    each must escape per the Prometheus text-format spec."""
-    reg = obs.MetricRegistry()
-    reg.gauge("serve/degraded", reason='C:\\tmp\\"bad"\nfile').set(1)
-    reg.counter("ok", plain="simple").inc()
-    text = reg.to_prometheus()
-    line = [ln for ln in text.splitlines()
-            if ln.startswith("serve_degraded{")][0]
-    assert line == ('serve_degraded{reason="C:\\\\tmp\\\\\\"bad\\"'
-                    '\\nfile"} 1.0')
-    assert "\n\n" not in text            # the newline never split a line
-    assert 'plain="simple"' in text      # plain values untouched
-    # every non-comment line still parses as <name>{<labels>} <value>
-    import re
-    for ln in text.splitlines():
-        if ln.startswith("#"):
-            continue
-        assert re.match(r'^[a-zA-Z0-9_:]+(\{([a-zA-Z0-9_]+="(\\.|[^"\\])*")'
-                        r'(,[a-zA-Z0-9_]+="(\\.|[^"\\])*")*\})? \S+$', ln), ln

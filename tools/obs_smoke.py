@@ -15,8 +15,8 @@ What it drives (tiny shapes, CPU, ~a minute):
   3. The static audit matrix (tools/hlo_audit.py), its finding count
      exported as the ``audit/findings`` gauge.
   4. Snapshot SCHEMA assertions (the keys the soak harness will script
-     against), a JSONL export/parse round trip, a Prometheus dump
-     sanity check, and the checked-in SLO rule file
+     against), a JSONL export/parse round trip, and the checked-in
+     SLO rule file
      (tools/slo_tier1.json) evaluated over the snapshot — compile-count
      and audit-findings rules active, NO perf rules (CI hosts are
      steal-noisy; perf gates live in docs/perf_model.md).
@@ -203,8 +203,8 @@ def main() -> int:
         # here) and which path the step spans were attributed to
         check("kernels/gate_verdict{impl=pallas}" in g,
               "kernel gate-verdict gauges")
-        check(any(k.startswith("span_seconds{span=train/step/update/")
-                  for k in h), "per-strategy update-phase span")
+        check(any(k.startswith("update/impl{impl=") for k in g),
+              "per-strategy update-path gauge")
         check(h["span_seconds{span=train/step}"]["count"] == STEPS,
               "train/step span count")
         check(h["serve/request_seconds"]["count"] == REQUESTS,
@@ -218,9 +218,6 @@ def main() -> int:
         lines = [json.loads(ln) for ln in open(jsonl)]
         check(len(lines) == 2 and lines[0]["counters"] == snap["counters"],
               "JSONL export round trip")
-        prom = reg.to_prometheus()
-        check("span_seconds" in prom and "train_steps_total" in prom,
-              "prometheus dump")
 
         # ---- 4c. the checked-in SLO rules --------------------------
         rules_path = os.path.join(os.path.dirname(os.path.abspath(
