@@ -307,6 +307,21 @@ def _top16(x: jax.Array) -> jax.Array:
     return lax.bitcast_convert_type(bits, jnp.float32)
 
 
+def _pieces(x: jax.Array) -> jax.Array:
+    """[width, chunk] f32 rows cut into three bfloat16 pieces, stacked
+    [3 * width, chunk] f32: 8 + 8 + 8 bits of mantissa, all of an f32, so
+    that their sum is x exactly."""
+    hi = _top16(x)
+    mid = _top16(x - hi)
+    return jnp.concatenate([hi, mid, (x - hi) - mid], axis=0)
+
+
+def _slab_sum(slabs: jax.Array, width: int) -> jax.Array:
+    """The three pieces' slabs added back into [width, tile] f32 rows."""
+    return (slabs[:width] + slabs[width:2 * width]) + slabs[2 * width:
+                                                            3 * width]
+
+
 def _placed(ids_ref, grads_ref, base, tile: int, lanes: bool) -> jax.Array:
     """One pair's contribution to its tile, in the tile's orientation:
     [tile, width], or [width, tile] with `lanes`.
@@ -334,16 +349,11 @@ def _placed(ids_ref, grads_ref, base, tile: int, lanes: bool) -> jax.Array:
                                precision=lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
     x = grads_ref[:].astype(jnp.float32)                 # [width, chunk]
-    width = x.shape[0]
-    hi = _top16(x)
-    mid = _top16(x - hi)
-    pieces = jnp.concatenate([hi, mid, (x - hi) - mid],
-                             axis=0).astype(jnp.bfloat16)
-    slabs = lax.dot_general(pieces,
+    slabs = lax.dot_general(_pieces(x).astype(jnp.bfloat16),
                             _onehot(ids, base, tile).astype(jnp.bfloat16),
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    return (slabs[:width] + slabs[width:2 * width]) + slabs[2 * width:]
+    return _slab_sum(slabs, x.shape[0])
 
 
 def _tile_total(tof_ref, cof_ref, ids_ref, grads_ref, acc_ref, *, tile: int,
@@ -643,6 +653,213 @@ def tiled_adam(table: jax.Array, mu: jax.Array, nu: jax.Array, count,
     out = _adam_call(table, mu, nu, sid, rows, hp, chunk, tile, interpret,
                      b1=b1, b2=b2, eps=eps)
     return out[0], out[1], out[2], count
+
+
+# --------------------------------------------------------------------------
+# dense aggregate (chunk-major walk, the whole target resident)
+#
+# `sparse_update._dense_sum` for a small column-major bucket on a TPU: the
+# stream is walked in the order it arrives, no sort and no permutation, and
+# the target never leaves fast memory. XLA's scatter-add into the same
+# target is paid by the row (18 ns at widths 8 and 16: 45 ms a step for
+# Tiny V3's width-8 bucket, 2.69M contributions into 60,160 rows, with its
+# padded copy of the stream and its sort of the ids); here a chunk is
+# summed into each tile of rows its ids can name by a one-hot product, so
+# the price goes by how far a chunk's ids spread.
+#
+# The product is the update walk's (`_placed`, rows on lanes: three exact
+# bfloat16 pieces, f32 accumulation) with its one-hot FACTORED. A row id is
+# block * 128 + lane. The lane's one-hot `[128, chunk]` is built once a
+# chunk, whatever tiles the chunk is paired with; a pair copies the pieces
+# once for each of the tile's blocks, zeroed where a slot names another
+# block (a select, not a compare against `tile` rows), and one matmul
+# `[blocks * 32, chunk] x [chunk, 128]` places all of them: the result's
+# sublane groups are the target's blocks as the chip stores them. Read on
+# one v5e chip (PERF.md section 6, PR 41): 0.30 ps an element of the
+# one-hot this stands for, where building and multiplying it whole costs
+# 0.70.
+# --------------------------------------------------------------------------
+# Fast memory, of the 16 MiB a kernel may use by default. The target's block
+# is [rows / 128, width + 8, 128] f32, rows on the lanes, the count's
+# sublane group beneath the sums, and the pipeline keeps two buffers of it:
+# 4 MiB each. A pair's operands take 4 MiB more: a chunk's pieces
+# [3 w + 8, chunk] f32, the lane's one-hot [128, chunk] (f32, then
+# bfloat16) and the tile's copies of the pieces [blocks (3 w + 8), chunk]
+# (f32, then bfloat16), which grow with the width where the target's
+# bound does not. The rest is the stream's blocks and the compiler's own.
+# The pair's count is of what the operands could take, not a limit the
+# compiler was seen at: on the chip the kernel compiled and ran at widths
+# 32, 64 and 96 with tiles of 1,024 rows too, no faster than with these
+# (PERF.md section 6, PR 41).
+_DENSE_SUM_BYTES_MAX = 4 * 2 ** 20
+_DENSE_PAIR_BYTES_MAX = 4 * 2 ** 20
+# Slots a chunk, rows a tile at most, chunks a grid step. Read on the chip
+# over Tiny V3's width-8 bucket (2,686,976 slots feature-major into 60,160
+# rows; PERF.md section 6, PR 41): chunk 256 / tile 2048 10.7 ms,
+# 512 / 2048 7.9, 1024 / 1024 7.0, 1024 / 2048 7.0, 1024 / 4096 8.4. A
+# larger chunk means fewer pairs, each with a fixed cost, until a chunk
+# holds more than one feature's slots.
+_DENSE_CHUNK = 1024
+_DENSE_TILE = 1024
+_DENSE_SPAN = 2
+
+
+def _dense_pair_bytes(width: int, tile: int) -> int:
+    """Fast memory that one (chunk, tile) pair's operands take."""
+    held = 3 * width + 8
+    return (tile // 128 * held * 6 + held * 4 + 128 * 6) * _DENSE_CHUNK
+
+
+def dense_sum_blocks(rows: int, width: int):
+    """(chunk, tile) of the dense aggregate's walk over a [rows, width]
+    target: the largest tile, a power of two blocks of 128 rows, whose
+    pair fits `_DENSE_PAIR_BYTES_MAX` (1,024 rows at widths 8 and 16, 128
+    at 96). None where no tile's does (widths over 104) or where the
+    target with its count rows does not fit `_DENSE_SUM_BYTES_MAX`."""
+    def fits(tile):
+        return _dense_pair_bytes(width, tile) <= _DENSE_PAIR_BYTES_MAX
+
+    tile = _DENSE_TILE
+    while tile > 128 and not fits(tile):
+        tile //= 2
+    tile = min(tile, -(-rows // 128) * 128)
+    n_tiles = -(-rows // tile)
+    if (rows < 1 or not fits(tile)
+            or n_tiles * (width + 8) * tile * 4 > _DENSE_SUM_BYTES_MAX):
+        return None
+    return _DENSE_CHUNK, tile
+
+
+def dense_sum_pair_ns(chunk: int, tile: int, width: int) -> float:
+    """What one (chunk, tile) pair of `dense_sum` costs on a v5e chip, in
+    ns: a fixed part, and for each of the tile's blocks of 128 rows a
+    part that goes by the chunk and by the rows a block holds of the
+    product, 3 w + 8 (the pieces' copy and its share of the matmul).
+    Fitted to 24 timed calls of `tools/tpu_dense_sum_sweep.py` (PERF.md
+    section 6, PR 41): widths 8-104, tiles of 128-1,024 rows, 2,112 to
+    153,809 pairs a call, feature-major and batch-major. It reads 496 ns
+    at width 8 and 757 at 16 (tile 1,024) where 480-490 and 744-767 were
+    timed, and is within 3% of every call but the feature-major ones at
+    widths 96 and 104 (5-13% under: so few pairs that a chunk's own cost
+    shows). That it goes by `held * chunk` is PR 41's earlier sweep's,
+    chunks of 256-1,024 slots at width 8."""
+    held = 3 * width + 8
+    return 140.0 + tile / 128 * (1.0 + 1.36 * held * chunk / 1024)
+
+
+def dense_sum_walk(ids: jax.Array, rows: int, chunk: int, tile: int):
+    """The chunk-major walk of an id stream in the order it arrives:
+    (kids [n_chunks, chunk] int32, -1 where an id names no row of the
+    target (negative, >= rows, the last chunk's padding); lo, hi
+    [n_chunks]: the first and last tile of `tile` rows that a chunk's
+    valid ids name, hi < lo for a chunk with none; pairs: a chunk is
+    paired with tiles lo..hi, `sum(hi - lo + 1)` pairs a call). The
+    stream is padded to whole grid steps of `_DENSE_SPAN` chunks."""
+    n = ids.shape[0]
+    n_chunks = -(-n // chunk)
+    span = min(_DENSE_SPAN, n_chunks)
+    n_chunks = -(-n_chunks // span) * span
+    ids = ids.astype(jnp.int32)
+    kids = jnp.where((ids >= 0) & (ids < rows), ids, -1)
+    kids = jnp.concatenate(
+        [kids, jnp.full((n_chunks * chunk - n,), -1, jnp.int32)]
+    ).reshape(n_chunks, chunk)
+    hi = jnp.max(kids, axis=1)
+    lo = jnp.min(jnp.where(kids >= 0, kids, jnp.int32(rows)), axis=1)
+    some = hi >= 0
+    lo = jnp.where(some, lo // tile, 0)
+    hi = jnp.where(some, hi // tile, -1)
+    return kids, lo, hi, jnp.sum(hi - lo + 1)
+
+
+def _dense_sum_kernel(lo_ref, hi_ref, ids_ref, grads_ref, out_ref, *,
+                      blocks: int, chunk: int, span: int):
+    """One grid step: `span` chunks of the stream, each summed into the
+    tiles lo..hi (of `blocks` blocks of 128 rows) of the resident target.
+    Eight rows of ones ride beneath the pieces: their slab is the count
+    of the chunk's ids that a lane names."""
+    step = pl.program_id(0)
+
+    @pl.when(step == 0)
+    def _():
+        out_ref[:] = jnp.zeros(out_ref.shape, jnp.float32)
+
+    width = grads_ref.shape[0]
+    held = 3 * width + 8            # a block's rows of the product
+    for s in range(span):
+        c = step * span + s
+        ids = ids_ref[s, 0, :]
+        x = grads_ref[:, s * chunk:(s + 1) * chunk].astype(jnp.float32)
+        pieces = jnp.concatenate(
+            [_pieces(x), jnp.ones((8, chunk), jnp.float32)], axis=0)
+        lane = _onehot(ids & 127, 0, 128).astype(jnp.bfloat16)
+        block = (ids >> 7)[None, :]         # -1 where the id is dropped
+
+        def pair(t, carry, pieces=pieces, lane=lane, block=block):
+            first = t * blocks
+            placed = jnp.concatenate(
+                [jnp.where(block == first + b, pieces, 0.0)
+                 for b in range(blocks)], axis=0).astype(jnp.bfloat16)
+            slabs = lax.dot_general(placed, lane, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            for b in range(blocks):
+                slab = slabs[b * held:(b + 1) * held]
+                out_ref[first + b] = out_ref[first + b] + jnp.concatenate(
+                    [_slab_sum(slab, width), slab[3 * width:]], axis=0)
+            return carry
+
+        lax.fori_loop(lo_ref[c], hi_ref[c] + 1, pair, 0)
+
+
+def dense_sum(kids: jax.Array, lo: jax.Array, hi: jax.Array,
+              contribs: jax.Array, rows: int, tile: int,
+              interpret: Optional[bool] = None):
+    """(g [rows, w] f32, counts [rows] f32): every row's summed
+    contributions and how many it received, from `dense_sum_walk`'s
+    (kids, lo, hi) and the stream's [n, w] contribution rows. Every
+    duplicate is summed (three exact bfloat16 pieces, f32 accumulation:
+    an f32 sum in another order than a scatter's), counts are exact, an
+    id of -1 adds nothing. The stream is read once, in place
+    (`contribs.T` is a bitcast of a column-major [n, w]), the target
+    written once, and where w is 8 it comes out as the chip stores a
+    column-major [rows, 8]."""
+    n_chunks, chunk = kids.shape
+    n, width = contribs.shape
+    span = min(_DENSE_SPAN, n_chunks)       # the walk's whole grid steps
+    steps = n_chunks // span
+    grads = contribs.astype(jnp.float32).T
+    if n_chunks * chunk != n:
+        # a partial block's tail is undefined, and 0 * NaN is not 0
+        grads = jnp.pad(grads, ((0, 0), (0, n_chunks * chunk - n)))
+    n_blocks = -(-rows // tile) * (tile // 128)
+    out = pl.pallas_call(
+        functools.partial(_dense_sum_kernel, blocks=tile // 128,
+                          chunk=chunk, span=span),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(steps,),
+            in_specs=[
+                # 3-D: a one-sublane block is tiling-legal only where its
+                # trailing dims equal the array's (see `_update_call`)
+                pl.BlockSpec((span, 1, chunk), lambda g, lo, hi: (g, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((width, span * chunk),
+                             lambda g, lo, hi: (0, g),
+                             memory_space=pltpu.VMEM),
+            ],
+            # the same block at every step: it stays where it is until
+            # the grid ends, and is written out once
+            out_specs=pl.BlockSpec((n_blocks, width + 8, 128),
+                                   lambda g, lo, hi: (0, 0, 0),
+                                   memory_space=pltpu.VMEM),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_blocks, width + 8, 128),
+                                       jnp.float32),
+        name="dense_sum",
+        interpret=_interpret_default(interpret),
+    )(lo, hi, kids.reshape(n_chunks, 1, chunk), grads)
+    g = out[:, :width, :].transpose(0, 2, 1).reshape(n_blocks * 128, width)
+    return g[:rows], out[:, width, :].reshape(n_blocks * 128)[:rows]
 
 
 # --------------------------------------------------------------------------

@@ -93,14 +93,15 @@ DEDUP_FLAGS = {"unique_indices": True, "indices_are_sorted": True}
 # DET_LOOKUP_PATH / an explicit strategy=): what was asked for runs, and a
 # kernel the chip's compiler refuses stops the program with the compiler's
 # own error. Nothing here falls back to another path. With no request,
-# one kernel runs by what the code sees: on a TPU, adagrad's sort branch
+# two kernels run by what the code sees: on a TPU, adagrad's sort branch
 # over a table the chip stores column-major hands the sorted stream,
 # duplicates and all, to the tile stream (`_tile_stream`, ISSUEs 33 and
-# 37). On a TPU backend the step/layer factories also run each family they
-# will dispatch to ONCE per width class, eagerly and compiled, against its
-# XLA formulation (`prevalidate_active_impl`) and raise on a mismatch;
-# off-TPU the kernels run in interpret mode and the test suite is that
-# check.
+# 37), and the dense branch's aggregate over a small such table stays in
+# fast memory (`_dense_kernel`, ISSUE 41). On a TPU backend the step/layer
+# factories also run each family they will dispatch to ONCE per width
+# class, eagerly and compiled, against its XLA formulation
+# (`prevalidate_active_impl`) and raise on a mismatch; off-TPU the kernels
+# run in interpret mode and the test suite is that check.
 def _width_class(width: int) -> int:
     """Pow2 lane-width shape-class for the compiled checks: the compiled
     form of a BlockSpec kernel depends on the lane padding of its width,
@@ -115,15 +116,18 @@ def _width_class(width: int) -> int:
 class _KernelCheck:
     """Once-per-(process, width class) compiled-vs-XLA check of one kernel
     family. A compile or run error propagates; a numerics mismatch
-    raises."""
+    raises. `classed` maps a width to the one its check runs at: the
+    pow2 class, or the width itself for a kernel whose operands go by the
+    exact width."""
 
-    def __init__(self, validator, what: str):
+    def __init__(self, validator, what: str, classed=_width_class):
         self.validator = validator      # (width class) -> bool, may raise
         self.what = what
+        self.classed = classed
         self.validated: set = set()     # width classes checked
 
     def prevalidate(self, width: int = 16) -> bool:
-        cls = _width_class(width)
+        cls = self.classed(width)
         if cls not in self.validated:
             if not self.validator(cls):
                 raise RuntimeError(
@@ -270,6 +274,47 @@ def _validate_tile_stream(width: int) -> bool:
     return bool(table_gap < 1e-5) and bool(acc_gap < 1e-5)
 
 
+def _validate_dense_sum(width: int) -> bool:
+    """Compiled correctness of the second kernel a default path runs:
+    `pallas_tiled.dense_sum`, the resident dense aggregate, against
+    `_scatter_sum`, the XLA lines it stands in for in `_dense_sum`, as one
+    program. The stream is what the kernel is for and what it must
+    survive: runs of slots that name one small table's rows (a table
+    straddles a tile edge), most of them duplicates, then a run spread
+    over the whole target, a few ids out of range either side, a length
+    that is no whole chunk. Counts are held exactly, which a lost or a
+    doubled contribution cannot pass; a row's sums to 1e-4 of its largest
+    element (an f32 sum in another order: 7e-6 was read on the chip
+    against XLA's scatter-add over a row of 49,000 contributions). It
+    runs at the width itself and not at its pow2 class: a pair's operands
+    are `3 * width + 8` rows a block, and `dense_sum_blocks` cuts the
+    tile to what they leave room for."""
+    import numpy as np
+    from distributed_embeddings_tpu.ops import pallas_tiled as ptl
+    rng = np.random.RandomState(0)
+    rows, n = _DENSE_SUM_CHECK_ROWS, 20_000
+    chunk, tile = ptl.dense_sum_blocks(rows, width)
+    base = np.repeat(rng.randint(0, rows - 1_500, n // 500 + 1), 500)[:n]
+    ids = np.where(np.arange(n) < 15_000, base + rng.zipf(1.3, n) % 1_500,
+                   rng.randint(-8, rows + 8, n))
+
+    @jax.jit
+    def gaps(ids, contribs):
+        kids, lo, hi, _ = ptl.dense_sum_walk(ids, rows, chunk, tile)
+        g, counts = ptl.dense_sum(kids, lo, hi, contribs, rows, tile,
+                                  interpret=False)
+        g_want, counts_want = _scatter_sum(ids, contribs, rows)
+        scale = jnp.maximum(jnp.max(jnp.abs(g_want), axis=1, keepdims=True),
+                            1e-30)
+        return (jnp.max(jnp.abs(g - g_want) / scale),
+                jnp.max(jnp.abs(counts - counts_want)))
+
+    g_gap, count_gap = gaps(
+        jnp.asarray(ids.astype(np.int32)),
+        jnp.asarray(rng.randn(n, width).astype(np.float32)))
+    return bool(g_gap < 1e-4) and bool(count_gap == 0)
+
+
 # 'pallas' names the fused deduped-row tile-walk strategy (ISSUE 12); the
 # per-row DMA RMW kernels (ops/pallas_scatter.py) are 'pallas-dma'
 _TILED_CHECK = _KernelCheck(_validate_tiled, "DET_SCATTER_IMPL=tiled")
@@ -277,9 +322,14 @@ _PALLAS_DMA_CHECK = _KernelCheck(_validate_pallas_scatter,
                                  "DET_SCATTER_IMPL=pallas-dma")
 _PALLAS_FUSED_CHECK = _KernelCheck(_validate_pallas_fused,
                                    "DET_SCATTER_IMPL=pallas")
-# the one kernel on a default path (see `_tile_stream`)
+# the two kernels on a default path (see `_tile_stream`, `_dense_kernel`)
 _TILE_STREAM_CHECK = _KernelCheck(_validate_tile_stream,
                                   "sparse_adagrad's tile stream")
+_DENSE_SUM_CHECK = _KernelCheck(_validate_dense_sum,
+                                "_dense_sum's resident kernel", classed=int)
+# Rows of that check's target: a width whose pair fits fast memory at all
+# (`pallas_tiled.dense_sum_blocks`) fits a target of so many
+_DENSE_SUM_CHECK_ROWS = 9_000
 
 
 def prevalidate_tiled(width: int = 16) -> bool:
@@ -375,7 +425,8 @@ def gate_verdicts() -> dict:
     return {"tiled": 1 if _TILED_CHECK.validated else -1,
             "pallas-dma": 1 if _PALLAS_DMA_CHECK.validated else -1,
             "pallas": 1 if (_PALLAS_FUSED_CHECK.validated
-                            or _TILE_STREAM_CHECK.validated) else -1}
+                            or _TILE_STREAM_CHECK.validated) else -1,
+            "dense-sum": 1 if _DENSE_SUM_CHECK.validated else -1}
 
 
 def active_scatter_impl(strategy: str = "auto", kind: Optional[str] = None,
@@ -392,13 +443,14 @@ def active_scatter_impl(strategy: str = "auto", kind: Optional[str] = None,
 def prevalidate_active_impl(strategy: Optional[str] = None,
                             widths=None, kind: Optional[str] = None) -> None:
     """Eagerly run the compiled check of whichever kernel family the env
-    knobs (or an explicit strategy= argument) select, and of the tile
-    stream adagrad's default path takes at the widths the chip stores
-    column-major (`kind`: the sparse optimizer about to be built), once
-    per width class, before a train step is traced. A no-op off-TPU and
-    where nothing dispatches to a kernel. Wired into
-    make_sparse_train_step and DistributedEmbedding construction, so user
-    code need not call it.
+    knobs (or an explicit strategy= argument) select, and of the two
+    kernels a default path takes at the widths the chip stores
+    column-major (adagrad's tile stream, and the dense aggregate's
+    resident kernel under adagrad and adam, at each width it has a walk
+    for; `kind`: the sparse optimizer about to be built), once per width
+    class, before a train step is traced. A no-op off-TPU and where
+    nothing dispatches to a kernel. Wired into make_sparse_train_step
+    and DistributedEmbedding construction, so user code need not call it.
 
     `widths`: the table lane widths the caller will dispatch at (the
     layer/step factories pass their plan's bucket+row widths); None
@@ -430,11 +482,19 @@ def prevalidate_active_impl(strategy: Optional[str] = None,
     # a chip is attached to run it on: a step compiled for a described
     # chip (tests/test_chip_compile.py, benchmark.tools.describe_chip)
     # has the backend answered for it and no device
-    if (kind == "adagrad" and strategy in (None, "auto")
+    if (kind in ("adagrad", "adam") and strategy in (None, "auto")
             and _scatter_route("auto") == "xla"
             and jax.devices()[0].platform == "tpu"):
-        for w in sorted({_width_class(w) for w in widths if _lane_width(w)}):
-            _TILE_STREAM_CHECK.prevalidate(w)
+        lane_widths = sorted({w for w in widths if _lane_width(w)})
+        if kind == "adagrad":
+            for w in sorted({_width_class(w) for w in lane_widths}):
+                _TILE_STREAM_CHECK.prevalidate(w)
+        # both optimizers' dense branch sums through `_dense_sum`, whose
+        # kernel takes the widths that `dense_sum_blocks` has a walk for
+        from distributed_embeddings_tpu.ops import pallas_tiled as ptl
+        for w in lane_widths:
+            if ptl.dense_sum_blocks(_DENSE_SUM_CHECK_ROWS, w) is not None:
+                _DENSE_SUM_CHECK.prevalidate(w)
 
 
 def _static_float(x):
@@ -599,19 +659,85 @@ def dedup_sum(ids: jax.Array, contribs: jax.Array, sentinel: int,
     return rep, sums.astype(contribs.dtype)
 
 
-@staged("dedup")
-def _dense_sum(ids, contribs, rows):
-    """[V, w] dense aggregation: scatter-add (OOB ids dropped), plus a row
-    contribution COUNT so the updater can skip untouched rows (and so
-    per-device partial aggregates can be psummed before thresholding —
-    the hot-row shard's replicated update does exactly that).
+# What XLA's scatter-add into a target of some ten thousand rows costs on
+# a v5e chip: it goes by the ROW, whatever the ids, and by which of two
+# forms the compiler gives it. With the count's column 9 or 17 floats wide
+# (widths 8 and 16) 18.3 ns a row: 49.2 and 46.6 ms for 2.69M and 2.56M
+# rows, the padded copy of the stream and the compiler's sort of the ids
+# included (in Tiny V3's step 37.0 + 4.2 + 4.0 ms, ledger, PRs 28-40). At
+# widths 32-104 7.6-8.4 ns a row. Read by `tools/tpu_dense_sum_sweep.py`
+# (PERF.md section 6, PR 41); width 24 read 18.3 over 1.0M rows and 9.7
+# over 2.5M and takes the lower price, under which the kernel runs only
+# where it wins against either. The resident kernel's price goes by the
+# pair (`pallas_tiled.dense_sum_pair_ns`); the two decide, call by
+# call, which of them sums a stream (`_dense_walk`).
+def _scatter_ns_per_row(width: int) -> float:
+    return 18.3 if width <= 16 else 8.0
 
-    One WIDENED scatter carries both: each contribution row is extended
-    with a 1.0 count column, so the count comes out of the same scatter as
-    the data. Round-3 prims: scatter cost is per-ROW (~55-106 ns), so two
-    n-row scatters (data + count) cost twice one — the fusion halves
-    the dense path's descriptor count. Returns (g [rows, w], counts [rows]
-    f32)."""
+
+def _dense_walk(rows: int, width: int, n: int):
+    """(chunk, tile, most pairs) of the resident kernel's walk
+    (`pallas_tiled.dense_sum`) of n id slots into a [rows, width] target,
+    from the shapes alone; None where no stream reaches the kernel
+    whatever its ids: a target the chip does not store column-major
+    (`_lane_width`: the stream's transpose is a bitcast there and the
+    target's rows lie on the lanes), one that does not fit the kernel's
+    share of fast memory beside a pair's operands, or a stream under one
+    chunk of slots (the scatter of so few costs what a kernel's launch
+    does: 19 us at 1,024 rows by `_scatter_ns_per_row`, computed and not
+    read on the chip; and the kernel would multiply padding). The same
+    floor is what lets a step of a few hundred id slots lower for CPU
+    devices with the backend answered "tpu", as
+    `tests/benchmark/test_benchmark_datadriven.py` stands a chip in:
+    `_dense_kernel` asks the backend alone. The kernel's price goes by
+    the (chunk, tile) pair where the scatter's goes by the row, so `most
+    pairs` is the count at which the two cost the same for these
+    shapes."""
+    from distributed_embeddings_tpu.ops import pallas_tiled as ptl
+    blocks = ptl.dense_sum_blocks(rows, width) if _lane_width(width) else None
+    if blocks is None or n < blocks[0]:
+        return None
+    chunk, tile = blocks
+    return chunk, tile, int(_scatter_ns_per_row(width) * n
+                            / ptl.dense_sum_pair_ns(chunk, tile, width))
+
+
+def _dense_kernel(strategy: str, rows: int, width: int, n: int):
+    """`_dense_walk`'s answer where `_dense_sum` may hand its stream to the
+    resident kernel, None where XLA's scatter-add is all there is. By
+    what the code sees, never by a request: a TPU and shapes the kernel
+    takes. An explicit strategy keeps the XLA lines, the reference the
+    kernel is held to. That much is static; how many pairs a call walks
+    is the stream's to say (a feature-major stream, `feature_major_stream`,
+    names one table's rows in a chunk, a batch-major one every table's),
+    and `_dense_sum` compares a call's own count with the walk's most."""
+    if strategy != "auto" or _scatter_route(strategy) != "xla":
+        return None
+    if jax.default_backend() != "tpu":
+        return None
+    return _dense_walk(rows, width, n)
+
+
+def dense_sum_pairs(ids: jax.Array, rows: int, width: int):
+    """(pairs, kernel) of one call of `_dense_sum` over this id stream
+    into a [rows, width] target on a TPU: the (chunk, tile) pairs the
+    resident kernel would walk, and whether that is few enough for it to
+    run (1) and not XLA's scatter-add (0). Forward only and jittable, as
+    `dup_share` is; (0, 0) where no stream reaches the kernel whatever
+    its ids (`_dense_walk`)."""
+    walk = _dense_walk(rows, width, ids.shape[0])
+    if walk is None:
+        return jnp.int32(0), jnp.int32(0)
+    from distributed_embeddings_tpu.ops import pallas_tiled as ptl
+    chunk, tile, most = walk
+    pairs = ptl.dense_sum_walk(ids, rows, chunk, tile)[3]
+    return pairs, (pairs <= most).astype(jnp.int32)
+
+
+def _scatter_sum(ids, contribs, rows):
+    """`_dense_sum` as one WIDENED XLA scatter-add: each contribution row
+    is extended with a 1.0 count column, so that the count comes out of
+    the same scatter as the data."""
     w = contribs.shape[-1]
     ext = jnp.concatenate(
         [contribs.astype(jnp.float32),
@@ -622,6 +748,42 @@ def _dense_sum(ids, contribs, rows):
     dense_ext = jnp.zeros((rows, w + 1), jnp.float32).at[safe_ids].add(
         ext, mode="drop")
     return dense_ext[:, :w], dense_ext[:, w]
+
+
+@staged("dedup")
+def _dense_sum(ids, contribs, rows, strategy: str = "auto"):
+    """[V, w] dense aggregation: every contribution summed into its row
+    (ids that are negative or >= rows dropped), plus a row contribution
+    COUNT so the updater can skip untouched rows (and so per-device
+    partial aggregates can be psummed before thresholding — the hot-row
+    shard's replicated update does exactly that). Returns (g [rows, w],
+    counts [rows] f32).
+
+    Two implementations of the one aggregate. XLA's scatter-add
+    (`_scatter_sum`) is the CPU's, a wide target's and every explicit
+    strategy's, and what the other is held to; on the chip it is paid by
+    the ROW whatever the ids, 18 ns at widths 8 and 16 and 8 ns at 32 and
+    wider (`_scatter_ns_per_row`; the round-3 figure of 55-106 ns is a
+    row into a large table). Where the target is small and column-major
+    on a TPU (`_dense_kernel`) a Pallas kernel keeps it in fast memory,
+    rows on the lanes, and sums each chunk of the stream into the tiles
+    its ids can name by a one-hot
+    product, in the order the stream arrives: no sort, no permutation,
+    no second copy of the stream. Its price goes by the (chunk, tile)
+    pair, so a min/max pass over the ids counts the pairs first and a
+    stream that spreads every chunk over the whole target (a batch-major
+    one) keeps the scatter: both are compiled, `lax.cond` picks, the
+    results agree to an f32 sum's order (counts exactly)."""
+    kernel = _dense_kernel(strategy, rows, contribs.shape[-1], ids.shape[0])
+    if kernel is None:
+        return _scatter_sum(ids, contribs, rows)
+    from distributed_embeddings_tpu.ops import pallas_tiled as ptl
+    chunk, tile, most = kernel
+    kids, lo, hi, pairs = ptl.dense_sum_walk(ids, rows, chunk, tile)
+    return lax.cond(
+        pairs <= most,
+        lambda: ptl.dense_sum(kids, lo, hi, contribs, rows, tile),
+        lambda: _scatter_sum(ids, contribs, rows))
 
 
 @staged("apply")
@@ -774,7 +936,7 @@ def sparse_adagrad(table: jax.Array, accum: jax.Array, grad: SparseRowGrad,
                                       eps=eps)
     how = _pick(strategy, rows, table.shape[-1])
     if how == "dense":
-        g, counts = _dense_sum(grad.ids, grad.contribs, rows)
+        g, counts = _dense_sum(grad.ids, grad.contribs, rows, strategy)
         t_new, (acc_new,) = apply_dense_rows(
             "adagrad", table, (accum,), g, counts > 0, lr, eps=eps)
         return t_new, acc_new
@@ -841,7 +1003,7 @@ def sparse_adam(table: jax.Array, mu: jax.Array, nu: jax.Array, count,
                                    b1=b1, b2=b2, eps=eps)
     how = _pick(strategy, rows, table.shape[-1])
     if how == "dense":
-        g, counts = _dense_sum(grad.ids, grad.contribs, rows)
+        g, counts = _dense_sum(grad.ids, grad.contribs, rows, strategy)
         t_new, (mu_new, nu_new, count) = apply_dense_rows(
             "adam", table, (mu, nu, count), g, counts > 0, lr,
             b1=b1, b2=b2, eps=eps)

@@ -6,7 +6,8 @@ see what it refuses: block shapes that are not tiling-legal, DMA semaphore
 and scalar memory that overflow, row slices the HBM tiling cannot address, a
 step that does not fit the chip's memory. These cases hold every Pallas
 entry point a dispatch on a TPU backend can select to that compiler at the
-widths the models use (16 and 128, at the models' batch of 65,536), and the
+widths the models use (16 and 128, at the models' batch of 65,536), the two
+kernels of Tiny V3's default path at their buckets' real shapes, and the
 full-size Tiny V3 Adagrad step to the chip's 16 GB. A kernel that cannot be
 made legal at a width must be refused by name before any step runs, and the
 case pins that refusal instead.
@@ -20,6 +21,7 @@ file may load it. Nothing here touches the topology at import, in a skipif
 or in a parametrize argument.
 """
 
+import math
 import re
 
 import jax
@@ -167,11 +169,23 @@ KERNELS = {f.__name__.lstrip("_"): f for f in (
 REFUSED = {("dma_gather", 16), ("dma_scatter", 16), ("dma_adagrad", 16)}
 
 
-def _lane_picks(text):
+def _lane_picks(text, count_rows=0):
     """The fusions that pick one lane of 128 out of every vector of a padded
-    activation array, to put the batch back on the lanes."""
-    return re.findall(
-        r"^\s*%?slice_reduce_fusion[.\d]* = \(?(?:bf16|f32)\[", text, re.M)
+    activation array, to put the batch back on the lanes. One such slice
+    is no activation's and is left out by its shape: the count row of a
+    `count_rows`-row dense aggregate (Tiny V3's width-8 bucket: 60,160
+    floats, 240 KB), once in each branch of `_dense_sum`'s conditional
+    and nowhere else."""
+    lines = re.findall(
+        r"^\s*%?slice_reduce_fusion[.\d]* = \(?(?:bf16|f32)\[.*$", text, re.M)
+    def elements(line):
+        dims = re.search(r"\[([\d,]*)\]", line).group(1)
+        return math.prod(int(d) for d in dims.split(","))
+
+    counts = [line for line in lines if "det.dedup/cond/branch_" in line
+              and elements(line) == count_rows]
+    assert len(counts) <= 2, counts
+    return [line for line in lines if line not in counts]
 
 
 def _pathless_row_loops(text):
@@ -220,13 +234,15 @@ def _tiny_v3_adagrad_step(one_chip, monkeypatch):
 
     cfg = SYNTHETIC_MODELS["tiny"]
     compiled = _compile_adagrad_step(cfg, BATCH, one_chip, monkeypatch)
-    # the width-16 bucket's apply is the Pallas tile stream (ISSUE 33): no
+    # the width-16 bucket's apply is the Pallas tile stream (ISSUE 33), the
+    # width-8 bucket's dense aggregate the resident kernel (ISSUE 41): no
     # other kernel is on this step's path
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    _dense_aggregate_is_the_kernels(text)
     # both narrow buckets' streams run feature-major (ISSUE 39): the parent
     # held 15 lane-picking fusions and 8 such loops here
-    assert not _lane_picks(text)
+    assert not _lane_picks(text, count_rows=DENSE_ROWS)
     assert not _pathless_row_loops(text)
     m = compiled.memory_analysis()
     live = (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -238,6 +254,95 @@ def _tiny_v3_adagrad_step(one_chip, monkeypatch):
     assert m.alias_size_in_bytes >= 2 * tables
     assert live < HBM_BYTES, (
         f"Tiny V3 step needs {live / 2**30:.2f} GiB of a 16 GB chip")
+
+
+# Tiny V3's width-8 bucket as a chip holds it: 41 id slots a sample
+DENSE_ROWS, DENSE_IDS = 60_160, 41 * BATCH
+
+
+def _computations(text):
+    """{name: body} of a compiled module's computations."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.\-]+) \(.*?\{\n(.*?)^\}", text, re.M | re.S)}
+
+
+def _dense_aggregate_is_the_kernels(text):
+    """The width-8 bucket's aggregate in the compiled Tiny V3 step (ISSUE
+    41): one `conditional` under `det.dedup` whose one branch is the
+    resident kernel, fed the stream and handing on the target as bitcasts
+    (no copy, pad, sort or scatter beside it), and whose other keeps XLA's
+    scatter-add into `f32[60160,9]` with the padded copy of the stream and
+    the sort the compiler makes of 2,686,976 ids. The parent held those
+    three on the step's own path, 45 ms of it."""
+    conds = [line for line in text.splitlines() if " conditional(" in line]
+    assert len(conds) == 1 and "det.dedup" in conds[0]
+    kernel, scatter = [], []
+    for name, body in _computations(text).items():
+        if (f"f32[8,{DENSE_IDS}]" in body and "tpu_custom_call" in body):
+            kernel.append(name)
+            assert not re.search(r" (copy|pad|sort|scatter)\(", body), name
+        if re.search(rf"f32\[{DENSE_IDS},9\][^ ]* pad\(", body):
+            scatter.append(name)
+            assert re.search(rf"s32\[{DENSE_IDS}\][^ ]*\) sort\(", body)
+    assert len(kernel) == 1 and len(scatter) == 1
+    # each a branch of the conditional, and neither the step's own path
+    branches = re.search(r"branch_computations=\{%([\w.\-]+), %([\w.\-]+)\}",
+                         conds[0]).groups()
+    assert sorted(branches) == sorted(kernel + scatter)
+    entry = re.search(r"^ENTRY .*?^\}", text, re.M | re.S).group(0)
+    assert f"[{DENSE_IDS},9]" not in entry
+    assert not re.search(rf"s32\[{DENSE_IDS}\][^ ]*\) sort\(", entry)
+
+
+def _tiny_v3_dense_sum(one_chip):
+    """`_dense_sum`'s resident kernel at the bucket's real shape with the
+    walk in front of it, as `_dense_sum` calls it: it compiles, the
+    stream goes in and the `[rows, 8]` target comes out as bitcasts (a
+    copy of the stream would be 86 MB a step), and it keeps nothing but
+    the walk's ids beside its arguments."""
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    chunk, tile = pallas_tiled.dense_sum_blocks(DENSE_ROWS, 8)
+
+    def aggregate(ids, contribs):
+        kids, lo, hi, _ = pallas_tiled.dense_sum_walk(ids, DENSE_ROWS,
+                                                      chunk, tile)
+        return pallas_tiled.dense_sum(kids, lo, hi, contribs, DENSE_ROWS,
+                                      tile, interpret=False)
+
+    compiled = jax.jit(aggregate).lower(
+        S((DENSE_IDS,), I32), S((DENSE_IDS, 8), F32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not re.search(r" (copy|pad|transpose)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 25
+
+
+def _widest_dense_sum(one_chip, width):
+    """The resident kernel at a lane width that no cell has, over the most
+    rows `dense_sum_blocks` lets in at that width: the target's two
+    buffers AND a pair's operands, which grow with the width, fit the
+    chip's fast memory (the compiler refuses a kernel that overflows its
+    16 MiB), and one more tile of rows is refused by the rule."""
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tile = pallas_tiled.dense_sum_blocks(9_000, width)[1]
+    rows = pallas_tiled._DENSE_SUM_BYTES_MAX // (4 * (width + 8) * tile) * tile
+    assert pallas_tiled.dense_sum_blocks(rows, width) == (1024, tile)
+    assert pallas_tiled.dense_sum_blocks(rows + 1, width) is None
+    n = 64 * 1024 + 100
+
+    def aggregate(ids, contribs):
+        kids, lo, hi, _ = pallas_tiled.dense_sum_walk(ids, rows, 1024, tile)
+        return pallas_tiled.dense_sum(kids, lo, hi, contribs, rows, tile,
+                                      interpret=False)
+
+    compiled = jax.jit(aggregate).lower(
+        S((n,), I32), S((n, width), F32)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
 
 
 def _narrow_bucket_step(one_chip, monkeypatch):
@@ -356,7 +461,10 @@ def test_tile_stream_selection(backend, rows, width, n, want, monkeypatch):
     "kernel,width",
     [(k, w) for k in KERNELS for w in (16, 128)]
     + [("tiny_v3_step", None), ("tiny_v3_bucket_stream", 16),
-       ("narrow_bucket_step", 16),
+       ("tiny_v3_dense_sum", 8), ("narrow_bucket_step", 16),
+       ("widest_dense_sum", 16), ("widest_dense_sum", 32),
+       ("widest_dense_sum", 64), ("widest_dense_sum", 96),
+       ("widest_dense_sum", 104),
        ("mellum_experts", 2304), ("lfm2_experts", 2048)],
     ids=lambda v: str(v))
 def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
@@ -365,6 +473,12 @@ def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
         return
     if kernel == "tiny_v3_bucket_stream":
         _tiny_v3_bucket_stream(one_chip)
+        return
+    if kernel == "tiny_v3_dense_sum":
+        _tiny_v3_dense_sum(one_chip)
+        return
+    if kernel == "widest_dense_sum":
+        _widest_dense_sum(one_chip, width)
         return
     if kernel == "narrow_bucket_step":
         _narrow_bucket_step(one_chip, monkeypatch)

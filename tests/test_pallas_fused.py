@@ -710,15 +710,20 @@ def test_tile_stream_check_runs_where_a_chip_is_attached(monkeypatch):
     with no variable asking for it, at the lane widths and only there; a
     backend answered "tpu" over CPU devices (a described chip) runs
     nothing; `gate_verdicts` shows the fused family's check passed."""
-    ran = []
+    ran, summed = [], []
     check = su._KernelCheck(lambda cls: ran.append(cls) or True, "test")
     monkeypatch.setattr(su, "_TILE_STREAM_CHECK", check)
+    # the dense aggregate's resident kernel (ISSUE 41) is checked beside
+    # it, under adam too: both optimizers' dense branch sums through it
+    monkeypatch.setattr(su, "_DENSE_SUM_CHECK", su._KernelCheck(
+        lambda cls: summed.append(cls) or True, "test", classed=int))
     monkeypatch.setattr(su, "_PALLAS_FUSED_CHECK",
                         su._KernelCheck(lambda cls: True, "test"))
     monkeypatch.setattr(su.jax, "default_backend", lambda: "tpu")
     su.prevalidate_active_impl(strategy="auto", widths=(8, 16, 128),
                                kind="adagrad")
     assert ran == [] and su.gate_verdicts()["pallas"] == -1
+    assert summed == [] and su.gate_verdicts()["dense-sum"] == -1
 
     class Chip:
         platform = "tpu"
@@ -733,11 +738,54 @@ def test_tile_stream_check_runs_where_a_chip_is_attached(monkeypatch):
                                kind="adagrad")
     su.prevalidate_active_impl(widths=(16,), kind="adagrad")
     assert ran == [8, 16] and su.gate_verdicts()["pallas"] == 1
+    assert summed == [8, 16] and su.gate_verdicts()["dense-sum"] == 1
+    su.prevalidate_active_impl(widths=(8, 128), kind="adam")
+    assert ran == [8, 16] and summed == [8, 16]
+    su._DENSE_SUM_CHECK.validated.clear()
+    su.prevalidate_active_impl(widths=(8, 128), kind="adam")
+    assert ran == [8, 16] and summed == [8, 16, 8]
+
+
+@pytest.mark.parametrize("width,tile", [(24, 512), (96, 128), (120, None)])
+def test_dense_sum_check_runs_at_the_width_itself(width, tile, monkeypatch):
+    """The dense aggregate's check under an attached chip at a lane width
+    that is no power of two: it runs the kernel at that width (a pair's
+    operands are 3 w + 8 rows a block, so the pow2 class's form is
+    another kernel, and at 72-120 the class is 128: no lane width at
+    all) with the tile that `dense_sum_blocks` leaves room for, and runs
+    nothing where no tile fits. The real check, its kernel in interpret
+    mode; the tile stream's stays by class."""
+    from distributed_embeddings_tpu.ops import pallas_tiled
+    ran, tiles = [], []
+    monkeypatch.setattr(su, "_TILE_STREAM_CHECK", su._KernelCheck(
+        lambda cls: ran.append(cls) or True, "test"))
+    monkeypatch.setattr(su, "_DENSE_SUM_CHECK", su._KernelCheck(
+        su._validate_dense_sum, "test", classed=int))
+    dense_sum = pallas_tiled.dense_sum
+
+    def interpreted(kids, lo, hi, contribs, rows, tile, interpret):
+        assert interpret is False and contribs.shape[1] == width
+        tiles.append(tile)
+        return dense_sum(kids, lo, hi, contribs, rows, tile, interpret=True)
+
+    monkeypatch.setattr(pallas_tiled, "dense_sum", interpreted)
+    monkeypatch.setattr(su.jax, "default_backend", lambda: "tpu")
+
+    class Chip:
+        platform = "tpu"
+
+    monkeypatch.setattr(su.jax, "devices", lambda: [Chip()])
+    su.prevalidate_active_impl(widths=(width,), kind="adagrad")
+    assert ran == [su._width_class(width)]
+    assert tiles == ([tile] if tile else [])
+    assert su._DENSE_SUM_CHECK.validated == ({width} if tile else set())
+    assert (su._dense_walk(60_160 * 8 // width // 2, width, 4096)
+            is None) == (tile is None)
 
 
 def test_gate_verdicts_shape():
     v = su.gate_verdicts()
-    assert set(v) == {"tiled", "pallas", "pallas-dma"}
+    assert set(v) == {"tiled", "pallas", "pallas-dma", "dense-sum"}
     assert all(x in (-1, 0, 1) for x in v.values())
 
 

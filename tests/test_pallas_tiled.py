@@ -387,3 +387,106 @@ def test_tiled_bf16_table():
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=5e-2, atol=1e-1)
+
+
+# ---- the dense aggregate's resident kernel (ISSUE 41) vs XLA's scatter-add
+def _dense_stream(kind, rows, n, rng):
+    """An id stream of n slots into a target of `rows` rows."""
+    if kind == "feature_major":
+        # runs of 512 slots, each inside one small table's rows of the
+        # bucket, nearly every slot a duplicate: what a narrow bucket's
+        # feature-major stream looks like
+        sizes = np.array([10, 10, 1000, 10, 1000, 10000])
+        starts = np.minimum(np.concatenate([[0], np.cumsum(sizes)[:-1]]),
+                            rows - sizes.clip(max=rows))
+        which = np.repeat(rng.randint(0, len(sizes), -(-n // 512)), 512)[:n]
+        return starts[which] + rng.zipf(1.3, n) % np.minimum(sizes[which],
+                                                             rows)
+    if kind == "every_tile":
+        # every chunk names the first and the last row and some between
+        ids = rng.randint(0, rows, n)
+        ids[::128], ids[1::128] = 0, rows - 1
+        return ids
+    assert kind == "out_of_range"
+    ids = rng.randint(0, rows, n)
+    ids[::5] = -1 - rng.randint(0, 9, len(ids[::5]))
+    ids[3::7] = rows + rng.randint(0, 9, len(ids[3::7]))
+    return ids
+
+
+@pytest.mark.parametrize("width", [8, 16])
+@pytest.mark.parametrize("kind,rows,n,tile", [
+    ("feature_major", 12160, 4096, 1024),
+    ("every_tile", 2048, 1536, 256),
+    ("out_of_range", 1000, 1111, 256),      # a length that is no whole chunk
+    ("feature_major", 12043, 2048, 512),    # rows that are no whole tile
+    ("every_tile", 300, 100, 2048),         # one tile, one short chunk
+])
+def test_dense_sum_matches_the_scatter(kind, rows, n, tile, width):
+    """`pallas_tiled.dense_sum`, the whole target resident, against the
+    XLA lines it stands in for (`sparse_update._scatter_sum`): counts
+    exactly, sums to an f32 sum's order, invalid ids dropped."""
+    rng = np.random.RandomState(rows + n + width)
+    ids = jnp.asarray(_dense_stream(kind, rows, n, rng).astype(np.int32))
+    contribs = jnp.asarray(rng.randn(n, width).astype(np.float32))
+    tile = min(tile, -(-rows // 128) * 128)
+    kids, lo, hi, pairs = pt.dense_sum_walk(ids, rows, 256, tile)
+    g, counts = pt.dense_sum(kids, lo, hi, contribs, rows, tile,
+                             interpret=True)
+    g_want, counts_want = su._scatter_sum(ids, contribs, rows)
+    assert g.shape == (rows, width) and counts.shape == (rows,)
+    np.testing.assert_array_equal(counts, counts_want)
+    np.testing.assert_allclose(g, g_want, rtol=1e-5, atol=1e-5)
+    # the walk pairs a chunk with the tiles between its least and its
+    # greatest valid id, and with none where it has no valid id
+    chunks = np.full(kids.size, -1)         # padded to whole grid steps
+    chunks[:n] = np.asarray(ids)
+    for c, l, h in zip(chunks.reshape(-1, 256), np.asarray(lo),
+                       np.asarray(hi)):
+        ok = c[(c >= 0) & (c < rows)]
+        assert (l, h) == ((ok.min() // tile, ok.max() // tile)
+                          if len(ok) else (0, -1))
+    assert int(pairs) == int(np.sum(np.asarray(hi) - np.asarray(lo) + 1))
+
+
+@pytest.mark.parametrize("width", [24, 32, 64, 96, 104])
+def test_dense_sum_at_the_wider_lane_widths(width):
+    """The kernel at the lane widths no cell has, with the tile that
+    `dense_sum_blocks` cuts to a pair's operands (3 w + 8 rows a block):
+    the scatter's counts and sums, over runs inside one small table and a
+    run across the whole target."""
+    rng = np.random.RandomState(width)
+    rows, n = 3000, 2500
+    chunk, tile = pt.dense_sum_blocks(rows, width)
+    ids = np.concatenate([
+        _dense_stream("feature_major", rows, 2048, rng),
+        _dense_stream("out_of_range", rows, n - 2048, rng)]).astype(np.int32)
+    contribs = jnp.asarray(rng.randn(n, width).astype(np.float32))
+    kids, lo, hi, _ = pt.dense_sum_walk(jnp.asarray(ids), rows, chunk, tile)
+    g, counts = pt.dense_sum(kids, lo, hi, contribs, rows, tile,
+                             interpret=True)
+    g_want, counts_want = su._scatter_sum(jnp.asarray(ids), contribs, rows)
+    np.testing.assert_array_equal(counts, counts_want)
+    np.testing.assert_allclose(g, g_want, rtol=1e-5, atol=1e-5)
+
+
+def test_dense_sum_blocks_fit_fast_memory():
+    """The target with its count rows, and a pair's operands, each stay
+    under their share of fast memory, or no stream reaches the kernel;
+    Tiny V3's width-8 bucket fits, and the tile is cut where the width
+    makes a pair's operands grow."""
+    chunk, tile = pt.dense_sum_blocks(60160, 8)
+    assert (chunk, tile) == (1024, 1024)
+    assert -(-60160 // tile) * 16 * tile * 4 <= pt._DENSE_SUM_BYTES_MAX
+    assert pt.dense_sum_blocks(100, 8) == (chunk, 128)
+    assert pt.dense_sum_blocks(2_000_000, 8) is None
+    assert pt.dense_sum_blocks(60160, 64) is None
+    tiles = {w: pt.dense_sum_blocks(9_000, w) for w in range(8, 128, 8)}
+    assert [tiles[w] and tiles[w][1] for w in (8, 16, 24, 32, 64, 96, 104)] \
+        == [1024, 1024, 512, 512, 256, 128, 128]
+    assert tiles[112] is None and tiles[120] is None
+    for w, blocks in tiles.items():
+        if blocks:
+            assert pt._dense_pair_bytes(w, blocks[1]) \
+                <= pt._DENSE_PAIR_BYTES_MAX < pt._dense_pair_bytes(
+                    w, 2 * blocks[1]) or blocks[1] == 1024

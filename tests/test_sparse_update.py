@@ -290,3 +290,143 @@ def test_sparse_adagrad_traced_lr(monkeypatch):
                                rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(np.asarray(a2), np.asarray(want_a),
                                rtol=1e-6, atol=1e-6)
+
+
+# ---- the dense aggregate on a TPU: the resident kernel or the scatter
+def _as_a_tpu(monkeypatch):
+    """What a TPU backend is answered, on the CPU: the kernels in
+    interpret mode."""
+    from distributed_embeddings_tpu.ops import pallas_tiled
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pallas_tiled, "_BACKEND_INTERPRET", True)
+
+
+def _bucket_streams(rng, rows, n, chunk):
+    """Two streams of n slots into Tiny V3's width-8 bucket: runs of a
+    chunk's slots inside one small table's rows (feature-major), and the
+    same slots with the first and the last row in every chunk (what a
+    batch-major stream does to a chunk's span)."""
+    starts = rng.integers(0, rows - 1000, size=n // chunk)
+    near = (np.repeat(starts, chunk)
+            + rng.integers(0, 1000, size=n)).astype(np.int32)
+    far = near.copy()
+    far[::chunk], far[1::chunk] = 0, rows - 1
+    return near, far
+
+
+def test_dense_sum_picks_by_the_streams_span(monkeypatch):
+    """The run-time rule of `_dense_sum` (ISSUE 41): the pairs a stream's
+    min/max walk finds, against the count at which the kernel and XLA's
+    scatter-add cost the same for the shapes. A stream whose chunks each
+    name one table's rows takes the kernel, one whose chunks span the
+    whole target the scatter; `dense_sum_pairs` says which, and either
+    way the sums and counts are the scatter's."""
+    rows, width, n = 60160, 8, 16384
+    rng = np.random.default_rng(41)
+    chunk, tile, most = su._dense_walk(rows, width, n)
+    near, far = _bucket_streams(rng, rows, n, chunk)
+    contribs = jnp.asarray(rng.standard_normal((n, width)), jnp.float32)
+    n_chunks, n_tiles = n // chunk, -(-rows // tile)
+    assert n_chunks * n_tiles > most > 2 * n_chunks
+    pairs_near, kernel_near = su.dense_sum_pairs(jnp.asarray(near), rows,
+                                                 width)
+    pairs_far, kernel_far = su.dense_sum_pairs(jnp.asarray(far), rows, width)
+    assert n_chunks <= int(pairs_near) <= 2 * n_chunks
+    assert int(pairs_far) == n_chunks * n_tiles
+    assert (int(kernel_near), int(kernel_far)) == (1, 0)
+    _as_a_tpu(monkeypatch)
+    traced = jax.make_jaxpr(lambda i, c: su._dense_sum(i, c, rows))(
+        jnp.asarray(near), contribs)
+    assert "cond" in str(traced) and "pallas_call" in str(traced)
+    for ids in (near, far):
+        ids = jnp.asarray(ids)
+        g, counts = jax.jit(lambda i, c: su._dense_sum(i, c, rows))(
+            ids, contribs)
+        g_want, counts_want = su._scatter_sum(ids, contribs, rows)
+        np.testing.assert_array_equal(counts, counts_want)
+        np.testing.assert_allclose(g, g_want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("backend,rows,width,strategy,kind", [
+    ("tpu", 64, 128, "auto", "adagrad"),      # a row-major target
+    # `lfm2.packed-4k`'s table: `sparse_adam`'s dense branch, 64 MB
+    ("tpu", 8192, 2048, "auto", "adam"),
+    ("cpu", 60160, 8, "auto", "adagrad"),
+    ("tpu", 60160, 8, "sort", "adagrad"),     # a strategy is the request
+    ("tpu", 60160, 8, "dense", "adam"),
+    ("tpu", 1_000_000, 8, "auto", "adagrad"),  # 64 MB of target
+])
+def test_dense_sum_kernel_is_not_reached(backend, rows, width, strategy,
+                                         kind, monkeypatch):
+    """Who keeps XLA's scatter-add: a wide target, the CPU, an explicit
+    strategy, a target over the kernel's share of fast memory, a stream
+    of under one chunk. The rule
+    answers None and the traced update holds no kernel; the Tiny bucket's
+    shape under "auto" on a TPU is the control, for both optimizers."""
+    _as_a_tpu(monkeypatch)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    n = 2048
+
+    def traced(rows, width, strategy, kind):
+        table = jnp.zeros((rows, width), jnp.float32)
+        grad = su.SparseRowGrad(jnp.zeros((n,), jnp.int32),
+                                jnp.ones((n, width), jnp.float32))
+        if kind == "adam":
+            return str(jax.make_jaxpr(lambda t, g: su.sparse_adam(
+                t, t, t, jnp.int32(0), g, 0.01, strategy=strategy))(
+                    table, grad))
+        return str(jax.make_jaxpr(lambda t, g: su.sparse_adagrad(
+            t, t, g, 0.01, strategy=strategy))(table, grad))
+
+    assert su._dense_kernel(strategy, rows, width, n) is None
+    assert "pallas_call" not in traced(rows, width, strategy, kind)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    chunk, _, most = su._dense_kernel("auto", 60160, 8, n)
+    assert most > n // chunk
+    assert "pallas_call" in traced(60160, 8, "auto", kind)
+    # a stream under one chunk of slots: the scatter costs a launch. The
+    # same floor keeps `tests/benchmark/test_benchmark_datadriven.py::
+    # test_describe_chip_lowers_the_cells_own_batch` lowering: it stands a
+    # chip in by answering "tpu" over CPU devices, where no kernel can
+    # lower, and its narrow adam cell has 256 id slots. Whoever shrinks
+    # `_DENSE_CHUNK` under that meets it there
+    assert su._dense_kernel("auto", 60160, 8, chunk - 1) is None
+    assert su._dense_kernel("auto", 512, 16, 256) is None
+
+
+# (width, rows, id slots, pairs walked, scatter ms, kernel ms) of
+# `tools/tpu_dense_sum_sweep.py --widths 8,16,32,64,96,104` on one v5e chip
+# (my run, PR 41): Tiny V3's width-8 bucket's tables, cut to what fits at
+# the width, feature-major and then batch-major
+SWEEP = [
+    (8, 60160, 2686976, 12096, 49.2, 6.9),
+    (8, 60160, 2686976, 153809, 49.2, 74.9),
+    (16, 40160, 2555904, 10752, 46.6, 9.3),
+    (16, 40160, 2555904, 97717, 46.6, 73.7),
+    (32, 20160, 2424832, 17024, 18.6, 13.3),
+    (32, 20160, 2424832, 94674, 18.4, 67.8),
+    (64, 14160, 2031616, 30197, 16.2, 22.3),
+    (64, 14160, 2031616, 110783, 16.1, 76.6),
+    (96, 9160, 1638400, 6144, 13.5, 4.8),
+    (96, 9160, 1638400, 114966, 13.5, 63.1),
+    (104, 9160, 1638400, 6144, 13.7, 5.0),
+    (104, 9160, 1638400, 114996, 13.5, 66.4),
+]
+
+
+@pytest.mark.parametrize("width,rows,n,pairs,scatter_ms,kernel_ms", SWEEP)
+def test_dense_walk_prices_what_the_chip_read(width, rows, n, pairs,
+                                              scatter_ms, kernel_ms):
+    """`_dense_walk`'s two prices against the chip's readings: the
+    scatter's by the row, the kernel's by the pair (less the walk's 1 ms,
+    paid either way), each to 15%, and the count that decides between
+    them picks the one that won: at every width but 8 a rule that priced
+    the scatter at width 8's 1.7 ns an element picked the kernel for all
+    of these, and it lost five of the ten."""
+    from distributed_embeddings_tpu.ops import pallas_tiled
+    chunk, tile, most = su._dense_walk(rows, width, n)
+    assert su._scatter_ns_per_row(width) * n / 1e6 == pytest.approx(
+        scatter_ms, rel=0.15)
+    assert pairs * pallas_tiled.dense_sum_pair_ns(chunk, tile, width) / 1e6 \
+        == pytest.approx(kernel_ms - 1.0, rel=0.15)
+    assert (pairs <= most) == (kernel_ms < scatter_ms)
