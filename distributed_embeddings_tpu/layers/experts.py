@@ -127,7 +127,8 @@ SIGMOID_NORM_EPS = 1e-6
 
 class Routing(NamedTuple):
     """A batch's routing: per token its `top_k` experts, ascending by score
-    rank, and their weights, renormalised to sum to 1."""
+    rank, and their weights, renormalised to sum to 1 (times the layer's
+    `routed_scale`)."""
     experts: jax.Array      # [T, top_k] int32, over all experts
     weights: jax.Array      # [T, top_k] f32
 
@@ -149,10 +150,16 @@ class ExpertLayer:
         `top_k` are taken of ``score + bias`` and weighted by the scores
         alone. A buffer that balances the experts' load from outside the
         loss: no gradient reaches it, and this layer never changes it.
+      routed_scale: the renormalised weights are multiplied by it (a
+        config's ``routed_scaling_factor``); 1 multiplies nothing.
+      norm_eps: what the sigmoid rule's renormalisation adds to the chosen
+        scores' sum.
     """
 
     def __init__(self, hidden: int, width: int, num_experts_total: int,
-                 held: Sequence[int], top_k: int, router: str = "softmax"):
+                 held: Sequence[int], top_k: int, router: str = "softmax",
+                 routed_scale: float = 1.0,
+                 norm_eps: float = SIGMOID_NORM_EPS):
         given = list(held)
         held = range(given[0], given[0] + len(given)) if given else range(0)
         if not (given and given == list(held) and 0 <= held.start
@@ -167,6 +174,7 @@ class ExpertLayer:
         self.num_experts_total, self.held, self.top_k = (
             num_experts_total, held, top_k)
         self.router = router
+        self.routed_scale, self.norm_eps = routed_scale, norm_eps
 
     def init(self, key, std: float = 0.02, down_std: float = None,
              bias_range: float = 0.0) -> dict:
@@ -191,22 +199,23 @@ class ExpertLayer:
               bias: jax.Array = None) -> Routing:
         """The router's rule over all experts (`ROUTERS`): the `top_k`
         largest, of ``score + bias`` where a selection bias is given, and
-        their scores renormalised."""
+        their scores renormalised, then scaled by `routed_scale`."""
         with stage("router"):
             if self.router == "softmax":
                 scores = jax.nn.softmax(x @ router, axis=-1)
                 top, experts = lax.top_k(scores, self.top_k)
-                return Routing(experts.astype(jnp.int32),
-                               top / jnp.sum(top, axis=-1, keepdims=True))
-            scores = jax.nn.sigmoid(x @ router)
-            ranked = (scores if bias is None
-                      else scores + lax.stop_gradient(bias))
-            _, experts = lax.top_k(ranked, self.top_k)
-            top = jnp.take_along_axis(scores, experts, axis=-1)
-            return Routing(
-                experts.astype(jnp.int32),
-                top / (jnp.sum(top, axis=-1, keepdims=True)
-                       + SIGMOID_NORM_EPS))
+                weights = top / jnp.sum(top, axis=-1, keepdims=True)
+            else:
+                scores = jax.nn.sigmoid(x @ router)
+                ranked = (scores if bias is None
+                          else scores + lax.stop_gradient(bias))
+                _, experts = lax.top_k(ranked, self.top_k)
+                top = jnp.take_along_axis(scores, experts, axis=-1)
+                weights = top / (jnp.sum(top, axis=-1, keepdims=True)
+                                 + self.norm_eps)
+            if self.routed_scale != 1:
+                weights = weights * self.routed_scale
+            return Routing(experts.astype(jnp.int32), weights)
 
     def __call__(self, params: dict, x: jax.Array) -> jax.Array:
         """``[T, hidden] -> [T, hidden]``: the held experts' part of the

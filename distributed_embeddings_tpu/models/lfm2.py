@@ -34,7 +34,7 @@ from distributed_embeddings_tpu.layers.embedding import Embedding
 from distributed_embeddings_tpu.layers.experts import ExpertLayer
 from distributed_embeddings_tpu.models.mellum import (
     INIT_STD, _attend_blocks, _normal_init, _rms_norm, _rotate, _table_init,
-    embed_tokens, head_loss, packed_mask_terms, rotary_frequencies)
+    embed_tokens, head_loss, packed_mask_terms, rotary_frequencies, swiglu)
 from distributed_embeddings_tpu.obs.spans import spanned
 from distributed_embeddings_tpu.obs.stages import stage
 
@@ -196,8 +196,7 @@ class Lfm2:
     def _dense_mlp(self, layer, x):
         with stage("mlp"):
             normed = _rms_norm(x, layer["ffn_norm"], self.norm_eps)
-            return x + (jax.nn.silu(normed @ layer["w1"])
-                        * (normed @ layer["w3"])) @ layer["w2"]
+            return x + swiglu(normed, layer["w1"], layer["w3"], layer["w2"])
 
     def _sparse_mlp(self, layer, x):
         # the expert layer opens its own two stages

@@ -126,6 +126,12 @@ def _rms_norm(x, weight, eps):
                         + eps) * weight
 
 
+def swiglu(x, gate, up, down):
+    """``(silu(x gate) * (x up)) down``: a dense SwiGLU block's three
+    products."""
+    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
 def embed_tokens(embedding, params, cats, taps, return_residuals):
     """(the tokens' rows ``[T, hidden]``, the lookup's residuals or None):
     the one-table embedding as `make_sparse_train_step`'s ``loss_fn`` calls

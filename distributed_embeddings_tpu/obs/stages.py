@@ -33,12 +33,18 @@ do. They are declared here too, in `MODEL_STAGES`, apart from the engine's
 eight: a step over a model without such a block holds none of them.
 
   attn       a transformer block's attention: projections, rotary, scores
+  latent     inside ``attn``, and winning there: what multi-head latent
+             attention puts in front of the scores (the two down-projections
+             with their norms, the two up-projections, the rotary on the
+             narrow parts, the assembly of q, k and v)
   shortconv  a gated short convolution: in-projection, gates, the taps over
              packed documents, out-projection
   mlp        a dense SwiGLU block
   router     an expert layer's scores over all experts and the top-k choice
   experts    the held experts' part: pair sort, gathers, grouped products,
              the weighted combine
+  shared     a shared expert: the SwiGLU every token passes beside its routed
+             experts
   head       final norm, logits over the vocabulary's slice, the loss
 """
 
@@ -51,7 +57,8 @@ __all__ = ["MODEL_STAGES", "PREFIX", "STAGES", "STEP_NAME", "stage", "staged"]
 PREFIX = "det."
 STAGES = ("ids", "lookup", "acts", "model", "dense_opt", "contrib", "dedup",
           "apply")
-MODEL_STAGES = ("attn", "shortconv", "mlp", "router", "experts", "head")
+MODEL_STAGES = ("attn", "latent", "shortconv", "mlp", "router", "experts",
+                "shared", "head")
 # the jitted train steps' function name: traces say jit(det_train_step) and
 # the compiled module is jit_det_train_step
 STEP_NAME = "det_train_step"

@@ -437,6 +437,77 @@ def _cell_experts(one_chip, monkeypatch, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 30
 
 
+CHIP_GIB = 15.75                # what one v5e chip's allocator hands out
+
+
+def _glm47_step_and_check_fit(one_chip, monkeypatch):
+    """`glm47-flash.packed-4k` at its real size (ISSUE 42): the training
+    step as the cell dispatches it, and the program the CHECK runs beside
+    the program's parameters, which is what caps a token cell's size (a
+    transcription of `benchmark/reference.py::train_steps`' `dense_step`:
+    the plain reference's loss and gradient twice, adam over the whole dense
+    tree, nothing donated). Each must fit a chip; 14.82 GiB ran and 15.83
+    died for `lfm2.packed-4k` (PERF.md section 6, PR 38)."""
+    import numpy as np
+
+    from benchmark import reference
+    from benchmark.harness import spec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = spec.load_cell("glm47-flash.packed-4k")
+    built = spec.plugin("builders", cell.config["builder"]).build(
+        cell.config, None, False)
+    tokens, length = built.global_batch, built.num_numerical
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def live_gib(compiled):
+        m = compiled.memory_analysis()
+        return (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes - m.alias_size_in_bytes) / 2 ** 30
+
+    params = place(jax.eval_shape(built.model.init, jax.random.PRNGKey(0)))
+    positions, next_ids = S((tokens // length, length), I32), S((tokens,), I32)
+    init_fn, step_fn = built.make_step()
+    step = step_fn.lower(params, place(jax.eval_shape(init_fn, params)),
+                         positions, [S((tokens,), I32)], next_ids).compile()
+    text = step.as_text()
+    assert "det.latent" in text and "det.shared" in text
+    assert text.count("tpu_custom_call") >= 4 * 9    # the experts' products
+    assert live_gib(step) < CHIP_GIB - 2, "the step and its window's batches"
+
+    model_loss = spec.plugin("references", built.reference).loss
+    dense = built.dense_params(params)
+
+    def dense_step(embs, dense, d_state, lr, inputs, labels):
+        def loss_fn(embs, dense):
+            return model_loss(dense, embs, inputs, labels)
+
+        loss, (g_embs, g_dense) = jax.value_and_grad(
+            loss_fn, argnums=(0, 1))(embs, dense)
+        with jax.default_matmul_precision("highest"):
+            loss_high, g_high = jax.value_and_grad(loss_fn)(embs, dense)
+        share = (jnp.sum(jnp.abs(g_embs[0] - g_high[0]))
+                 / jnp.maximum(jnp.sum(jnp.abs(g_high[0])), 1e-30))
+        dense, d_state = reference.apply_rule(built.optimizer, lr, dense,
+                                              g_dense, d_state)[:2]
+        return loss, loss_high, share, g_embs, dense, d_state
+
+    d_state = {"count": S((), I32), "mu": dense, "nu": dense}
+    check = jax.jit(dense_step).lower(
+        [S((tokens, cell.config["hidden_size"]), F32)], dense, d_state,
+        S((), F32), positions, next_ids).compile()
+    held = 4 * sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
+    assert live_gib(check) + held / 2 ** 30 < CHIP_GIB - 0.5, (
+        f"the check needs {live_gib(check):.2f} GiB beside the program's "
+        f"{held / 2 ** 30:.2f}")
+
+
 @pytest.mark.parametrize("backend,rows,width,n,want", [
     ("tpu", BUCKET_ROWS, 16, BUCKET_IDS, "pallas"),
     ("tpu", BUCKET_ROWS, 128, BUCKET_IDS, "xla"),       # a row-major table
@@ -465,7 +536,8 @@ def test_tile_stream_selection(backend, rows, width, n, want, monkeypatch):
        ("widest_dense_sum", 16), ("widest_dense_sum", 32),
        ("widest_dense_sum", 64), ("widest_dense_sum", 96),
        ("widest_dense_sum", 104),
-       ("mellum_experts", 2304), ("lfm2_experts", 2048)],
+       ("mellum_experts", 2304), ("lfm2_experts", 2048),
+       ("glm47_step_and_check", 2048)],
     ids=lambda v: str(v))
 def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
     if kernel == "tiny_v3_step":
@@ -485,6 +557,9 @@ def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
         return
     if kernel in ("mellum_experts", "lfm2_experts"):
         _cell_experts(one_chip, monkeypatch, kernel)
+        return
+    if kernel == "glm47_step_and_check":
+        _glm47_step_and_check_fit(one_chip, monkeypatch)
         return
 
     def S(shape, dtype):
