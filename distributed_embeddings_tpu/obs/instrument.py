@@ -15,7 +15,8 @@ from typing import Optional
 from distributed_embeddings_tpu.obs.registry import MetricRegistry
 
 __all__ = ["export_exchange_gauges", "export_kernel_gauges",
-           "export_moe_gauges", "export_update_gauges",
+           "export_diffusion_gauges", "export_moe_gauges",
+           "export_update_gauges",
            "EXCHANGE_GAUGE_FIELDS", "EXCHANGE_GROUP_GAUGE_FIELDS"]
 
 
@@ -51,6 +52,24 @@ def export_moe_gauges(registry: MetricRegistry, stats: dict) -> dict:
         out[name] = [float(v) for v in per_layer]
         for layer, value in enumerate(out[name]):
             registry.gauge(f"moe/{name}", layer=layer).set(value)
+    return out
+
+
+def export_diffusion_gauges(registry: MetricRegistry, stats: dict) -> dict:
+    """Set ``diffusion/masked_share``, ``diffusion/mask_row_share`` and
+    ``attn/visited_pair_share{mask=block_diffusion}`` from one batch's
+    `models.sdar.Sdar.diffusion_stats` (jitted and forward only): the
+    masked noisy positions over the noisy positions, which is the part of
+    the noisy stream the loss reads; the step's lookups that hit the mask
+    id's row, the one row a sparse update sums most duplicates into; and
+    the (query, key) pairs the block-diffusion mask keeps over the pairs of
+    the score tiles the step computes. A host read of a device result: call
+    it beside a loss fetch, not every step. Returns ``{name: float}``."""
+    out = {name: float(value) for name, value in stats.items()}
+    registry.gauge("diffusion/masked_share").set(out["masked_share"])
+    registry.gauge("diffusion/mask_row_share").set(out["mask_row_share"])
+    registry.gauge("attn/visited_pair_share", mask="block_diffusion").set(
+        out["visited_pair_share"])
     return out
 
 
