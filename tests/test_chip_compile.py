@@ -14,11 +14,13 @@ case pins that refusal instead.
 
 A compile that passes here is not a chip run: `chip_smoke.py` is.
 
-This is the ONLY file that describes the chip, and it does so inside a
-module-scoped fixture: one process at a time may hold the TPU library, the
-suite runs under several workers, and only the worker that is handed this
-file may load it. Nothing here touches the topology at import, in a skipif
-or in a parametrize argument.
+The chip is described inside a module-scoped fixture, which the token
+cells' cases share (`test_chip_compile_cells.py`, `test_chip_compile_check.py`:
+a whole program's compile each, in files of their own so that the suite's
+workers, handed a file each, run them side by side): only a worker handed
+one of these files loads the TPU library, and the suite lets several
+processes load it (`ALLOW_MULTIPLE_LIBTPU_LOAD`). Nothing here touches the
+topology at import, in a skipif or in a parametrize argument.
 """
 
 import math
@@ -476,106 +478,6 @@ def _tiny_v3_bucket_stream(one_chip):
     assert m.alias_size_in_bytes >= 2 * BUCKET_ROWS * 16 * 4
 
 
-def _cell_experts(one_chip, monkeypatch, cell):
-    """The expert layer at a cell's size (`mellum2.packed-4k`: 16,384
-    tokens of width 2,304, 8 of 64 experts of width 896 held, top 8, a
-    softmax router; `lfm2.packed-4k`: width 2,048, experts of 1,536, top 4,
-    a sigmoid router with its selection bias), forward and gradient: the
-    library's grouped-product kernels at this repo's tilings, and both
-    branches of the bounded rows."""
-    from distributed_embeddings_tpu.layers.experts import ExpertLayer
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    layer = {"mellum_experts": ExpertLayer(2304, 896, 64, range(8), 8),
-             "lfm2_experts": ExpertLayer(2048, 1536, 64, range(8), 4,
-                                         router="sigmoid")}[cell]
-
-    def place(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-
-    params = place(jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
-    x = jax.ShapeDtypeStruct((16384, layer.hidden), F32, sharding=one_chip)
-    compiled = jax.jit(jax.grad(
-        lambda p, x: jnp.sum(layer(p, x)), argnums=(0, 1))).lower(
-            params, x).compile()
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 2 * 9    # 3 products, 3 passes
-    assert "conditional(" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 30
-
-
-CHIP_GIB = 15.75                # what one v5e chip's allocator hands out
-
-
-def _glm47_step_and_check_fit(one_chip, monkeypatch):
-    """`glm47-flash.packed-4k` at its real size (ISSUE 42): the training
-    step as the cell dispatches it, and the program the CHECK runs beside
-    the program's parameters, which is what caps a token cell's size (a
-    transcription of `benchmark/reference.py::train_steps`' `dense_step`:
-    the plain reference's loss and gradient twice, adam over the whole dense
-    tree, nothing donated). Each must fit a chip; 14.82 GiB ran and 15.83
-    died for `lfm2.packed-4k` (PERF.md section 6, PR 38)."""
-    import numpy as np
-
-    from benchmark import reference
-    from benchmark.harness import spec
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cell = spec.load_cell("glm47-flash.packed-4k")
-    built = spec.plugin("builders", cell.config["builder"]).build(
-        cell.config, None, False)
-    tokens, length = built.global_batch, built.num_numerical
-
-    def place(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-
-    def S(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    def live_gib(compiled):
-        m = compiled.memory_analysis()
-        return (m.argument_size_in_bytes + m.output_size_in_bytes
-                + m.temp_size_in_bytes - m.alias_size_in_bytes) / 2 ** 30
-
-    params = place(jax.eval_shape(built.model.init, jax.random.PRNGKey(0)))
-    positions, next_ids = S((tokens // length, length), I32), S((tokens,), I32)
-    init_fn, step_fn = built.make_step()
-    step = step_fn.lower(params, place(jax.eval_shape(init_fn, params)),
-                         positions, [S((tokens,), I32)], next_ids).compile()
-    text = step.as_text()
-    assert "det.latent" in text and "det.shared" in text
-    assert text.count("tpu_custom_call") >= 4 * 9    # the experts' products
-    assert live_gib(step) < CHIP_GIB - 2, "the step and its window's batches"
-
-    model_loss = spec.plugin("references", built.reference).loss
-    dense = built.dense_params(params)
-
-    def dense_step(embs, dense, d_state, lr, inputs, labels):
-        def loss_fn(embs, dense):
-            return model_loss(dense, embs, inputs, labels)
-
-        loss, (g_embs, g_dense) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1))(embs, dense)
-        with jax.default_matmul_precision("highest"):
-            loss_high, g_high = jax.value_and_grad(loss_fn)(embs, dense)
-        share = (jnp.sum(jnp.abs(g_embs[0] - g_high[0]))
-                 / jnp.maximum(jnp.sum(jnp.abs(g_high[0])), 1e-30))
-        dense, d_state = reference.apply_rule(built.optimizer, lr, dense,
-                                              g_dense, d_state)[:2]
-        return loss, loss_high, share, g_embs, dense, d_state
-
-    d_state = {"count": S((), I32), "mu": dense, "nu": dense}
-    check = jax.jit(dense_step).lower(
-        [S((tokens, cell.config["hidden_size"]), F32)], dense, d_state,
-        S((), F32), positions, next_ids).compile()
-    held = 4 * sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
-    assert live_gib(check) + held / 2 ** 30 < CHIP_GIB - 0.5, (
-        f"the check needs {live_gib(check):.2f} GiB beside the program's "
-        f"{held / 2 ** 30:.2f}")
-
-
 @pytest.mark.parametrize("backend,rows,width,n,want", [
     ("tpu", BUCKET_ROWS, 16, BUCKET_IDS, "pallas"),
     ("tpu", BUCKET_ROWS, 128, BUCKET_IDS, "xla"),       # a row-major table
@@ -604,9 +506,7 @@ def test_tile_stream_selection(backend, rows, width, n, want, monkeypatch):
        ("tiny_v3_dense_sum", 8), ("narrow_bucket_step", 16),
        ("widest_dense_sum", 16), ("widest_dense_sum", 32),
        ("widest_dense_sum", 64), ("widest_dense_sum", 96),
-       ("widest_dense_sum", 104),
-       ("mellum_experts", 2304), ("lfm2_experts", 2048),
-       ("glm47_step_and_check", 2048)],
+       ("widest_dense_sum", 104)],
     ids=lambda v: str(v))
 def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
     if kernel == "tiny_v3_step":
@@ -626,12 +526,6 @@ def test_compiles_for_described_v5e(kernel, width, one_chip, monkeypatch):
         return
     if kernel == "narrow_bucket_step":
         _narrow_bucket_step(one_chip, monkeypatch)
-        return
-    if kernel in ("mellum_experts", "lfm2_experts"):
-        _cell_experts(one_chip, monkeypatch, kernel)
-        return
-    if kernel == "glm47_step_and_check":
-        _glm47_step_and_check_fit(one_chip, monkeypatch)
         return
 
     def S(shape, dtype):

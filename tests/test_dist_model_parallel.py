@@ -51,6 +51,10 @@ def ref_apply(weights, inputs, table_map, combiners):
     return outs
 
 
+# one program per shape and placement, shared by every case of the worker
+_sgd = jax.jit(lambda p, g: p - LR * g)
+
+
 def _sgd_respecting_placement(p, g):
     """p - LR*g, keeping offloaded (pinned-host) params in their memory
     space: the update runs in device space, the result is placed back."""
@@ -68,15 +72,15 @@ def _sgd_respecting_placement(p, g):
         if getattr(getattr(g, "sharding", None), "memory_kind", None) == \
                 "pinned_host":
             gd = jax.device_put(g, dev_sharding(g))
-        return jax.device_put(pd - LR * gd, p.sharding)
-    return p - LR * g
+        return jax.device_put(_sgd(pd, gd), p.sharding)
+    return _sgd(p, g)
 
 
 def check_equivalence(specs, world=8, input_table_map=None, inputs=None,
                       seed=0, check_train=True, input_max_hotness=None,
                       rtol=1e-5, atol=1e-5, train_rtol=1e-4, train_atol=1e-5,
                       store_roundtrip=False, vocab_axis=False,
-                      lookahead_axis=False,
+                      lookahead_axis=False, eager=False,
                       **dist_kwargs):
     """specs: list of (vocab, width) or (vocab, width, combiner).
 
@@ -99,7 +103,10 @@ def check_equivalence(specs, world=8, input_table_map=None, inputs=None,
     property also holds for dynamically-bound vocabularies (the
     reference model sees the same translated rows over zero-padded
     tables; what this axis exercises is the slack plan + binding
-    composition, per-table and shared-table alike)."""
+    composition, per-table and shared-table alike).
+
+    eager: run the forward and gradient comparisons op by op, as a caller
+    who never jits the layer does, instead of as one program each."""
     rng = np.random.RandomState(seed)
     embeddings = []
     combiners = []
@@ -189,9 +196,13 @@ def check_equivalence(specs, world=8, input_table_map=None, inputs=None,
             st.publish(stream_dir)
             params = restore_from_published(dist, stream_dir).params
 
+    # each side is one program (the batch is a constant of it), not one
+    # program per operation; `eager` keeps the op-by-op public mode
+    compiled = (lambda f: f) if eager else jax.jit
     ref_w = [jnp.asarray(w) for w in weights]
-    ref_outs = ref_apply(ref_w, inputs, table_map, combiners)
-    dist_outs = dist.apply(params, inputs)
+    ref_outs = compiled(
+        lambda ws: ref_apply(ws, inputs, table_map, combiners))(ref_w)
+    dist_outs = compiled(lambda p: dist.apply(p, inputs))(params)
 
     assert len(ref_outs) == len(dist_outs)
     for i, (a, b) in enumerate(zip(ref_outs, dist_outs)):
@@ -215,11 +226,11 @@ def check_equivalence(specs, world=8, input_table_map=None, inputs=None,
         outs = ref_apply(ws, inputs, table_map, combiners)
         return sum(jnp.vdot(o, c) for o, c in zip(outs, cots))
 
-    dist_grads = jax.grad(dist_loss)(params)
+    dist_grads = compiled(jax.grad(dist_loss))(params)
     new_params = jax.tree.map(_sgd_respecting_placement, params, dist_grads)
 
-    ref_grads = jax.grad(ref_loss)(ref_w)
-    new_ref = [w - LR * g for w, g in zip(ref_w, ref_grads)]
+    ref_grads = compiled(jax.grad(ref_loss))(ref_w)
+    new_ref = [_sgd(w, g) for w, g in zip(ref_w, ref_grads)]
 
     got = dist.get_weights(new_params)
     for t, (a, b) in enumerate(zip(new_ref, got)):
@@ -260,7 +271,7 @@ def _check_lookahead_parity(dist, params, inputs, rng, steps=3):
             return (loss, res) if return_residuals else loss
 
     model = _Head(dist)
-    outs = dist.apply(params, inputs)
+    outs = jax.eval_shape(lambda p: dist.apply(p, inputs), params)
     feat = sum(int(np.prod(o.shape[1:])) for o in outs)
     batch = int(outs[0].shape[0])
     head = jnp.asarray(rng.randn(feat, 1).astype(np.float32)) * 0.1
@@ -304,7 +315,7 @@ ONE_HOT_8 = [(96, 8), (50, 8), (100, 16), (120, 8), (40, 16), (70, 8),
 
 
 def test_basic():
-    check_equivalence(ONE_HOT_8, strategy="basic")
+    check_equivalence(ONE_HOT_8, strategy="basic", eager=True)
 
 
 def test_memory_balanced():
@@ -330,10 +341,6 @@ def test_data_parallel():
                       data_parallel_threshold=500)
 
 
-# execution-bound on the single-core CPU test host (see
-# .claude/skills/verify/SKILL.md): runs in the `-m slow` tier so the
-# not-slow tier-1 sweep completes inside its time budget
-@pytest.mark.slow
 def test_all_parallelism_modes():
     specs = [(10, 4), (96, 8), (50, 8), (1000, 16), (2000, 16), (30, 4),
              (800, 8), (64, 8)]
@@ -549,10 +556,13 @@ def test_jit_apply():
 
 # ---------------------------------------------------------------- mp input
 def check_mp_equivalence(specs, world=8, input_table_map=None, seed=0,
-                         check_train=True, **dist_kwargs):
+                         check_train=True, eager=False, **dist_kwargs):
     """Same equivalence check through the model-parallel input path
     (reference dp_input=False): each rank gets global-batch ids for the
-    features it owns (strategy.input_ids_list order)."""
+    features it owns (strategy.input_ids_list order).
+
+    eager: as `check_equivalence`'s. Called eagerly, `apply_mp` stages each
+    rank's block on its own device; inside one program it stacks them."""
     rng = np.random.RandomState(seed)
     embeddings, combiners = [], []
     for spec in specs:
@@ -583,9 +593,11 @@ def check_mp_equivalence(specs, world=8, input_table_map=None, seed=0,
         return [[inps[dist.strategy.input_groups[1][pos]] for pos in rank_ids]
                 for rank_ids in dist.strategy.input_ids_list]
 
+    compiled = (lambda f: f) if eager else jax.jit
     ref_w = [jnp.asarray(w) for w in weights]
-    ref_outs = ref_apply(ref_w, inputs, table_map, combiners)
-    dist_outs = dist.apply_mp(params, to_mp(inputs))
+    ref_outs = compiled(
+        lambda ws: ref_apply(ws, inputs, table_map, combiners))(ref_w)
+    dist_outs = compiled(dist.apply_mp)(params, to_mp(inputs))
 
     assert len(ref_outs) == len(dist_outs)
     for i, (a, b) in enumerate(zip(ref_outs, dist_outs)):
@@ -597,18 +609,18 @@ def check_mp_equivalence(specs, world=8, input_table_map=None, seed=0,
     cots = [jnp.asarray(rng.randn(*o.shape).astype(np.float32))
             for o in ref_outs]
 
-    def dist_loss(p):
-        outs = dist.apply_mp(p, to_mp(inputs))
+    def dist_loss(p, mp_inputs):
+        outs = dist.apply_mp(p, mp_inputs)
         return sum(jnp.vdot(o, c) for o, c in zip(outs, cots))
 
     def ref_loss(ws):
         outs = ref_apply(ws, inputs, table_map, combiners)
         return sum(jnp.vdot(o, c) for o, c in zip(outs, cots))
 
-    dist_grads = jax.grad(dist_loss)(params)
+    dist_grads = compiled(jax.grad(dist_loss))(params, to_mp(inputs))
     new_params = jax.tree.map(_sgd_respecting_placement, params, dist_grads)
-    ref_grads = jax.grad(ref_loss)(ref_w)
-    new_ref = [w - LR * g for w, g in zip(ref_w, ref_grads)]
+    ref_grads = compiled(jax.grad(ref_loss))(ref_w)
+    new_ref = [_sgd(w, g) for w, g in zip(ref_w, ref_grads)]
     got = dist.get_weights(new_params)
     for t, (a, b) in enumerate(zip(new_ref, got)):
         np.testing.assert_allclose(b, np.asarray(a), rtol=1e-4, atol=1e-5,
@@ -617,7 +629,7 @@ def check_mp_equivalence(specs, world=8, input_table_map=None, seed=0,
 
 
 def test_mp_input_basic():
-    check_mp_equivalence(ONE_HOT_8, strategy="basic")
+    check_mp_equivalence(ONE_HOT_8, strategy="basic", eager=True)
 
 
 def test_mp_input_memory_balanced():
@@ -681,10 +693,6 @@ def test_bf16_column_slice():
                       compute_dtype=jnp.bfloat16, **BF16_TOL)
 
 
-# execution-bound on the single-core CPU test host (see
-# .claude/skills/verify/SKILL.md): runs in the `-m slow` tier so the
-# not-slow tier-1 sweep completes inside its time budget
-@pytest.mark.slow
 def test_bf16_row_slice():
     check_equivalence(ONE_HOT_8, strategy="memory_balanced",
                       row_slice_threshold=1600,

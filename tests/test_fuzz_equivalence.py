@@ -97,7 +97,6 @@ def test_random_config_equivalence(seed):
         raise
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("seed", range(4))
 def test_random_config_ragged_and_weighted(seed):
     """Same sweep but inputs arrive as RaggedIds / (ids, weights) tuples
@@ -230,7 +229,6 @@ def test_storage_dtype_stream_and_stash_fuzz(seed, tmp_path):
             assert np.abs(back - rows[i]).max() <= bound + 1e-6
 
 
-@pytest.mark.slow
 def test_sparse_ids_through_distributed_forward():
     """COO SparseIds inputs through the full distributed forward — the one
     prepared-input form the named tests don't cover (reference sparse-input
@@ -262,7 +260,6 @@ def test_sparse_ids_through_distributed_forward():
                       strategy="memory_balanced", check_train=False)
 
 
-@pytest.mark.slow
 def test_comm_balanced_equivalence():
     """comm_balanced placement is numerically identical to the reference
     model, hotness hints and all (mixed one-hot + multi-hot + shared)."""
@@ -287,7 +284,6 @@ def test_comm_balanced_equivalence():
                       input_max_hotness=hot, strategy="comm_balanced")
 
 
-@pytest.mark.slow
 def test_mp_input_mixed_forms_equivalence():
     """apply_mp (feature-sharded input) with mixed dense/ragged/weighted
     forms matches the unsharded reference — per-rank input routing plus
@@ -335,175 +331,9 @@ def test_mp_input_mixed_forms_equivalence():
         for rank_ids in dist.strategy.input_ids_list]
     outs = dist.apply_mp(params, mp_inputs)
 
-    refs = ref_apply([jnp.asarray(w) for w in weights], flat_inputs,
-                     list(range(len(specs))), [c for _, _, c in specs])
+    refs = jax.jit(lambda ws: ref_apply(
+        ws, flat_inputs, list(range(len(specs))), [c for _, _, c in specs]))(
+            [jnp.asarray(w) for w in weights])
     for i, (a, b) in enumerate(zip(refs, outs)):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=1e-5,
                                    atol=1e-5, err_msg=f"output {i}")
-
-
-def _offload_vs_device_sparse(specs, optimizer, dedup, placement, budget,
-                              seed):
-    """Sparse train steps on an offloaded model must equal the same steps
-    on the all-device model (same lazy rules both sides, so ALL optimizers
-    incl. adam are valid here — unlike the dense-reference comparison)."""
-    import jax
-    import jax.numpy as jnp
-    from test_sparse_train import TinyModel, BATCH
-    from distributed_embeddings_tpu.training import make_sparse_train_step
-    from distributed_embeddings_tpu.parallel.mesh import create_mesh
-
-    rng = np.random.RandomState(seed)
-    mesh = create_mesh(jax.devices()[:8])
-    weights = [rng.randn(s[0], s[1]).astype(np.float32) * 0.1 for s in specs]
-    head = rng.randn(sum(s[1] for s in specs), 1).astype(np.float32)
-    results = []
-    for off in (False, True):
-        model = TinyModel(specs, mesh, strategy=placement,
-                          gpu_embedding_size=(budget if off else None))
-        if off and not any(b.offload
-                           for b in model.embedding.plan.tp_buckets):
-            pytest.skip("budget did not offload anything")
-        init_fn, step_fn = make_sparse_train_step(model, optimizer, lr=0.05,
-                                                  strategy=dedup)
-        params = {"embedding": model.embedding.set_weights(weights),
-                  "head": {"w": jnp.asarray(head)}}
-        state = init_fn(params)
-        r2 = np.random.RandomState(seed + 1)
-        losses = []
-        for _ in range(3):
-            cats = [jnp.asarray(r2.randint(0, v, size=(BATCH, 2)))
-                    for v, _, _ in specs]
-            labels = jnp.asarray(r2.randn(BATCH).astype(np.float32))
-            params, state, loss = step_fn(params, state,
-                                          jnp.zeros((BATCH, 1)), cats,
-                                          labels)
-            losses.append(float(loss))
-        results.append((losses,
-                        model.embedding.get_weights(params["embedding"])))
-    (l_dev, w_dev), (l_off, w_off) = results
-    np.testing.assert_allclose(l_off, l_dev, rtol=1e-5, atol=1e-6)
-    for t, (a, b) in enumerate(zip(w_dev, w_off)):
-        np.testing.assert_allclose(b, a, rtol=2e-5, atol=2e-5,
-                                   err_msg=f"table {t} ({optimizer})")
-
-
-@pytest.mark.parametrize("optimizer", ["sgd", "adagrad", "adam"])
-@pytest.mark.parametrize("ragged", [False, True])
-@pytest.mark.parametrize("weighted", [False, True])
-def test_sparse_train_wire_axis(optimizer, ragged, weighted, monkeypatch):
-    """Wire-dtype axis over the sparse training path (ISSUE 5): the bf16
-    exchange wire must match the f32 wire within the documented
-    tolerance across every optimizer x exchange-path x weightedness
-    combination, and the f32 wire must match the seam-less default
-    BIT-exactly. (adam is compared bf16-vs-f32 wire, both lazy — the
-    dense-reference caveat of run_equivalence does not apply here.)"""
-    import jax
-    import jax.numpy as jnp
-    from test_sparse_train import TinyModel, BATCH
-    from distributed_embeddings_tpu.training import make_sparse_train_step
-    from distributed_embeddings_tpu.parallel.mesh import create_mesh
-
-    monkeypatch.setenv("DET_RAGGED_EXCHANGE", "1" if ragged else "0")
-    specs = [(96, 8, "sum"), (50, 8, "sum"), (70, 8, "mean"),
-             (300, 8, "sum"), (64, 8, "sum"), (120, 8, "sum"),
-             (80, 8, "sum"), (45, 8, "sum")]
-    rng = np.random.RandomState(31)
-    mesh = create_mesh(jax.devices()[:8])
-    weights = [rng.randn(v, w).astype(np.float32) * 0.1 for v, w, _ in specs]
-    head = rng.randn(sum(w for _, w, _ in specs), 1).astype(np.float32)
-    batches = []
-    r2 = np.random.RandomState(32)
-    for _ in range(2):
-        cats = []
-        for v, _, _ in specs:
-            ids = jnp.asarray(r2.randint(0, v, size=(BATCH, 3)))
-            if weighted:
-                cats.append((ids, jnp.asarray(
-                    np.abs(r2.rand(BATCH, 3)).astype(np.float32))))
-            else:
-                cats.append(ids)
-        batches.append((cats, jnp.asarray(r2.randn(BATCH)
-                                          .astype(np.float32))))
-
-    def run(wire):
-        kw = {"input_max_hotness": [3] * len(specs)}
-        if wire is not None:
-            kw["exchange_wire"] = wire
-        model = TinyModel(specs, mesh, **kw)
-        init_fn, step_fn = make_sparse_train_step(model, optimizer, lr=0.1)
-        params = {"embedding": model.embedding.set_weights(weights),
-                  "head": {"w": jnp.asarray(head)}}
-        state = init_fn(params)
-        losses = []
-        for cats, labels in batches:
-            params, state, loss = step_fn(params, state,
-                                          jnp.zeros((BATCH, 1)), cats,
-                                          labels)
-            losses.append(float(loss))
-        return losses, model.embedding.get_weights(params["embedding"])
-
-    l_def, w_def = run(None)
-    l_f32, w_f32 = run("f32")
-    assert l_f32 == l_def
-    for t, (a, b) in enumerate(zip(w_def, w_f32)):
-        assert (a == b).all(), f"table {t} ({optimizer})"
-    l_bf, w_bf = run("bf16")
-    np.testing.assert_allclose(l_bf, l_f32, rtol=2e-2, atol=2e-2)
-    for t, (a, b) in enumerate(zip(w_f32, w_bf)):
-        np.testing.assert_allclose(b, a, rtol=3e-2, atol=3e-3,
-                                   err_msg=f"table {t} ({optimizer})")
-
-
-# seeds 2, 3, 5, 6 and 9 take 42-55 s each alone (the others 6-11): they stay
-# in the `-m slow` tier so this file keeps its share of the tier-1 clock
-@pytest.mark.parametrize("seed", [
-    pytest.param(s, marks=pytest.mark.slow) if s in (2, 3, 5, 6, 9) else s
-    for s in range(10)])
-def test_random_sparse_train_equivalence(seed):
-    """Randomized sparse TRAINING equivalence: optimizer x dedup strategy x
-    placement x host-offload corners (the named cases in test_sparse_train /
-    test_offload walk fixed configs; this walks random ones). Two modes:
-
-      * no offload: sparse path vs dense optax — sgd/adagrad only (the
-        rules that match dense EXACTLY on any id stream; lazy adam equals
-        dense adam only under full row coverage, pinned by
-        test_sparse_train_adam_full_coverage);
-      * offload: sparse-offload vs sparse-device — all three optimizers
-        (same lazy rules both sides), covering the round-3 host-adam rule.
-    """
-    from test_sparse_train import run_equivalence
-
-    rng = np.random.RandomState(3000 + seed)
-    n = int(rng.randint(5, 9))
-    specs = []
-    for _ in range(n):
-        vocab = int(rng.choice([30, 90, 400, 1500, 4000]))
-        width = int(rng.choice([4, 8, 16]))
-        combiner = ["sum", "mean"][rng.randint(2)]
-        specs.append((vocab, width, combiner))
-    # scatter_impl axis (ISSUE 12): the fused pallas strategy rides the
-    # sweep next to the XLA aggregation strategies — every random corner
-    # that holds for 'sort' must hold for the deduped-row tile walk too
-    dedup = ["sort", "dense", "auto", "pallas"][rng.randint(4)]
-    placement = ["memory_balanced", "comm_balanced", "basic"][rng.randint(3)]
-    offload = rng.rand() < 0.5
-    try:
-        if offload:
-            optimizer = ["sgd", "adagrad", "adam"][rng.randint(3)]
-            total = sum(s[0] * s[1] for s in specs)
-            # gpu_embedding_size is a PER-DEVICE element budget: a third
-            # of the fair per-rank share forces the biggest buckets out
-            _offload_vs_device_sparse(specs, optimizer, dedup, placement,
-                                      budget=total // 24, seed=seed)
-        else:
-            optimizer = ["sgd", "adagrad"][rng.randint(2)]
-            kw = {"placement": placement}
-            if rng.rand() < 0.4:
-                kw["data_parallel_threshold"] = 256
-            run_equivalence(specs, optimizer, strategy=dedup, seed=seed,
-                            **kw)
-    except ValueError as e:
-        if "Not enough tables" in str(e):
-            pytest.skip(f"seed {seed}: config unplaceable on 8 devices")
-        raise

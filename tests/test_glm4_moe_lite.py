@@ -79,7 +79,7 @@ def program_side(model, params, positions, cats, next_ids):
     """(loss, (gradient of the dense tree, of the table)) of the program's
     model, under `highest`."""
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(model.loss_fn)(
+        loss, grads = jax.jit(jax.value_and_grad(model.loss_fn))(
             params, positions, cats, next_ids)
     (table,) = model.embedding.get_weights(grads.pop("embedding"))
     return loss, (grads, jnp.asarray(table))
@@ -91,10 +91,11 @@ def reference_side(model, params, positions, cats, next_ids):
     dense = {k: v for k, v in params.items() if k != "embedding"}
     (table,) = model.embedding.get_weights(params["embedding"])
     with jax.default_matmul_precision("highest"):
-        loss, (g_dense, g_x) = jax.value_and_grad(
-            lambda dense, x: reference.model_loss(dense, [x], positions,
-                                                  next_ids, SPEC),
-            argnums=(0, 1))(dense, jnp.asarray(table)[cats[0]])
+        loss, (g_dense, g_x) = jax.jit(jax.value_and_grad(
+            lambda dense, x, positions, next_ids: reference.model_loss(
+                dense, [x], positions, next_ids, SPEC),
+            argnums=(0, 1)))(dense, jnp.asarray(table)[cats[0]], positions,
+                             next_ids)
     g_table = np.zeros_like(table)
     np.add.at(g_table, cats[0], np.asarray(g_x))
     return loss, (g_dense, jnp.asarray(g_table))
@@ -110,8 +111,9 @@ def worst_relative(got, want):
     gradient is zero on both sides (the selection bias) counts as 0."""
     worst = 0.0
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        scale = float(jnp.max(jnp.abs(w)))
-        off = float(jnp.max(jnp.abs(g - w)))
+        g, w = np.asarray(g), np.asarray(w)
+        scale = float(np.max(np.abs(w)))
+        off = float(np.max(np.abs(g - w)))
         worst = max(worst, off / scale if scale else off)
     return worst
 
@@ -200,29 +202,35 @@ def test_the_eight_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
     layer = scaled({"layers": [whole._init_layer(jax.random.PRNGKey(0),
                                                  "sparse")]})["layers"][0]
     x = jax.random.normal(jax.random.PRNGKey(1), (96, HIDDEN))
-    normed = mellum._rms_norm(x, layer["post_attention_layernorm"], 1e-5)
-    shared = mellum.swiglu(normed, **layer["shared"])
-    parts = []
-    for first in range(0, TOTAL, 2):
-        share = sparse_layer(range(first, first + 2))
-        cut = {"router": layer["experts"]["router"],
-               "bias": layer["experts"]["bias"],
-               **{k: layer["experts"][k][first:first + 2]
-                  for k in ("gate", "up", "down")}}
-        parts.append(share.experts(cut, normed))
+
+    @jax.jit
+    def shares_and_reference(layer, x):
+        normed = mellum._rms_norm(x, layer["post_attention_layernorm"], 1e-5)
+        shared = mellum.swiglu(normed, **layer["shared"])
+        parts, blocks = [], []
+        for first in range(0, TOTAL, 2):
+            share = sparse_layer(range(first, first + 2))
+            cut = {"router": layer["experts"]["router"],
+                   "bias": layer["experts"]["bias"],
+                   **{k: layer["experts"][k][first:first + 2]
+                      for k in ("gate", "up", "down")}}
+            parts.append(share.experts(cut, normed))
+            blocks.append(share._sparse_mlp(dict(layer, experts=cut), x))
+        want = reference.sparse_ffn(layer, normed, 2,
+                                    dict(SPEC, first_expert_held=0))
+        return shared, parts, blocks, want, whole._sparse_mlp(layer, x)
+
+    shared, parts, blocks, want, uncut = shares_and_reference(layer, x)
+    for part, block in zip(parts, blocks):
         # a share's block is its routed part, the shared expert and the
         # stream
-        np.testing.assert_allclose(
-            share._sparse_mlp(dict(layer, experts=cut), x),
-            x + parts[-1] + shared, rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(block, x + part + shared, rtol=1e-5,
+                                   atol=1e-6)
     assert len(parts) == 8
     assert all(0 < float(jnp.abs(p).max()) for p in parts)
-    want = reference.sparse_ffn(layer, normed, 2,
-                                dict(SPEC, first_expert_held=0))
     np.testing.assert_allclose(sum(parts) + shared, want, rtol=2e-5,
                                atol=2e-6)
-    np.testing.assert_allclose(whole._sparse_mlp(layer, x), x + want,
-                               rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(uncut, x + want, rtol=2e-5, atol=2e-6)
     assert float(jnp.abs(shared).max()) > 0.1 * float(jnp.abs(want).max())
 
 
@@ -259,9 +267,11 @@ def test_the_scaling_factor_multiplies_and_the_bias_selects():
         many.weights, np.asarray(one.weights) * np.float32(SCALE))
     # and the held part is linear in them
     params = dict(plain.init(jax.random.PRNGKey(5), std=0.3), bias=bias)
-    np.testing.assert_allclose(scaled_layer(params, x),
-                               SCALE * plain(params, x), rtol=1e-5, atol=1e-6)
-    grads = jax.grad(lambda p: jnp.sum(scaled_layer(p, x) ** 2))(params)
+    np.testing.assert_allclose(jax.jit(scaled_layer)(params, x),
+                               SCALE * jax.jit(plain)(params, x), rtol=1e-5,
+                               atol=1e-6)
+    grads = jax.jit(jax.grad(lambda p: jnp.sum(scaled_layer(p, x) ** 2)))(
+        params)
     assert not np.any(np.asarray(grads["bias"]))
     assert np.any(np.asarray(grads["router"]))
     # the defaults are the other two models': no factor, 1e-6
@@ -352,7 +362,8 @@ def test_latent_attention_is_the_equations_written_out(sizes):
     positions = packed([(7, 9)])
     x = jax.random.normal(jax.random.PRNGKey(1), (16, HIDDEN))
     document, _ = mellum.packed_mask_terms(jnp.asarray(positions))
-    got = model._attention(layer, x, jnp.asarray(positions), document)
+    got = jax.jit(model._attention)(layer, x, jnp.asarray(positions),
+                                    document)
     want, _ = by_hand(layer, x, positions[0], nope=model.latent[
         "qk_nope_head_dim"], rope=model.latent["qk_rope_head_dim"],
         v_dim=model.latent["v_head_dim"])
@@ -361,8 +372,9 @@ def test_latent_attention_is_the_equations_written_out(sizes):
     spec = dict(SPEC, qk_nope_head_dim=model.latent["qk_nope_head_dim"],
                 qk_rope_head_dim=model.latent["qk_rope_head_dim"])
     np.testing.assert_allclose(
-        model._attend(layer, x, jnp.asarray(positions), document),
-        x + reference.latent_attention(layer, x, jnp.asarray(positions), spec),
+        jax.jit(model._attend)(layer, x, jnp.asarray(positions), document),
+        x + jax.jit(lambda layer, x, positions: reference.latent_attention(
+            layer, x, positions, spec))(layer, x, jnp.asarray(positions)),
         rtol=2e-5, atol=2e-6)
 
 
@@ -405,11 +417,12 @@ def test_a_key_in_another_document_is_never_seen():
     positions = jnp.asarray(packed([(20, 28)]))
     document, _ = mellum.packed_mask_terms(positions)
     x = jax.random.normal(jax.random.PRNGKey(1), (48, HIDDEN))
-    before = np.asarray(model._attend(layer, x, positions, document))
+    attend = jax.jit(model._attend)
+    before = np.asarray(attend(layer, x, positions, document))
     for token, changed in ((19, [19]), (5, list(range(5, 20))),
                            (20, list(range(20, 48)))):
-        after = np.asarray(model._attend(layer, x.at[token].add(1.0),
-                                         positions, document))
+        after = np.asarray(attend(layer, x.at[token].add(1.0), positions,
+                                  document))
         np.testing.assert_array_equal(
             np.flatnonzero(np.abs(after - before).max(axis=1) > 1e-7), changed)
 
@@ -425,8 +438,9 @@ def test_positions_restart_with_each_document():
     a = np.concatenate([rng.normal(size=(20, HIDDEN)), doc]).astype(np.float32)
     b = np.concatenate([rng.normal(size=(7, HIDDEN)), doc,
                         rng.normal(size=(13, HIDDEN))]).astype(np.float32)
-    out_a = model.hidden_states(params, jnp.asarray(packed([(20, 28)])), a)
-    out_b = model.hidden_states(params, jnp.asarray(packed([(7, 28, 13)])), b)
+    hidden_states = jax.jit(model.hidden_states)
+    out_a = hidden_states(params, jnp.asarray(packed([(20, 28)])), a)
+    out_b = hidden_states(params, jnp.asarray(packed([(7, 28, 13)])), b)
     np.testing.assert_allclose(out_a[20:], out_b[7:35], rtol=2e-5, atol=2e-5)
 
 

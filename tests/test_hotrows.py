@@ -155,8 +155,8 @@ def test_empty_hot_set_is_identity():
     p1 = m1.embedding.set_weights(weights)
     assert "hot" not in p0 and "hot" in p1
     cats = _zipf_cats(np.random.RandomState(2))
-    out0 = m0.embedding(p0, cats)
-    out1 = m1.embedding(p1, cats)
+    out0 = jax.jit(m0.embedding)(p0, cats)
+    out1 = jax.jit(m1.embedding)(p1, cats)
     for a, b in zip(out0, out1):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-7)
@@ -269,8 +269,8 @@ def test_checkpoint_roundtrip_merges_hot_rows():
     m2 = _TapModel(mesh, hot_rows=8)
     p2 = {"embedding": m2.embedding.set_weights(w_overlay)}
     cats = _zipf_cats(np.random.RandomState(11))
-    out1 = model.embedding(p_sync, cats)
-    out2 = m2.embedding(p2["embedding"], cats)
+    out1 = jax.jit(model.embedding)(p_sync, cats)
+    out2 = jax.jit(m2.embedding)(p2["embedding"], cats)
     for a, b in zip(out1, out2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
@@ -435,11 +435,12 @@ def test_tapped_forward_without_hot_taps_raises():
     taps = model.embedding.make_taps(cats)
     assert "hot" in taps
     # tapless and make_taps-built forwards both work
-    model.embedding(params["embedding"], list(cats))
-    model.embedding(params["embedding"], list(cats), taps=taps)
+    forward = jax.jit(model.embedding)
+    forward(params["embedding"], list(cats))
+    forward(params["embedding"], list(cats), taps=taps)
     with pytest.raises(ValueError, match=r"taps\['hot'\]"):
-        model.embedding(params["embedding"], list(cats),
-                        taps={"tp": taps["tp"], "row": taps["row"]})
+        forward(params["embedding"], list(cats),
+                taps={"tp": taps["tp"], "row": taps["row"]})
 
 
 def test_observe_hot_ids_ignores_out_of_range_ids():

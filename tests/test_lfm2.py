@@ -72,12 +72,13 @@ def both_sides(model, params, positions, cats, next_ids):
     dense = {k: v for k, v in params.items() if k != "embedding"}
     (table,) = model.embedding.get_weights(params["embedding"])
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(model.loss_fn)(
+        loss, grads = jax.jit(jax.value_and_grad(model.loss_fn))(
             params, positions, cats, next_ids)
-        want_loss, (want_dense, want_x) = jax.value_and_grad(
-            lambda dense, x: reference.model_loss(dense, [x], positions,
-                                                  next_ids, SPEC),
-            argnums=(0, 1))(dense, jnp.asarray(table)[cats[0]])
+        want_loss, (want_dense, want_x) = jax.jit(jax.value_and_grad(
+            lambda dense, x, positions, next_ids: reference.model_loss(
+                dense, [x], positions, next_ids, SPEC),
+            argnums=(0, 1)))(dense, jnp.asarray(table)[cats[0]], positions,
+                             next_ids)
     (got_table,) = model.embedding.get_weights(grads.pop("embedding"))
     want_table = np.zeros_like(table)
     np.add.at(want_table, cats[0], np.asarray(want_x))
@@ -90,8 +91,9 @@ def worst_relative(got, want):
     gradient is zero on both sides (the selection bias) counts as 0."""
     worst = 0.0
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        scale = float(jnp.max(jnp.abs(w)))
-        off = float(jnp.max(jnp.abs(g - w)))
+        g, w = np.asarray(g), np.asarray(w)
+        scale = float(np.max(np.abs(w)))
+        off = float(np.max(np.abs(g - w)))
         worst = max(worst, off / scale if scale else off)
     return worst
 
@@ -142,14 +144,19 @@ def test_the_eight_shares_under_the_sigmoid_rule_add_up_to_the_uncut_layer():
     layer = sigmoid_layer()
     params = layer.init(jax.random.PRNGKey(0), std=0.3, bias_range=0.05)
     x = jax.random.normal(jax.random.PRNGKey(1), (96, HIDDEN))
-    whole = layer(params, x)
-    parts = []
-    for first in range(0, TOTAL, 2):
-        share = sigmoid_layer(range(first, first + 2))
-        parts.append(share(
-            {"router": params["router"], "bias": params["bias"],
-             **{k: params[k][first:first + 2] for k in ("gate", "up", "down")}},
-            x))
+
+    @jax.jit
+    def whole_and_shares(params, x):
+        parts = []
+        for first in range(0, TOTAL, 2):
+            share = sigmoid_layer(range(first, first + 2))
+            parts.append(share(
+                {"router": params["router"], "bias": params["bias"],
+                 **{k: params[k][first:first + 2]
+                    for k in ("gate", "up", "down")}}, x))
+        return layer(params, x), parts
+
+    whole, parts = whole_and_shares(params, x)
     assert len(parts) == 8
     np.testing.assert_allclose(sum(parts), whole, rtol=2e-5, atol=2e-6)
     assert all(0 < float(jnp.abs(p).max()) for p in parts)
@@ -212,7 +219,7 @@ def test_the_bias_selects_and_does_not_weigh():
                           rel=1e-5))
     # no gradient reaches the bias; the router's passes through the scores
     params = dict(layer.init(jax.random.PRNGKey(5), std=0.3), bias=bias)
-    grads = jax.grad(lambda p: jnp.sum(layer(p, x) ** 2))(params)
+    grads = jax.jit(jax.grad(lambda p: jnp.sum(layer(p, x) ** 2)))(params)
     assert not np.any(np.asarray(grads["bias"]))
     assert np.any(np.asarray(grads["router"]))
     stats = layer.routing_stats(params, x)
@@ -260,10 +267,10 @@ def test_a_tap_never_reaches_into_the_document_before():
     layer = jax.tree.map(lambda p: p * 8.0 if p.ndim > 1 else p, layer)
     positions = jnp.asarray(packed([(20, 28)]))
     x = jax.random.normal(jax.random.PRNGKey(1), (48, HIDDEN))
-    before = np.asarray(model._convolve(layer, x, positions))
+    convolve = jax.jit(model._convolve)
+    before = np.asarray(convolve(layer, x, positions))
     for token, changed in ((19, [19]), (5, [5, 6, 7]), (20, [20, 21, 22])):
-        after = np.asarray(model._convolve(layer, x.at[token].add(1.0),
-                                           positions))
+        after = np.asarray(convolve(layer, x.at[token].add(1.0), positions))
         np.testing.assert_array_equal(
             np.flatnonzero(np.abs(after - before).max(axis=1) > 1e-7), changed)
 
@@ -280,8 +287,9 @@ def test_positions_restart_with_each_document():
     a = np.concatenate([rng.normal(size=(20, HIDDEN)), doc]).astype(np.float32)
     b = np.concatenate([rng.normal(size=(7, HIDDEN)), doc,
                         rng.normal(size=(13, HIDDEN))]).astype(np.float32)
-    out_a = model.hidden_states(params, jnp.asarray(packed([(20, 28)])), a)
-    out_b = model.hidden_states(params, jnp.asarray(packed([(7, 28, 13)])), b)
+    hidden_states = jax.jit(model.hidden_states)
+    out_a = hidden_states(params, jnp.asarray(packed([(20, 28)])), a)
+    out_b = hidden_states(params, jnp.asarray(packed([(7, 28, 13)])), b)
     np.testing.assert_allclose(out_a[20:], out_b[7:35], rtol=2e-5, atol=2e-5)
 
 

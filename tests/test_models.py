@@ -28,8 +28,8 @@ def test_dlrm_dp_input_forward_and_grad():
     cats = [jnp.asarray(rng.randint(0, v, (B,)).astype(np.int32))
             for v in SIZES]
     labels = jnp.asarray(rng.randint(0, 2, (B, 1)).astype(np.float32))
-    loss, grads = jax.value_and_grad(model.loss_fn)(params, numerical, cats,
-                                                    labels)
+    loss, grads = jax.jit(jax.value_and_grad(model.loss_fn))(
+        params, numerical, cats, labels)
     assert np.isfinite(float(loss))
     assert jnp.all(jnp.isfinite(grads["top_mlp"][0]["w"]))
 
@@ -87,5 +87,5 @@ def test_synthetic_tiny_step():
     params = model.init(jax.random.PRNGKey(0))
     gen = InputGenerator(small, 32, alpha=1.05, num_batches=1, seed=0)
     numerical, cats, labels = gen[0]
-    loss = model.loss_fn(params, numerical, cats, labels)
+    loss = jax.jit(model.loss_fn)(params, numerical, cats, labels)
     assert np.isfinite(float(loss))

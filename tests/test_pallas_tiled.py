@@ -25,6 +25,13 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 import tpu_sort_payload_sweep as sweep  # noqa: E402
 
 
+def one_program(fn, *arrays, **options):
+    """`fn(*arrays, **options)` compiled as one program, the arrays its
+    arguments and the options constants of it: not one program for each
+    operation, as an eager call compiles."""
+    return jax.jit(lambda *a: fn(*a, **options))(*arrays)
+
+
 def _mk(v, w, n, seed=0, frac_invalid=0.0, hot_skew=True):
     rng = np.random.RandomState(seed)
     if hot_skew:
@@ -51,14 +58,15 @@ def _mk(v, w, n, seed=0, frac_invalid=0.0, hot_skew=True):
 ])
 def test_tiled_gather_matches_take(v, w, n, tile, chunk):
     table, ids, _ = _mk(v, w, n, seed=v + n)
-    got = pt.tiled_gather(table, ids, chunk=chunk, tile=tile, interpret=True)
+    got = one_program(pt.tiled_gather, table, ids, chunk=chunk, tile=tile,
+                      interpret=True)
     want = jnp.take(table, jnp.clip(ids, 0, v - 1), axis=0)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 def test_tiled_gather_invalid_ids_zero_rows():
     table, ids, _ = _mk(500, 16, 400, seed=3, frac_invalid=0.25)
-    got = np.asarray(pt.tiled_gather(table, ids, interpret=True))
+    got = np.asarray(one_program(pt.tiled_gather, table, ids, interpret=True))
     idn = np.asarray(ids)
     bad = (idn < 0) | (idn >= 500)
     assert bad.any()
@@ -70,7 +78,7 @@ def test_tiled_gather_invalid_ids_zero_rows():
 def test_tiled_gather_sorted_direct():
     table, ids, _ = _mk(2000, 32, 900, seed=11)
     sid = jnp.sort(ids)
-    got = pt.tiled_gather_sorted(table, sid, interpret=True)
+    got = one_program(pt.tiled_gather_sorted, table, sid, interpret=True)
     want = jnp.take(table, sid, axis=0)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
@@ -84,9 +92,12 @@ def test_tiled_gather_sorted_direct():
 def test_tiled_sgd_matches_xla(v, w, n, frac_invalid):
     table, ids, contribs = _mk(v, w, n, seed=v, frac_invalid=frac_invalid)
     lr = 0.07
-    got = pt.tiled_sgd(table, ids, contribs, lr, interpret=True)
-    want = table.at[jnp.clip(ids, 0, v)].add(
-        -lr * jnp.where(((ids >= 0) & (ids < v))[:, None], contribs, 0.0))
+    got = one_program(pt.tiled_sgd, table, ids, contribs, lr=lr,
+                      interpret=True)
+    want = one_program(lambda table, ids, contribs: table.at[
+        jnp.clip(ids, 0, v)].add(-lr * jnp.where(
+            ((ids >= 0) & (ids < v))[:, None], contribs, 0.0)),
+        table, ids, contribs)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
@@ -101,10 +112,11 @@ def test_tiled_adagrad_matches_sparse_update(v, w, n, frac_invalid, tile,
     table, ids, contribs = _mk(v, w, n, seed=7 * v, frac_invalid=frac_invalid)
     accum = jnp.full((v, w), 0.1, jnp.float32)
     lr = 0.05
-    got_t, got_a = pt.tiled_adagrad(table, accum, ids, contribs, lr,
-                                    tile=tile, chunk=chunk, interpret=True)
-    want_t, want_a = su.sparse_adagrad(
-        table, accum, su.SparseRowGrad(ids, contribs), lr, strategy="sort")
+    got_t, got_a = one_program(pt.tiled_adagrad, table, accum, ids, contribs,
+                               lr=lr, tile=tile, chunk=chunk, interpret=True)
+    want_t, want_a = one_program(
+        su.sparse_adagrad, table, accum, su.SparseRowGrad(ids, contribs),
+        lr=lr, strategy="sort")
     np.testing.assert_allclose(got_a, want_a, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(got_t, want_t, rtol=1e-4, atol=1e-5)
 
@@ -119,11 +131,11 @@ def test_tiled_adam_matches_sparse_update(v, w, n, frac_invalid):
     nu = jnp.zeros((v, w), jnp.float32)
     cnt = jnp.zeros((), jnp.int32)
     lr = 0.02
-    got = pt.tiled_adam(table, mu, nu, cnt, ids, contribs, lr,
-                        interpret=True)
-    want = su.sparse_adam(table, mu, nu, cnt,
-                          su.SparseRowGrad(ids, contribs), lr,
-                          strategy="sort")
+    got = one_program(pt.tiled_adam, table, mu, nu, cnt, ids, contribs,
+                      lr=lr, interpret=True)
+    want = one_program(su.sparse_adam, table, mu, nu, cnt,
+                       su.SparseRowGrad(ids, contribs), lr=lr,
+                       strategy="sort")
     for g, wv, name in zip(got, want, ("table", "mu", "nu", "count")):
         np.testing.assert_allclose(np.asarray(g, np.float32),
                                    np.asarray(wv, np.float32),
@@ -146,11 +158,12 @@ def test_tiled_adam_two_steps_touched_only_decay():
     s_t, s_mu, s_nu, s_c = table, mu, nu, cnt
     w_t, w_mu, w_nu, w_c = table, mu, nu, cnt
     for ids, g in ((ids1, g1), (ids2, g2)):
-        s_t, s_mu, s_nu, s_c = pt.tiled_adam(s_t, s_mu, s_nu, s_c, ids, g,
-                                             0.05, interpret=True)
-        w_t, w_mu, w_nu, w_c = su.sparse_adam(
-            w_t, w_mu, w_nu, w_c, su.SparseRowGrad(ids, g), 0.05,
-            strategy="sort")
+        s_t, s_mu, s_nu, s_c = one_program(
+            pt.tiled_adam, s_t, s_mu, s_nu, s_c, ids, g, lr=0.05,
+            interpret=True)
+        w_t, w_mu, w_nu, w_c = one_program(
+            su.sparse_adam, w_t, w_mu, w_nu, w_c, su.SparseRowGrad(ids, g),
+            lr=0.05, strategy="sort")
     np.testing.assert_allclose(s_t, w_t, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(s_mu, w_mu, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(s_nu, w_nu, rtol=1e-4, atol=1e-5)
@@ -167,8 +180,9 @@ def test_tiled_adagrad_traced_lr_and_jit():
         return pt.tiled_adagrad(t, a, i, c, lr, interpret=True)
 
     got_t, got_a = step(table, accum, ids, contribs, jnp.float32(0.03))
-    want_t, want_a = su.sparse_adagrad(
-        table, accum, su.SparseRowGrad(ids, contribs), 0.03, strategy="sort")
+    want_t, want_a = one_program(
+        su.sparse_adagrad, table, accum, su.SparseRowGrad(ids, contribs),
+        lr=0.03, strategy="sort")
     np.testing.assert_allclose(got_a, want_a, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(got_t, want_t, rtol=1e-4, atol=1e-5)
 
@@ -227,17 +241,17 @@ def test_tiled_embedding_lookup_matches_fused_contract():
             rows = jnp.take(tbl, jnp.clip(ids, 0, v - 1), axis=0)
             return jnp.einsum("bk,bkw->bw", ww, rows)
 
-        got = pt2.tiled_embedding_lookup(table, ids, wts, comb,
-                                         interpret=True)
-        np.testing.assert_allclose(got, ref(table, wts), rtol=1e-5,
+        got = one_program(pt2.tiled_embedding_lookup, table, ids, wts,
+                          combiner=comb, interpret=True)
+        np.testing.assert_allclose(got, jax.jit(ref)(table, wts), rtol=1e-5,
                                    atol=1e-5)
         # gradient parity (dense path)
         g = jnp.asarray(rng.randn(b, w).astype(np.float32))
         f_tiled = lambda t, wv: jnp.vdot(
             pt2.tiled_embedding_lookup(t, ids, wv, comb, interpret=True), g)
         f_ref = lambda t, wv: jnp.vdot(ref(t, wv), g)
-        gt_t, gt_w = jax.grad(f_tiled, argnums=(0, 1))(table, wts)
-        gr_t, gr_w = jax.grad(f_ref, argnums=(0, 1))(table, wts)
+        gt_t, gt_w = jax.jit(jax.grad(f_tiled, argnums=(0, 1)))(table, wts)
+        gr_t, gr_w = jax.jit(jax.grad(f_ref, argnums=(0, 1)))(table, wts)
         np.testing.assert_allclose(gt_t, gr_t, rtol=1e-4, atol=1e-5)
         np.testing.assert_allclose(gt_w, gr_w, rtol=1e-4, atol=1e-5)
 
@@ -412,11 +426,11 @@ def test_tiled_lookup_path_forward_equivalence(monkeypatch):
                                     else (16,))) for i in range(3)]
     emb = build()
     params = emb.set_weights(weights)
-    want = emb(params, list(cats))
+    want = jax.jit(emb)(params, list(cats))
     monkeypatch.setenv("DET_LOOKUP_PATH", "tiled")
     emb2 = build()
     params2 = emb2.set_weights(weights)
-    got = emb2(params2, list(cats))
+    got = jax.jit(emb2)(params2, list(cats))
     for a, b2 in zip(want, got):
         np.testing.assert_allclose(
             np.asarray(b2).reshape(np.asarray(a).shape), np.asarray(a),
@@ -524,10 +538,12 @@ def test_dense_sum_matches_the_scatter(kind, rows, n, tile, width):
     ids = jnp.asarray(_dense_stream(kind, rows, n, rng).astype(np.int32))
     contribs = jnp.asarray(rng.randn(n, width).astype(np.float32))
     tile = min(tile, -(-rows // 128) * 128)
-    kids, lo, hi, pairs = pt.dense_sum_walk(ids, rows, 256, tile)
-    g, counts = pt.dense_sum(kids, lo, hi, contribs, rows, tile,
-                             interpret=True)
-    g_want, counts_want = su._scatter_sum(ids, contribs, rows)
+    kids, lo, hi, pairs = one_program(pt.dense_sum_walk, ids, rows=rows,
+                                      chunk=256, tile=tile)
+    g, counts = one_program(pt.dense_sum, kids, lo, hi, contribs, rows=rows,
+                            tile=tile, interpret=True)
+    g_want, counts_want = one_program(su._scatter_sum, ids, contribs,
+                                      rows=rows)
     assert g.shape == (rows, width) and counts.shape == (rows,)
     np.testing.assert_array_equal(counts, counts_want)
     np.testing.assert_allclose(g, g_want, rtol=1e-5, atol=1e-5)
@@ -556,10 +572,12 @@ def test_dense_sum_at_the_wider_lane_widths(width):
         _dense_stream("feature_major", rows, 2048, rng),
         _dense_stream("out_of_range", rows, n - 2048, rng)]).astype(np.int32)
     contribs = jnp.asarray(rng.randn(n, width).astype(np.float32))
-    kids, lo, hi, _ = pt.dense_sum_walk(jnp.asarray(ids), rows, chunk, tile)
-    g, counts = pt.dense_sum(kids, lo, hi, contribs, rows, tile,
-                             interpret=True)
-    g_want, counts_want = su._scatter_sum(jnp.asarray(ids), contribs, rows)
+    kids, lo, hi, _ = one_program(pt.dense_sum_walk, jnp.asarray(ids),
+                                  rows=rows, chunk=chunk, tile=tile)
+    g, counts = one_program(pt.dense_sum, kids, lo, hi, contribs, rows=rows,
+                            tile=tile, interpret=True)
+    g_want, counts_want = one_program(su._scatter_sum, jnp.asarray(ids),
+                                      contribs, rows=rows)
     np.testing.assert_array_equal(counts, counts_want)
     np.testing.assert_allclose(g, g_want, rtol=1e-5, atol=1e-5)
 

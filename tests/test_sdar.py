@@ -82,7 +82,7 @@ def program_side(model, params, inputs, cats, weights):
     """(loss, (gradient of the dense tree, of the table)) of the program's
     model, under `highest`."""
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(model.loss_fn)(
+        loss, grads = jax.jit(jax.value_and_grad(model.loss_fn))(
             params, inputs, cats, weights)
     (table,) = model.embedding.get_weights(grads.pop("embedding"))
     return loss, (grads, jnp.asarray(table))
@@ -98,9 +98,11 @@ def reference_side(model, params, inputs, cats, weights):
     dense = {k: v for k, v in params.items() if k != "embedding"}
     (table,) = model.embedding.get_weights(params["embedding"])
     with jax.default_matmul_precision("highest"):
-        loss, (g_dense, g_x) = jax.value_and_grad(
-            lambda dense, x: reference_loss(dense, [x], inputs, weights),
-            argnums=(0, 1))(dense, jnp.asarray(table)[cats[0]])
+        loss, (g_dense, g_x) = jax.jit(jax.value_and_grad(
+            lambda dense, x, inputs, weights: reference_loss(
+                dense, [x], inputs, weights),
+            argnums=(0, 1)))(dense, jnp.asarray(table)[cats[0]], inputs,
+                             weights)
     g_table = np.zeros_like(table)
     np.add.at(g_table, cats[0], np.asarray(g_x))
     return loss, (g_dense, jnp.asarray(g_table))
@@ -115,8 +117,9 @@ def worst_relative(got, want):
     """The worst leaf's largest error over its largest entry."""
     worst = 0.0
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        scale = float(jnp.max(jnp.abs(w)))
-        worst = max(worst, float(jnp.max(jnp.abs(g - w))) / scale)
+        g, w = np.asarray(g), np.asarray(w)
+        scale = float(np.max(np.abs(w)))
+        worst = max(worst, float(np.max(np.abs(g - w))) / scale)
     return worst
 
 
@@ -193,15 +196,18 @@ def noisy_states(model, params, inputs, ids):
         jnp.asarray(inputs[:, 0]), jnp.asarray(table)[ids])
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def _one_layer(model, layer, x, positions):
+    document, blk = sdar.block_terms(positions, BLOCK)
+    x = model._attend(layer, x, positions, document, blk, last=False)
+    return model._sparse_mlp(layer, x)
+
+
 def both_streams(model, params, inputs, ids):
     """One layer over both streams, as every layer but the last runs."""
     (table,) = model.embedding.get_weights(params["embedding"])
-    layer = params["layers"][0]
-    positions = jnp.asarray(inputs[:, 0])
-    document, blk = sdar.block_terms(positions, BLOCK)
-    x = model._attend(layer, jnp.asarray(table)[ids], positions, document,
-                      blk, last=False)
-    return model._sparse_mlp(layer, x)
+    return _one_layer(model, params["layers"][0], jnp.asarray(table)[ids],
+                      jnp.asarray(inputs[:, 0]))
 
 
 # the last document of sequence 0, positions 817-1023, and its 40th block
@@ -276,24 +282,30 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         "layers"][0]
     inputs, (ids,), _ = diffusion_batch(packed(((40, 24),)), 6)
     x = jax.random.normal(jax.random.PRNGKey(1), (ids.size, HIDDEN))
-    positions = jnp.asarray(inputs[:, 0])
-    document, blk = sdar.block_terms(positions, BLOCK)
-    h = whole._attend(layer, x, positions, document, blk, last=False)
-    parts = []
-    for first in range(0, 128, 16):
-        share = small_model(layers=1, held=range(first, first + 16),
-                            total=128, top_k=8)
-        cut = {"router": layer["experts"]["router"],
-               **{k: layer["experts"][k][first:first + 16]
-                  for k in ("gate", "up", "down")}}
-        parts.append(share._sparse_mlp(dict(layer, experts=cut), h) - h)
+    spec = dict(SPEC, num_experts_per_tok=8, first_expert_held=0)
+
+    @jax.jit
+    def shares_and_reference(layer, x, inputs):
+        positions = inputs[:, 0]
+        document, blk = sdar.block_terms(positions, BLOCK)
+        h = whole._attend(layer, x, positions, document, blk, last=False)
+        parts = []
+        for first in range(0, 128, 16):
+            share = small_model(layers=1, held=range(first, first + 16),
+                                total=128, top_k=8)
+            cut = {"router": layer["experts"]["router"],
+                   **{k: layer["experts"][k][first:first + 16]
+                      for k in ("gate", "up", "down")}}
+            parts.append(share._sparse_mlp(dict(layer, experts=cut), h) - h)
+        attended = x + reference.attention(layer, x, inputs, spec)
+        want = attended + reference.experts_held(
+            layer["experts"], reference.rms_norm(
+                attended, layer["post_attention_layernorm"], 1e-6), 1, spec)
+        return h, parts, attended, want
+
+    h, parts, attended, want = shares_and_reference(layer, x, inputs)
     assert len(parts) == 8
     assert all(0 < float(jnp.abs(p).max()) for p in parts)
-    spec = dict(SPEC, num_experts_per_tok=8, first_expert_held=0)
-    attended = x + reference.attention(layer, x, inputs, spec)
-    want = attended + reference.experts_held(
-        layer["experts"], reference.rms_norm(
-            attended, layer["post_attention_layernorm"], 1e-6), 1, spec)
     np.testing.assert_allclose(h, attended, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(h + sum(parts), want, rtol=2e-5, atol=2e-5)
 

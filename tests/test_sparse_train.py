@@ -107,14 +107,20 @@ def run_equivalence(specs, optimizer, input_table_map=None, steps=3,
     # --- dense reference: plain value_and_grad + optax over everything
     dense_opt = {"sgd": optax.sgd(lr), "adagrad": optax.adagrad(lr),
                  "adam": optax.adam(lr)}[optimizer]
+    # one program for the whole step, not one per operation
+    @jax.jit
+    def dense_step(dparams, dstate, num, cats, labels):
+        loss, grads = jax.value_and_grad(model.loss_fn)(dparams, num, cats,
+                                                        labels)
+        upd, dstate = dense_opt.update(grads, dstate, dparams)
+        return optax.apply_updates(dparams, upd), dstate, loss
+
     dparams = jax.tree.map(lambda x: x, params)
     dstate = dense_opt.init(dparams)
     dlosses = []
     for num, cats, labels in batches:
-        loss, grads = jax.value_and_grad(model.loss_fn)(dparams, num, cats,
-                                                        labels)
-        upd, dstate = dense_opt.update(grads, dstate, dparams)
-        dparams = optax.apply_updates(dparams, upd)
+        dparams, dstate, loss = dense_step(dparams, dstate, num, cats,
+                                           labels)
         dlosses.append(float(loss))
 
     # --- sparse tapped path
@@ -171,10 +177,6 @@ def test_sparse_train_strategies(strategy):
     run_equivalence(SPECS_BASIC, "adagrad", strategy=strategy)
 
 
-# execution-bound on the single-core CPU test host (see
-# .claude/skills/verify/SKILL.md): runs in the `-m slow` tier so the
-# not-slow tier-1 sweep completes inside its time budget
-@pytest.mark.slow
 def test_sparse_train_multihot_combiners():
     specs = [(40, 4, "sum"), (60, 8, "mean"), (30, 4, "sum"), (50, 8, "mean"),
              (25, 4, "sum"), (70, 8, "sum"), (45, 4, "mean"), (35, 8, "sum")]
@@ -195,10 +197,6 @@ def test_sparse_train_row_slice():
                     atol=2e-4)
 
 
-# execution-bound on the single-core CPU test host (see
-# .claude/skills/verify/SKILL.md): runs in the `-m slow` tier so the
-# not-slow tier-1 sweep completes inside its time budget
-@pytest.mark.slow
 def test_sparse_train_hybrid_dp_col_row():
     specs = [(512, 8, "sum"), (300, 8, "sum"), (8, 4), (6, 4),
              (100, 8, "sum"), (90, 8, "sum"), (80, 8, "sum"), (70, 8, "sum"),
@@ -275,10 +273,6 @@ def test_sparse_train_ragged_inputs():
                     input_max_hotness=[6] * 8)
 
 
-# execution-bound on the single-core CPU test host (see
-# .claude/skills/verify/SKILL.md): runs in the `-m slow` tier so the
-# not-slow tier-1 sweep completes inside its time budget
-@pytest.mark.slow
 def test_sparse_train_weighted_inputs():
     rng_w = np.random.RandomState(99)
 
