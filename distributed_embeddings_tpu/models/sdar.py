@@ -60,7 +60,7 @@ from distributed_embeddings_tpu.obs.spans import spanned
 from distributed_embeddings_tpu.obs.stages import stage
 
 __all__ = ["Sdar", "Tile", "attend_block_diffusion", "block_diffusion_tiles",
-           "diffusion_head_loss"]
+           "clean_key_start", "diffusion_head_loss"]
 
 NOISY, CLEAN = 0, 1                    # the streams, in the order of the ids
 # attention's output in front of its out-projection: the one array of a
@@ -70,7 +70,8 @@ ATTENDED = "det_attended"
 
 class Tile(NamedTuple):
     """One static block of scores: queries ``[start, stop)`` of one stream
-    against the clean keys ``[0, clean_stop)`` and, for noisy queries, the
+    against the clean keys ``[0, clean_stop)`` (of which a sequence's tile
+    computes those from `clean_key_start` on) and, for noisy queries, the
     noisy keys ``[noisy_start, noisy_stop)``."""
     stream: int
     start: int
@@ -108,19 +109,29 @@ def block_diffusion_tiles(length: int, block: int, streams=(NOISY, CLEAN),
     return tiles
 
 
+def _pair_mask(stream: int, queries, clean, noisy=None):
+    """``[sequences, queries, keys]`` bool: which pairs the mask keeps,
+    from the (document, block) terms ``[sequences, positions]`` of the
+    queries, of the clean keys and, for noisy queries, of the noisy keys;
+    keys in that order (clean, then noisy)."""
+    (doc_q, blk_q), (doc_c, blk_c) = queries, clean
+    same_doc = doc_q[:, :, None] == doc_c[:, None, :]
+    earlier = blk_q[:, :, None] - blk_c[:, None, :]
+    if stream == CLEAN:
+        return same_doc & (earlier >= 0)
+    doc_n, blk_n = noisy
+    own = ((doc_q[:, :, None] == doc_n[:, None, :])
+           & (blk_q[:, :, None] == blk_n[:, None, :]))
+    return jnp.concatenate([same_doc & (earlier > 0), own], axis=2)
+
+
 def _visible(tile: Tile, document, blk):
     """``[sequences, queries, keys]`` bool: which pairs of `tile` the mask
     keeps, keys in the tile's order (clean, then noisy)."""
-    q = slice(tile.start, tile.stop)
-    same_doc = (document[:, q, None] == document[:, None, :tile.clean_stop])
-    earlier = blk[:, q, None] - blk[:, None, :tile.clean_stop]
-    if tile.stream == CLEAN:
-        return same_doc & (earlier >= 0)
-    clean = same_doc & (earlier > 0)
+    q, c = slice(tile.start, tile.stop), slice(0, tile.clean_stop)
     n = slice(tile.noisy_start, tile.noisy_stop)
-    own = ((document[:, q, None] == document[:, None, n])
-           & (blk[:, q, None] == blk[:, None, n]))
-    return jnp.concatenate([clean, own], axis=2)
+    return _pair_mask(tile.stream, (document[:, q], blk[:, q]),
+                      (document[:, c], blk[:, c]), (document[:, n], blk[:, n]))
 
 
 def block_terms(positions, block: int):
@@ -130,31 +141,184 @@ def block_terms(positions, block: int):
     return document, positions // block
 
 
+def clean_key_start(document, start: int):
+    """``[sequences]`` int32: the first clean key that the tile of queries
+    from `start` computes in each sequence, the first position of the
+    document that holds query `start`, rounded down to whole tiles of
+    `ATTN_BLOCK` keys. A packed sequence's documents are contiguous, so no
+    query from `start` on sees a clean key before it."""
+    first = jnp.sum(document < document[:, start:start + 1], axis=1)
+    return jnp.clip(first // ATTN_BLOCK, 0, start // ATTN_BLOCK) * ATTN_BLOCK
+
+
+def _windows(stream: int, chunks: int, reach: int, tile: int, length: int,
+             start, first):
+    """Where the keys of the tile of queries from `start` lie, whose clean
+    keys start at `first`: ``((stream, first key, keys), ...)``, clean then
+    noisy. The clean keys run over `chunks` tiles to the tile's last query
+    and, for clean queries, `reach` past it (a block may straddle the
+    tile's edge); noisy queries also read the noisy keys `reach` past both
+    edges, the only noisy keys they can see: `block_diffusion_tiles`'s keys
+    from `first` on. The counts are static, one per `chunks`, so where a
+    window would leave the sequence it starts up to `reach` keys earlier,
+    on keys the mask drops (another block, or before the document of the
+    tile's first query)."""
+    if stream == CLEAN:
+        size = min(chunks * tile + reach, length)
+        return ((CLEAN, jnp.minimum(first, length - size), size),)
+    size = min(tile + 2 * reach, length)
+    return ((CLEAN, first, chunks * tile),
+            (NOISY, jnp.clip(start - reach, 0, length - size), size))
+
+
+def _tile_forward(stream, chunks, reach, q, k, v, document, blk, s, start,
+                  first):
+    """The tile of queries `q` ``[1, tile, kv heads, group, d]`` of sequence
+    `s`: `mellum._scores_to_values` over its `_windows` of `k` and `v`."""
+    return _scores_to_values(q, *_tile_operands(
+        stream, chunks, reach, q, k, v, document, blk, s, start, first))
+
+
+def _tile_operands(stream, chunks, reach, q, k, v, document, blk, s, start,
+                   first):
+    """(keys, values, mask) of a tile, keys in `_windows`' order."""
+    tile, length = q.shape[1], document.shape[1]
+    windows = _windows(stream, chunks, reach, tile, length, start, first)
+
+    def rows(x, at, size):                     # [n, length] -> [1, size]
+        return jax.lax.dynamic_slice(x, (s, at), (1, size))
+
+    def gather(x):                             # [n, 2, length, ...]
+        return jnp.concatenate([jax.lax.dynamic_slice(
+            x, (s, part, at) + (0,) * (x.ndim - 3),
+            (1, 1, size) + x.shape[3:])[:, 0]
+            for part, at, size in windows], axis=1)
+
+    mask = _pair_mask(stream, (rows(document, start, tile),
+                               rows(blk, start, tile)),
+                      *((rows(document, at, size), rows(blk, at, size))
+                        for _, at, size in windows))
+    return gather(k), gather(v), mask
+
+
+def _tile_backward(stream, chunks, reach, q, k, v, document, blk, s, start,
+                   first, grad, dk, dv):
+    """The same tile taken again and differentiated: (the gradient of its
+    queries, `dk` and `dv` with those of its keys and values added)."""
+    keys, values, mask = _tile_operands(stream, chunks, reach, q, k, v,
+                                        document, blk, s, start, first)
+    _, vjp = jax.vjp(lambda q, keys, values: _scores_to_values(
+        q, keys, values, mask), q, keys, values)
+    dq, dkeys, dvalues = vjp(grad)
+    windows = _windows(stream, chunks, reach, q.shape[1], document.shape[1],
+                       start, first)
+
+    def add(acc, grads):
+        done = 0
+        for part, at, size in windows:
+            index = (s, part, at) + (0,) * (acc.ndim - 3)
+            acc = jax.lax.dynamic_update_slice(
+                acc, jax.lax.dynamic_slice(acc, index,
+                                           (1, 1, size) + acc.shape[3:])
+                + grads[:, None, done:done + size], index)
+            done += size
+        return acc
+
+    return dq, add(dk, dkeys), add(dv, dvalues)
+
+
+@functools.lru_cache(maxsize=None)
+def _branches(direction, stream: int, reach: int, count: int):
+    """A tile's programs, one per number of tiles of clean keys it reads
+    (1 to `count`): the same function objects for every layer, so JAX
+    traces each once."""
+    return tuple(functools.partial(direction, stream, chunks, reach)
+                 for chunks in range(1, count + 1))
+
+
+def _loop_terms(q, document, first):
+    """What each (sequence, tile) of a stream's loop reads: its queries
+    ``[n * count, tile, ...]``, sequence, first query, first clean key and
+    the branch of its `chunks`."""
+    n, length = document.shape
+    count = first.shape[1]
+    tile = length // count
+    index = jnp.arange(n * count)
+    seq, at = index // count, index % count
+    first = first.reshape(-1)
+    return (q.reshape((n * count, tile) + q.shape[2:]), seq, at * tile,
+            first * tile, at - first)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _attend_stream(stream: int, reach: int, q, k, v, document, blk, first):
+    """Attention of one stream's queries `q` ``[n, length, kv heads, group,
+    d]`` tile by tile, `first` ``[n, count]`` the tile at which each
+    (sequence, tile)'s clean keys start: a loop over the (sequence, tile)
+    pairs whose body switches to the program of the pair's number of tiles
+    of clean keys. So the step holds one program per such number, not one
+    per (layer, sequence, tile, start): a switch a tile multiplied the
+    step's programs several times over, and the time to load it from the
+    compile cache with them. The backward pass keeps the loop's inputs
+    alone. Returns ``[n, length, kv heads, group, d]``."""
+    return _attend_stream_fwd(stream, reach, q, k, v, document, blk,
+                              first)[0]
+
+
+def _attend_stream_fwd(stream, reach, q, k, v, document, blk, first):
+    branches = _branches(_tile_forward, stream, reach, first.shape[1])
+    tiles, *where, choice = _loop_terms(q, document, first)
+    out = jax.lax.map(
+        lambda x: jax.lax.switch(x[-1], branches, x[0][None], k, v,
+                                 document, blk, *x[1:-1]),
+        (tiles, *where, choice))
+    return out.reshape(q.shape), (q, k, v, document, blk, first)
+
+
+def _attend_stream_bwd(stream, reach, saved, grad):
+    q, k, v, document, blk, first = saved
+    branches = _branches(_tile_backward, stream, reach, first.shape[1])
+    tiles, *where, choice = _loop_terms(q, document, first)
+
+    def body(acc, x):
+        tile, *at, pick, g = x
+        dq, *acc = jax.lax.switch(pick, branches, tile[None], k, v,
+                                  document, blk, *at, g[None], *acc)
+        return acc, dq
+
+    (dk, dv), dq = jax.lax.scan(
+        body, [jnp.zeros_like(k), jnp.zeros_like(v)],
+        (tiles, *where, choice, grad.reshape(tiles.shape)))
+    return dq.reshape(q.shape), dk, dv, None, None, None
+
+
+_attend_stream.defvjp(_attend_stream_fwd, _attend_stream_bwd)
+
+
 def attend_block_diffusion(q, k, v, document, blk, block: int):
     """Attention of the queries `q` ``[n, streams, length, heads, d]`` (both
     streams, or the noisy one alone) over the keys and values `k`, `v` ``[n,
     2, length, kv heads, d]`` of both streams under the block-diffusion
     mask, scores scaled by ``1 / sqrt(d)``, in plain XLA. Only the tiles of
-    `block_diffusion_tiles` are computed, each with
-    `mellum._scores_to_values` (recomputed in the backward pass). Returns
-    ``[n, streams, length, heads * d]``."""
+    `block_diffusion_tiles` are computed, and of each sequence's tile only
+    the clean keys from `clean_key_start` on, each with
+    `mellum._scores_to_values` (taken again in the backward pass), one
+    stream at a time by `_attend_stream`. `length` is at most `ATTN_BLOCK`
+    or a multiple of it. Returns ``[n, streams, length, heads * d]``."""
     n, streams, length, heads, d = q.shape
     kv_heads = k.shape[3]
+    tile = min(ATTN_BLOCK, length)
+    if length % tile:
+        raise ValueError(f"a sequence of {length} tokens is no whole number "
+                         f"of tiles of {tile} queries")
     q = q.reshape(n, streams, length, kv_heads, heads // kv_heads, d)
-    outs = {}
-    for tile in block_diffusion_tiles(length, block, (NOISY, CLEAN)[:streams]):
-        keys = [k[:, CLEAN, :tile.clean_stop]]
-        values = [v[:, CLEAN, :tile.clean_stop]]
-        if tile.stream == NOISY:
-            keys.append(k[:, NOISY, tile.noisy_start:tile.noisy_stop])
-            values.append(v[:, NOISY, tile.noisy_start:tile.noisy_stop])
-        outs.setdefault(tile.stream, []).append(_scores_to_values(
-            q[:, tile.stream, tile.start:tile.stop],
-            jnp.concatenate(keys, axis=1), jnp.concatenate(values, axis=1),
-            _visible(tile, document, blk)))
-    return jnp.stack([jnp.concatenate(outs[s], axis=1)
-                      for s in range(streams)], axis=1).reshape(
-                          n, streams, length, heads * d)
+    first = jnp.stack([clean_key_start(document, start) // tile
+                       for start in range(0, length, tile)], axis=1)
+    return jnp.stack([
+        _attend_stream(stream, block - 1, q[:, stream], k, v, document, blk,
+                       first)
+        for stream in (NOISY, CLEAN)[:streams]], axis=1).reshape(
+            n, streams, length, heads * d)
 
 
 def diffusion_head_loss(x, final_norm, head, targets, weights, eps):
@@ -289,14 +453,18 @@ class Sdar:
         the scores are taken again. A block's second half is taken again
         from its input."""
         document, blk = block_terms(positions, self.block)
+        # one function and policy object for the layers alike, so that JAX
+        # traces and differentiates each half of a block once
+        policy = jax.checkpoint_policies.save_only_these_names(ATTENDED)
+        attend = {last: jax.checkpoint(functools.partial(self._attend,
+                                                         last=last),
+                                       policy=policy)
+                  for last in (False, True)}
+        sparse_mlp = jax.checkpoint(self._sparse_mlp)
         for i, layer in enumerate(params["layers"]):
-            attend = functools.partial(self._attend,
-                                       last=i == self.num_layers - 1)
-            x = jax.checkpoint(
-                attend,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    ATTENDED))(layer, x, positions, document, blk)
-            x = jax.checkpoint(self._sparse_mlp)(layer, x)
+            x = attend[i == self.num_layers - 1](layer, x, positions,
+                                                 document, blk)
+            x = sparse_mlp(layer, x)
         return x
 
     def loss_fn(self, params, inputs, cats, weights, taps=None,
@@ -314,10 +482,14 @@ class Sdar:
         """Forward only, what one batch asks of the step: ``masked_share``,
         the masked noisy positions over the noisy positions (the part of
         the noisy stream the loss reads); ``mask_row_share``, the step's
-        lookups that hit the mask id's row; ``visited_pair_share``, the
-        (query, key) pairs the mask keeps over the pairs of the score tiles
-        the step computes, over its layers (the last one's queries are the
-        noisy stream's alone). Reads no parameter; jit it."""
+        lookups that hit the mask id's row; ``skipped_pair_share``, the
+        (query, key) pairs of the static score tiles whose clean keys lie
+        before the sequence's `clean_key_start`, which the step does not
+        compute, over the static tiles' pairs; ``visited_pair_share``, the
+        pairs the mask keeps over the pairs the step computes (its windows
+        add up to ``block - 1`` masked keys to a sequence's edge tiles,
+        left out here). Both over its layers (the last one's queries are
+        the noisy stream's alone). Reads no parameter; jit it."""
         positions = inputs[:, 0]
         n, length = positions.shape
         document, blk = block_terms(positions, self.block)
@@ -325,9 +497,13 @@ class Sdar:
         tiles = block_diffusion_tiles(length, self.block)
         visible = sum(layers[t.stream] * jnp.sum(_visible(t, document, blk))
                       for t in tiles)
-        computed = sum(layers[t.stream] * n * (t.stop - t.start) * t.keys()
-                       for t in tiles)
+        tiled = sum(layers[t.stream] * n * (t.stop - t.start) * t.keys()
+                    for t in tiles)
+        skipped = sum(layers[t.stream] * (t.stop - t.start)
+                      * jnp.sum(clean_key_start(document, t.start))
+                      for t in tiles)
         (ids,) = cats
         return {"masked_share": jnp.mean(weights > 0),
                 "mask_row_share": jnp.mean(ids == self.mask_id),
-                "visited_pair_share": visible / computed}
+                "skipped_pair_share": skipped / tiled,
+                "visited_pair_share": visible / (tiled - skipped)}

@@ -56,20 +56,25 @@ def export_moe_gauges(registry: MetricRegistry, stats: dict) -> dict:
 
 
 def export_diffusion_gauges(registry: MetricRegistry, stats: dict) -> dict:
-    """Set ``diffusion/masked_share``, ``diffusion/mask_row_share`` and
-    ``attn/visited_pair_share{mask=block_diffusion}`` from one batch's
+    """Set ``diffusion/masked_share``, ``diffusion/mask_row_share``,
+    ``attn/visited_pair_share{mask=block_diffusion}`` and
+    ``attn/skipped_pair_share{mask=block_diffusion}`` from one batch's
     `models.sdar.Sdar.diffusion_stats` (jitted and forward only): the
     masked noisy positions over the noisy positions, which is the part of
     the noisy stream the loss reads; the step's lookups that hit the mask
-    id's row, the one row a sparse update sums most duplicates into; and
-    the (query, key) pairs the block-diffusion mask keeps over the pairs of
-    the score tiles the step computes. A host read of a device result: call
-    it beside a loss fetch, not every step. Returns ``{name: float}``."""
+    id's row, the one row a sparse update sums most duplicates into; the
+    (query, key) pairs the block-diffusion mask keeps over the pairs the
+    step computes; and the pairs of the static score tiles that the
+    document-aware start of their clean keys leaves out, over those pairs.
+    A host read of a device result: call it beside a loss fetch, not every
+    step. Returns ``{name: float}``."""
     out = {name: float(value) for name, value in stats.items()}
     registry.gauge("diffusion/masked_share").set(out["masked_share"])
     registry.gauge("diffusion/mask_row_share").set(out["mask_row_share"])
     registry.gauge("attn/visited_pair_share", mask="block_diffusion").set(
         out["visited_pair_share"])
+    registry.gauge("attn/skipped_pair_share", mask="block_diffusion").set(
+        out["skipped_pair_share"])
     return out
 
 

@@ -4,7 +4,8 @@ against its plain reference (`benchmark/references/sdar_moe.py`: dense
 dense gradients and the table's rows after two adam steps, and a bfloat16
 mutation that must fail the same comparison; the mask's properties; the
 eight shares of the experts against the uncut layer; the tiles the
-attention visits; the gauges on a batch counted by hand; and the shared
+attention visits and where a sequence's tile starts its clean keys; the
+gauges on batches counted by hand; and the shared
 `mellum._attend_blocks` held to the code it was before this model came.
 """
 
@@ -34,6 +35,9 @@ SPEC = {   # a test's spec: what `reference.published_spec` reads from file
 # two sequences of 1,024 whose documents put blocks across the edge of the
 # attention's first tile of 512 queries (510 + [0, 4); 1 + 508 + [0, 4))
 LENGTHS = ((510, 7, 300, 207), (1, 1022, 1))
+# the same shape, but sequence 0's second tile of queries begins a document
+# at 512, so its clean keys start there; sequence 1's still start at 0
+TILE_DOCUMENT = ((300, 212, 512), (1, 1022, 1))
 
 
 def small_model(layers=2, held=range(4, 8), total=TOTAL, top_k=TOP_K,
@@ -109,8 +113,8 @@ def reference_side(model, params, inputs, cats, weights):
 
 
 @functools.lru_cache(maxsize=None)
-def reference_of_seed(seed):
-    return reference_side(*small_case(seed))
+def reference_of_seed(seed, lengths=LENGTHS):
+    return reference_side(*small_case(seed, lengths))
 
 
 def worst_relative(got, want):
@@ -128,12 +132,15 @@ def worst_relative(got, want):
 LOSS_RTOL, GRAD_RTOL = 1e-5, 2e-4
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_the_model_agrees_with_the_plain_reference(seed):
+@pytest.mark.parametrize("seed, lengths", [
+    (0, LENGTHS), (1, LENGTHS), (2, LENGTHS), (9, TILE_DOCUMENT)],
+    ids=["0", "1", "2", "tile_document"])
+def test_the_model_agrees_with_the_plain_reference(seed, lengths):
     """Loss to 1e-5 and every gradient, the table's rows among them, to
-    2e-4 of its leaf's largest entry. Every dense leaf has a gradient."""
-    loss, grads = program_side(*small_case(seed))
-    want_loss, want_grads = reference_of_seed(seed)
+    2e-4 of its leaf's largest entry. Every dense leaf has a gradient. The
+    last case's second tile starts its clean keys at 512 in one sequence."""
+    loss, grads = program_side(*small_case(seed, lengths))
+    want_loss, want_grads = reference_of_seed(seed, lengths)
     assert float(loss) == pytest.approx(float(want_loss), rel=LOSS_RTOL)
     assert float(loss) > 1.0
     assert worst_relative(grads, want_grads) < GRAD_RTOL
@@ -372,6 +379,94 @@ def test_the_visited_tiles_hold_every_visible_pair_and_nothing_else_whole():
     assert float(stats["visited_pair_share"]) > 1.1 * one_stream
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _clean_key_starts(positions, length):
+    document, _ = sdar.block_terms(positions, BLOCK)
+    return jnp.stack([sdar.clean_key_start(document, start)
+                      for start in range(0, length, mellum.ATTN_BLOCK)],
+                     axis=1)
+
+
+@pytest.mark.parametrize("lengths, want", [
+    (LENGTHS, [[0, 0], [0, 0]]), (TILE_DOCUMENT, [[0, 512], [0, 0]])],
+    ids=["every_tile_from_0", "tile_document"])
+def test_each_tiles_clean_keys_start_at_its_first_querys_document(
+        lengths, want):
+    """A (sequence, tile)'s clean keys start at the document start of the
+    tile's first query, rounded down to 512; the mask keeps no pair of the
+    tile's queries before it, in either stream, so the tiles from there on
+    still hold every pair the plain reference's mask keeps."""
+    positions = packed(lengths)
+    length = positions.shape[1]
+    starts = np.asarray(_clean_key_starts(jnp.asarray(positions), length))
+    np.testing.assert_array_equal(starts, want)
+    tile = mellum.ATTN_BLOCK
+    for n, row in enumerate(positions):
+        mask = np.asarray(reference.block_diffusion_mask(
+            jnp.asarray(row), BLOCK))
+        for i, start in enumerate(range(0, length, tile)):
+            document_start = start - row[start]
+            assert starts[n, i] == document_start // tile * tile
+            for stream in (sdar.NOISY, sdar.CLEAN):
+                rows = mask[stream * length + start:
+                            stream * length + start + tile]
+                assert not rows[:, length:length + starts[n, i]].any()
+
+
+def masked_attention(q, k, v, positions):
+    """The plain reference's attention over ``[xt ; x0]`` of each sequence:
+    `q` ``[n, streams, L, heads, d]`` (the noisy stream's rows of the
+    ``[2L, 2L]`` mask alone where one stream), `k`, `v` ``[n, 2, L, kv
+    heads, d]``; ``[n, streams, L, heads * d]``."""
+    n, streams, length, heads, d = q.shape
+    group = heads // k.shape[3]
+    outs = []
+    for s in range(n):
+        mask = reference.block_diffusion_mask(positions[s], BLOCK)
+        mask = mask[:streams * length]
+        queries = q[s].reshape(streams * length, heads, d)
+        keys = jnp.repeat(k[s].reshape(2 * length, -1, d), group, axis=1)
+        values = jnp.repeat(v[s].reshape(2 * length, -1, d), group, axis=1)
+        scores = jnp.einsum("qhd,khd->hqk", queries, keys) / math.sqrt(d)
+        weights = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+        outs.append(jnp.einsum("hqk,khd->qhd", weights, values).reshape(
+            streams, length, heads * d))
+    return jnp.stack(outs)
+
+
+@pytest.mark.parametrize("streams", [2, 1], ids=["both", "noisy_last"])
+def test_the_attention_and_its_gradients_match_the_plain_masked_attention(
+        streams):
+    """`attend_block_diffusion` on documents that start the second tile's
+    clean keys at 512 in one sequence and at 0 in the other: outputs and
+    the gradients of q, k and v to the model's limit (2e-4 of a leaf's
+    largest entry) against the plain reference's ``[2L, 2L]`` masked
+    attention, both streams' queries (every layer but the last) and the
+    noisy stream's alone (the last)."""
+    positions = jnp.asarray(packed(TILE_DOCUMENT))
+    n, length = positions.shape
+    keys = jax.random.split(jax.random.PRNGKey(10), 4)
+    q = jax.random.normal(keys[0], (n, streams, length, HEADS, HEAD_DIM))
+    k = jax.random.normal(keys[1], (n, 2, length, KV_HEADS, HEAD_DIM))
+    v = jax.random.normal(keys[2], (n, 2, length, KV_HEADS, HEAD_DIM))
+    cotangent = jax.random.normal(keys[3], (n, streams, length,
+                                            HEADS * HEAD_DIM))
+
+    def program(q, k, v, positions):
+        document, blk = sdar.block_terms(positions, BLOCK)
+        return sdar.attend_block_diffusion(q, k, v, document, blk, BLOCK)
+
+    def run(attend):
+        def total(q, k, v, positions):
+            return jnp.sum(attend(q, k, v, positions) * cotangent)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(lambda *a: (attend(*a), jax.grad(
+                total, argnums=(0, 1, 2))(*a)))(q, k, v, positions)
+
+    got, want = run(program), run(masked_attention)
+    assert worst_relative(got, want) < GRAD_RTOL
+
+
 # ------------------------------------------------------------------ gauges
 def test_the_gauges_read_a_batch_counted_by_hand():
     """One sequence of 8: a document of 5 and one of 3, blocks of 2, so
@@ -395,6 +490,7 @@ def test_the_gauges_read_a_batch_counted_by_hand():
     said = export_diffusion_gauges(registry, jax.jit(model.diffusion_stats)(
         inputs, [ids], weights))
     assert said == {"masked_share": 3 / 8, "mask_row_share": 3 / 16,
+                    "skipped_pair_share": 0.0,
                     "visited_pair_share": pytest.approx(72 / 320)}
     gauges = registry.snapshot()["gauges"]
     assert gauges["diffusion/masked_share"] == 3 / 8
@@ -406,7 +502,31 @@ def test_the_gauges_read_a_batch_counted_by_hand():
         catalog = f.read()
     assert all(f"`{name}" in catalog for name in (
         "diffusion/masked_share", "diffusion/mask_row_share",
-        "attn/visited_pair_share{mask=block_diffusion}"))
+        "attn/visited_pair_share{mask=block_diffusion}",
+        "attn/skipped_pair_share{mask=block_diffusion}"))
+
+
+def test_the_skipped_pair_share_reads_a_sequence_counted_by_hand():
+    """One sequence of 1,024: a document of 512 then one of 512, blocks of
+    4, two layers. The static tiles (512 queries each): noisy 512 + 515
+    and 1,024 + 515 keys, clean 515 and 1,024, the noisy ones in both
+    layers: 512 x (2 x 2,566 + 1,539) = 512 x 6,671 pairs. The second
+    tile's first query starts a document at 512, so both streams' second
+    tiles leave out 512 clean keys: 512 x 512 x (2 + 1) pairs. A stream
+    keeps 2 x 16 x (1 + ... + 128) = 264,192 pairs a layer, three times."""
+    model = small_model(layers=2)
+    positions = packed(((512, 512),))
+    inputs, (ids,), weights = diffusion_batch(positions, 11)
+    assert block_diffusion.visible_pairs(positions, BLOCK) == 264_192
+    registry = MetricRegistry()
+    said = export_diffusion_gauges(registry, jax.jit(model.diffusion_stats)(
+        inputs, [ids], weights))
+    assert said["skipped_pair_share"] == pytest.approx(1_536 / 6_671)
+    assert said["visited_pair_share"] == pytest.approx(
+        3 * 264_192 / (512 * (6_671 - 1_536)))
+    gauges = registry.snapshot()["gauges"]
+    assert gauges["attn/skipped_pair_share{mask=block_diffusion}"] == (
+        pytest.approx(1_536 / 6_671))
 
 
 # ------------------------------------ the shared attention, as it was before
